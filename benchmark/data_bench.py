@@ -229,6 +229,9 @@ def main(argv=None):
                         help="decode throughput at 1/2/4/8 workers")
     args = parser.parse_args(argv)
 
+    from mxnet_tpu.compile import jax_cache_dir
+
+    jax_cache_dir()
     from mxnet_tpu import io as mxio
 
     if args.train or args.scaling:

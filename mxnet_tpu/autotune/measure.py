@@ -3,7 +3,7 @@
 ``tune(site, key)`` runs every candidate config of a site's grid as a
 micro-benchmark (deterministic seeded inputs, warm-up runs discarded,
 trimmed-mean of timed repeats; each measured run sits inside an
-``autotune_measure`` trace span so tunnel captures keep the raw
+``autotune_measure`` trace span so a traced run keeps the raw
 per-candidate durations in the flight ring), enforces the guards —
 
 - **shape parity**: outputs must match the default config's shapes;

@@ -552,7 +552,7 @@ class _ShardLayout(TuningSite):
     test_shard_mp), so parity is structural like ``decode_bucket``:
     a layout can change residency and wire bytes, never tokens or
     weights.  Winners come from committed bench rows (bench.py
-    ``shard_tp_step``) or an mfu_campaign sweep — layout changes
+    ``shard_tp_step``) — layout changes
     recapture the step program (the table is part of the capture
     signature), which is exactly the cost measure.tune() must not
     pay per candidate."""
@@ -598,8 +598,8 @@ class _ShardLayout(TuningSite):
         raise MXNetError(
             "shard_layout is a structural site: a layout change "
             "recaptures the step program, so it is measured by the "
-            "committed bench rows (bench.py shard_tp_step / "
-            "tools/mfu_campaign.sh --shard) and drilled by make "
+            "committed bench rows (bench.py shard_tp_step) and "
+            "drilled by make "
             "shard-smoke, not by measure.tune()")
 
 

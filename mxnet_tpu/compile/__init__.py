@@ -32,6 +32,7 @@ histograms, visible in the Prometheus/JSON exporters and serve
 """
 from __future__ import annotations
 
+import os
 import threading
 
 from ..base import get_env
@@ -39,12 +40,37 @@ from .aot import attach_from_cache, precompile, warm_start
 from .cache import CompileCache, block_signature, default_cache_dir
 
 __all__ = ["enable", "disable", "is_enabled", "configure", "get_cache",
-           "cache_dir", "stats", "clear",
+           "cache_dir", "stats", "clear", "jax_cache_dir",
            "precompile", "warm_start", "attach_from_cache",
            "CompileCache", "block_signature", "default_cache_dir"]
 
 _LOCK = threading.Lock()
 _CACHE = None
+
+
+def jax_cache_dir():
+    """Turn JAX's own persistent compilation cache on for this process
+    and return its directory — the first call of ``chip_smoke.py``,
+    ``bench.py`` and the ``benchmark/`` scripts (mx.compile above is the
+    repo's store for hybridize/captured programs and stays off there).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already keeps its
+    cache there and no other path is named here.  Where it is not, the
+    cache goes to ``.jax_cache`` at the root of the checkout: the path is
+    part of every entry's key, so it is fixed — never a temp dir, a pid
+    or a time."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    # cache the small programs too (an eager op is a compile on the chip)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 def _env_enabled():
     """Initial enablement from the environment.  An explicitly-set
     MXNET_COMPILE_CACHE always wins; _DIR implies on only while the

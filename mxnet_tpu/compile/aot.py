@@ -93,11 +93,29 @@ def _spec_json_safe(spec):
         return False
 
 
+def _serialize(se, compiled, key):
+    """compiled executable -> ARTIFACT.bin bytes.  The ids of the devices
+    it runs on, in assignment order, ride along: the loader needs them."""
+    exe, in_tree, out_tree = se.serialize(compiled)
+    return pickle.dumps({
+        "exe": exe, "in_tree": in_tree, "out_tree": out_tree, "key": key,
+        "devices": [d.id for d in
+                    compiled.runtime_executable().local_devices()]})
+
+
 def _deserialize(se, raw):
-    """raw ARTIFACT.bin bytes -> (loaded executable, key) or None."""
+    """raw ARTIFACT.bin bytes -> (loaded executable, key).  The executable
+    is loaded onto the devices it was compiled for: left to its default,
+    ``deserialize_and_load`` takes EVERY device of the backend as the
+    execution devices, and a one-device program then fails at call time
+    on a host with several ("expected ... to have 8 shards")."""
+    import jax
+
     payload = pickle.loads(raw)
-    cfn = se.deserialize_and_load(payload["exe"], payload["in_tree"],
-                                  payload["out_tree"])
+    by_id = {d.id: d for d in jax.devices()}
+    cfn = se.deserialize_and_load(
+        payload["exe"], payload["in_tree"], payload["out_tree"],
+        execution_devices=[by_id[i] for i in payload["devices"]])
     return cfn, payload["key"]
 
 
@@ -143,14 +161,10 @@ def attach_lowered(lowered, block_class, block_sig):
                     telemetry.COMPILE_CACHE_MISS.inc()
                 compiled = lowered.compile()
                 try:
-                    exe, in_tree, out_tree = se.serialize(compiled)
-                    artifact = pickle.dumps(
-                        {"exe": exe, "in_tree": in_tree,
-                         "out_tree": out_tree, "key": None})
-                    cache.commit(fingerprint, artifact, {
-                        "block_class": block_class,
-                        "block_sig": block_sig,
-                        "portable": False})
+                    cache.commit(
+                        fingerprint, _serialize(se, compiled, None),
+                        {"block_class": block_class,
+                         "block_sig": block_sig, "portable": False})
                 except Exception:
                     _LOGGER.debug("program cache commit failed",
                                   exc_info=True)
@@ -222,9 +236,7 @@ def attach_from_cache(block, centry, key, flat_inputs, training,
         return None  # let the lazy jit path surface the real error
     t_io = time.perf_counter()
     try:
-        exe, in_tree, out_tree = se.serialize(compiled)
-        artifact = pickle.dumps({"exe": exe, "in_tree": in_tree,
-                                 "out_tree": out_tree, "key": key})
+        artifact = _serialize(se, compiled, key)
         portable = (_key_is_portable(key)
                     and _spec_json_safe(centry.out_spec)
                     and _spec_json_safe(getattr(centry, "in_spec",

@@ -13,7 +13,7 @@ item 1).  Four pieces, each drillable on CPU with 2 local processes:
 - :mod:`~mxnet_tpu.dist.timeouts` — ``MXNET_DIST_COLLECTIVE_TIMEOUT``
   deadlines around collective dispatch: a dead peer turns the
   classic forever-hang in ``psum`` into a classified
-  :class:`DistTimeout` the supervisor taxonomy retries via the
+  :class:`DistTimeout` the supervisor classification retries via the
   coordinated world-restart path, with the trace watchdog armed
   around every collective.
 - :mod:`~mxnet_tpu.dist.podckpt` — pod-consistent checkpoints: every

@@ -5,7 +5,7 @@ SIGKILL) and every peer blocks in the next all-reduce with nothing to
 time it out.  ``run_with_deadline`` closes that hole: the collective
 body runs on a worker thread, the caller joins it under
 ``MXNET_DIST_COLLECTIVE_TIMEOUT`` seconds, and a miss raises
-``DistTimeout`` — which the PR 8 supervisor taxonomy classifies
+``DistTimeout`` — which the PR 8 supervisor classification classifies
 *transient* (``mx_fault_kind``), so the failure routes into the
 coordinated world-stop/restart path instead of a hang.
 

@@ -7,7 +7,7 @@ real subsystems:
 - ``mx.checkpoint`` owns persistence (the ``CheckpointManager`` here
   is a positional-arg-compatible shim over it);
 - ``mx.resilience`` owns detection and recovery: the exception
-  taxonomy, backoff/budget policy, preemption handling, bounded
+  classification, backoff/budget policy, preemption handling, bounded
   health probes, and the ``Supervisor`` loop.
 
 ``FaultTolerantRunner`` is kept for existing callers but is now a
@@ -33,8 +33,8 @@ def device_health_check(timeout_ok=True, timeout=None):
 
     Returns ``{device_str: "ok" | "error: ..."}``.  With ``timeout``
     (seconds) each device is probed in a worker thread under a shared
-    wall-clock bound, and a hung transfer — a dead chip, or a dead
-    tunnel to it — reports ``"error: timeout"`` instead of blocking
+    wall-clock bound, and a hung transfer — a dead chip — reports
+    ``"error: timeout"`` instead of blocking
     the caller forever (the gap this function's own docstring used to
     document).  ``timeout=None`` keeps the old unbounded behavior.
     ``timeout_ok`` is accepted for signature compatibility."""
@@ -76,7 +76,7 @@ class FaultTolerantRunner(Supervisor):
     """DEPRECATED alias of ``mx.resilience.Supervisor`` keeping the old
     constructor and semantics: a LIFETIME restart budget and no
     backoff sleep between restarts.  It still gains the new hardening
-    for free — exception taxonomy (fatal shape/user errors raise
+    for free — exception classification (fatal shape/user errors raise
     immediately instead of burning restarts), bounded health probes,
     contained ``on_failure`` callbacks (a raising callback no longer
     masks the original training error), preemption polling, and a

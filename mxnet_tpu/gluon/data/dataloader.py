@@ -360,6 +360,13 @@ class DataLoader:
     custom ``batchify_fn`` must be picklable (module-level, no lambdas);
     pass ``mp_context='fork'`` to trade safety for closure support when
     no device backend has been touched yet.
+
+    Workers must stay OFF JAX: a chip belongs to one process at a time
+    and the parent holds it, so a worker that touches a jax array (an
+    ``nd`` op in ``dataset.__getitem__`` or ``batchify_fn``, a
+    ``.as_in_context``) opens the default backend, and then fails or
+    hangs on a machine with a chip.  Datasets and batchify functions
+    produce numpy; the parent does the device transfer.
     """
 
     def __init__(self, dataset, batch_size=None, shuffle=False, sampler=None,

@@ -21,7 +21,7 @@ asynchronously, and acted on:
   bench-row health columns, ``tools/diagnose.py --monitor``.
 
 Off by default; arm with ``MXNET_MONITOR=1`` (and see the README's
-"Training health" section for the tunnel-capture recipe).  This is the
+"Training health" section for the recipe).  This is the
 MXNet ``mx.monitor.Monitor`` capability rebuilt TPU-native: per-layer
 stat inspection without per-layer eager readbacks.
 """

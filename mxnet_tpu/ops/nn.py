@@ -608,10 +608,18 @@ def multi_head_attention(q, k, v, num_heads=1, mask=None, scale=None,
                          "with mxnet_tpu.random.take_key())")
     has_dropout = attn_dropout > 0.0
     if impl == "auto":
-        # the Pallas kernel now covers dropout too (in-kernel per-tile
-        # PRNG mask, fwd + both bwd kernels regenerate it)
-        impl = "pallas" if pa.use_flash(Tq, Tk, D, mask is not None) \
-            else "dense"
+        # the Pallas kernel covers dropout too (in-kernel per-tile PRNG
+        # mask, fwd + both bwd kernels regenerate it); a sequence whose
+        # K/V outgrows the kernels' fast memory takes the blockwise scan,
+        # never the (Tq, Tk) score matrix
+        isz = q.dtype.itemsize
+        if pa.use_flash(Tq, Tk, D, mask is not None, isz):
+            impl = "pallas"
+        elif mask is None and pa.flash_vmem_bytes(Tq, Tk, D, isz) \
+                > pa.VMEM_BUDGET_BYTES:
+            impl = "flash"
+        else:
+            impl = "dense"
     if impl in ("pallas", "flash"):
         if mask is not None:
             raise MXNetError(

@@ -9,23 +9,30 @@ running (m, l, acc) state, so memory is O(T·D) and the MXU sees back-to-back
 (block_q × D) @ (D × block_k) matmuls.
 
 Three tiers:
-- ``flash_attention``     — Pallas kernels fwd AND bwd (TPU;
-                            ``interpret=True`` elsewhere so the same
-                            kernels are testable on CPU): the backward
-                            recomputes per-block probabilities from the
-                            saved logsumexp in dedicated dq and dk/dv
-                            kernels, with in-kernel probability dropout.
+- ``flash_attention``     — Pallas kernels fwd AND bwd (compiled on
+                            the TPU, interpreted on the CPU backend so
+                            the same kernels are testable there): the
+                            backward recomputes per-block probabilities
+                            from the saved logsumexp in dedicated dq and
+                            dk/dv kernels, with in-kernel probability
+                            dropout.
 - ``blockwise_attention`` — pure-JAX lax.scan online softmax;
                             differentiable end-to-end; the fallback path.
 - dense                   — plain einsum chain (ops/nn.py), best for short T.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
+
+from ..base import MXNetError
 
 _NEG_INF = -1e30
 
@@ -178,26 +185,29 @@ def blockwise_attention(q, k, v, causal=False, sm_scale=None, block_k=None,
 # Pallas kernels (forward + flash backward; reference fwd-only equivalent:
 # src/operator/contrib/transformer.cc:650-826)
 # ---------------------------------------------------------------------------
-def _tile_keep_mask(seed, bh, qi, j, shape, dropout_p, interpret):
+def _tile_keep_mask(seed_bh, tile, shape, dropout_p, interpret):
     """Deterministic per-tile keep mask.
 
-    Seeding by (seed, bh, qi, j) makes the SAME mask reproducible from the
-    forward kernel, the dq kernel (fixed qi, looping j) and the dkv kernel
-    (fixed j, looping qi) without storing any bits.  On TPU hardware the
-    bits come from the core PRNG (pltpu.prng_*); interpret mode has no
-    lowering for those, so it derives a threefry mask instead — each
-    backend is self-consistent across its fwd/bwd passes, which is the
-    only requirement (masks need not match across backends)."""
+    ``seed_bh`` is this (batch, head)'s word of the seed array (see
+    ``_bh_seeds``) and ``tile`` the flat (q-block, k-block) index, so the
+    SAME mask is reproducible from the forward kernel, the dq kernel (fixed
+    qi, looping j) and the dkv kernel (fixed j, looping qi) without storing
+    any bits.  Two words are all the core PRNG takes (Mosaic: "Setting seed
+    with more than 2 values is not supported").  On TPU hardware the bits
+    come from the core PRNG (pltpu.prng_*); interpret mode has no lowering
+    for those, so it derives a threefry mask instead — each backend is
+    self-consistent across its fwd/bwd passes, which is the only
+    requirement (masks need not match across backends)."""
     if interpret:
-        key = jax.random.fold_in(jax.random.fold_in(jax.random.fold_in(
-            jax.random.PRNGKey(seed), bh), qi), j)
+        key = jax.random.fold_in(jax.random.PRNGKey(seed_bh), tile)
         return jax.random.bernoulli(key, 1.0 - dropout_p, shape)
     from jax.experimental.pallas import tpu as pltpu
 
-    pltpu.prng_seed(seed, bh, qi, j)
-    bits = pltpu.prng_random_bits(shape)
-    thresh = jnp.uint32(int((1.0 - dropout_p) * float(2 ** 32 - 1)))
-    return bits.astype(jnp.uint32) < thresh
+    pltpu.prng_seed(seed_bh, tile)
+    bits = pltpu.prng_random_bits(shape)      # int32, all 2^32 patterns
+    # signed compare: P(bits < t) = (t + 2^31) / 2^32 = 1 - dropout_p
+    thresh = int((1.0 - dropout_p) * 2.0 ** 32) - 2 ** 31
+    return bits < jnp.int32(min(thresh, 2 ** 31 - 1))
 
 
 def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
@@ -206,7 +216,8 @@ def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale          # (block_q, D)
     D = q.shape[-1]
-    nk = pl.cdiv(seq_k, block_k)
+    nk_all = pl.cdiv(seq_k, block_k)
+    nk = nk_all
     if causal:
         # skip fully-masked K blocks right of the diagonal
         nk = jnp.minimum(nk, pl.cdiv((qi + 1) * block_q, block_k))
@@ -233,7 +244,7 @@ def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
         # dropout(softmax(s)) @ v — normalization sees the full softmax)
         l_new = l * corr + p.sum(-1)
         if dropout_p > 0.0:
-            keep = _tile_keep_mask(seed_ref[0], bh, qi, j, p.shape,
+            keep = _tile_keep_mask(seed_ref[bh], qi * nk_all + j, p.shape,
                                    dropout_p, interpret)
             p = p * keep.astype(p.dtype) / (1.0 - dropout_p)
         acc_new = acc * corr[:, None] + jax.lax.dot_general(
@@ -264,7 +275,8 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     lse = lse_ref[0, 0, 0, :]           # row 0 of the (8, block_q) tile
     delta = delta_ref[0, 0, 0, :]
     D = qs.shape[-1]
-    nk = pl.cdiv(seq_k, block_k)
+    nk_all = pl.cdiv(seq_k, block_k)
+    nk = nk_all
     if causal:
         nk = jnp.minimum(nk, pl.cdiv((qi + 1) * block_q, block_k))
 
@@ -285,7 +297,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dp = jax.lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if dropout_p > 0.0:
-            keep = _tile_keep_mask(seed_ref[0], bh, qi, j, p.shape,
+            keep = _tile_keep_mask(seed_ref[bh], qi * nk_all + j, p.shape,
                                    dropout_p, interpret)
             dp = dp * keep.astype(dp.dtype) / (1.0 - dropout_p)
         ds = p * (dp - delta[:, None])
@@ -311,6 +323,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     vblk = v_ref[0].astype(jnp.float32)
     D = kblk.shape[-1]
     nq = pl.cdiv(seq_q, block_q)
+    nk_all = pl.cdiv(seq_k, block_k)
 
     def body(qi, carry):
         dk, dv = carry
@@ -332,7 +345,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         p = jnp.exp(s - lse[:, None])
         p = jnp.where(valid, p, 0.0)                  # padded q rows -> 0
         if dropout_p > 0.0:
-            keep = _tile_keep_mask(seed_ref[0], bh, qi, j, p.shape,
+            keep = _tile_keep_mask(seed_ref[bh], qi * nk_all + j, p.shape,
                                    dropout_p, interpret).astype(p.dtype) \
                 / (1.0 - dropout_p)
         else:
@@ -361,7 +374,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _smem_spec():
-    """BlockSpec for the scalar dropout seed (SMEM on TPU)."""
+    """BlockSpec for the per-(batch, head) dropout seeds (SMEM on TPU)."""
     from jax.experimental.pallas import tpu as pltpu
 
     return pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -387,20 +400,78 @@ def _pad_pack(q, k, v, block_q, block_k):
     return qf, kf, vf, nq, nk, pad_q, pad_k
 
 
-def _flash_forward(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                   interpret, dropout_p, want_lse=False):
+class _Cfg(NamedTuple):
+    """Static (hashable) kernel configuration shared by fwd and bwd."""
+    causal: bool
+    scale: float
+    block_q: int
+    block_k: int
+    interpret: bool
+    dropout_p: float
+
+
+_MESH_ROWS = threading.local()
+
+
+@contextlib.contextmanager
+def mesh_rows(mesh, batch_axes):
+    """Declare that the step being traced splits the batch over
+    ``batch_axes`` of ``mesh``.
+
+    Mosaic kernels cannot be partitioned automatically, so an engine that
+    jits one program over a mesh (``FusedTrainer(mesh=...)``,
+    ``mx.step`` on a ``GlobalMesh``) enters this around the call that
+    traces it: the kernels then run under a ``shard_map`` in which every
+    device works on its own batch rows — H, T and D whole — instead of
+    failing to lower or gathering the batch to every chip.  Axes that do
+    not divide B are left out, which costs a gather but stays correct."""
+    prev = getattr(_MESH_ROWS, "layout", None)
+    _MESH_ROWS.layout = (mesh, tuple(batch_axes))
+    try:
+        yield
+    finally:
+        _MESH_ROWS.layout = prev
+
+
+def _over_rows(local_fn, out_ndims, cfg, *arrays):
+    """``local_fn(cfg, *arrays)`` — Pallas calls gridded over the leading
+    (B, H) dims of every array — directly, or per device under the
+    ``mesh_rows`` layout in force."""
+    layout = getattr(_MESH_ROWS, "layout", None)
+    # inside a caller's shard_map (ring attention, a pipeline stage, an
+    # MoE expert) the arrays are one device's already
+    if layout is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return local_fn(cfg, *arrays)
+    mesh, batch_axes = layout
+    B, keep, size = arrays[1].shape[0], [], 1
+    for a in batch_axes:
+        if B % (size * mesh.shape[a]) == 0:
+            keep.append(a)
+            size *= mesh.shape[a]
+    spec = lambda ndim: P(tuple(keep) or None,  # noqa: E731
+                          *(None,) * (ndim - 1))
+    # pallas_call outputs carry no varying-axes annotation
+    return jax.shard_map(
+        functools.partial(local_fn, cfg), mesh=mesh,
+        in_specs=tuple(spec(a.ndim) for a in arrays),
+        out_specs=tuple(spec(n) for n in out_ndims),
+        check_vma=False)(*arrays)
+
+
+def _forward_local(cfg, seeds, q, k, v):
+    """Forward kernel over local arrays -> (out (B,H,Tq,D), lse
+    (B,H,Tq padded to the q block))."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    scale = sm_scale if sm_scale is not None else 1.0 / (D ** 0.5)
-    block_q = min(block_q, Tq)
-    block_k = min(block_k, Tk)
-    qf, kf, vf, nq, nk, pad_q, _pad_k = _pad_pack(q, k, v, block_q, block_k)
+    block_q, block_k = min(cfg.block_q, Tq), min(cfg.block_k, Tk)
+    qf, kf, vf, nq, _nk, pad_q, _pad_k = _pad_pack(q, k, v, block_q,
+                                                   block_k)
     Tk_pad = kf.shape[1]
 
     kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, seq_k=Tk, dropout_p=dropout_p,
-        interpret=interpret)
+        _flash_kernel, scale=cfg.scale, causal=cfg.causal, block_q=block_q,
+        block_k=block_k, seq_k=Tk, dropout_p=cfg.dropout_p,
+        interpret=cfg.interpret)
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, nq),
@@ -418,41 +489,25 @@ def _flash_forward(q, k, v, seed, causal, sm_scale, block_q, block_k,
             jax.ShapeDtypeStruct((B * H, nq * block_q, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, nq, 8, block_q), jnp.float32),
         ],
-        interpret=interpret,
-    )(seed, qf, kf, vf)
-    lse = lse[:, :, 0, :].reshape(B * H, nq * block_q)
-    outr = out.reshape(B, H, nq * block_q, D)
-    if pad_q:
-        outr = outr[:, :, :Tq]
-    if want_lse:
-        return outr, lse
-    return outr
+        interpret=cfg.interpret,
+        compiler_params=_vmem_params(cfg, Tq, Tk, q),
+        name="flash_fwd",
+    )(seeds.reshape(B * H), qf, kf, vf)
+    lse = lse[:, :, 0, :].reshape(B, H, nq * block_q)
+    out = out.reshape(B, H, nq * block_q, D)
+    return (out[:, :, :Tq] if pad_q else out), lse
 
 
-def _flash_backward(q, k, v, seed, out, lse, do, causal, scale, block_q,
-                    block_k, interpret, dropout_p, dlse=None):
+def _backward_local(cfg, seeds, q, k, v, do, lse, delta):
+    """dq and dk/dv kernels over local arrays; ``lse``/``delta`` are
+    (B, H, Tq padded to the q block)."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    block_q = min(block_q, Tq)
-    block_k = min(block_k, Tk)
-    qf, kf, vf, nq, nk, pad_q, pad_k = _pad_pack(q, k, v, block_q, block_k)
+    block_q, block_k = min(cfg.block_q, Tq), min(cfg.block_k, Tk)
+    qf, kf, vf, nq, nk, pad_q, _pad_k = _pad_pack(q, k, v, block_q, block_k)
     Tq_pad, Tk_pad = qf.shape[1], kf.shape[1]
     dof = jnp.pad(do, ((0, 0), (0, 0), (0, pad_q), (0, 0))) if pad_q else do
     dof = dof.reshape(B * H, Tq_pad, D)
-    # Δ = rowsum(dO ∘ O) — one cheap fused XLA reduction, fed to both
-    # kernels (padded rows contribute zeros via the padded dO)
-    outf = (jnp.pad(out, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
-            if pad_q else out).reshape(B * H, Tq_pad, D)
-    delta = jnp.sum(dof.astype(jnp.float32) * outf.astype(jnp.float32),
-                    axis=-1)                           # (B*H, Tq_pad)
-    if dlse is not None:
-        # lse cotangent folds into the delta term: the softmax backward is
-        # ds = p·(dp − Δ) and ∂lse/∂s = p, so ds = p·(dp − (Δ − dlse)) —
-        # the kernels need no change to support flash_attention_lse
-        dlf = jnp.pad(dlse.reshape(B * H, Tq),
-                      ((0, 0), (0, pad_q))) if pad_q \
-            else dlse.reshape(B * H, Tq)
-        delta = delta - dlf.astype(jnp.float32)
 
     # widen lse/delta rows to the (nq, 8, block_q) tile layout the kernels
     # read (see _flash_kernel's lse note)
@@ -462,12 +517,14 @@ def _flash_backward(q, k, v, seed, out, lse, do, causal, scale, block_q,
 
     lse4 = _widen(lse)
     delta4 = _widen(delta)
+    seedf = seeds.reshape(B * H)
 
     smem_spec = _smem_spec()
+    params = _vmem_params(cfg, Tq, Tk, q)
     dq_kernel = functools.partial(
-        _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, seq_k=Tk, dropout_p=dropout_p,
-        interpret=interpret)
+        _bwd_dq_kernel, scale=cfg.scale, causal=cfg.causal, block_q=block_q,
+        block_k=block_k, seq_k=Tk, dropout_p=cfg.dropout_p,
+        interpret=cfg.interpret)
     dq = pl.pallas_call(
         dq_kernel,
         grid=(B * H, nq),
@@ -482,13 +539,15 @@ def _flash_backward(q, k, v, seed, out, lse, do, causal, scale, block_q,
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Tq_pad, D), q.dtype),
-        interpret=interpret,
-    )(seed, qf, kf, vf, dof, lse4, delta4)
+        interpret=cfg.interpret,
+        compiler_params=params,
+        name="flash_bwd_dq",
+    )(seedf, qf, kf, vf, dof, lse4, delta4)
 
     dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, seq_q=Tq, seq_k=Tk, dropout_p=dropout_p,
-        interpret=interpret)
+        _bwd_dkv_kernel, scale=cfg.scale, causal=cfg.causal,
+        block_q=block_q, block_k=block_k, seq_q=Tq, seq_k=Tk,
+        dropout_p=cfg.dropout_p, interpret=cfg.interpret)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(B * H, nk),
@@ -509,8 +568,10 @@ def _flash_backward(q, k, v, seed, out, lse, do, causal, scale, block_q,
             jax.ShapeDtypeStruct((B * H, Tk_pad, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, Tk_pad, D), v.dtype),
         ],
-        interpret=interpret,
-    )(seed, qf, kf, vf, dof, lse4, delta4)
+        interpret=cfg.interpret,
+        compiler_params=params,
+        name="flash_bwd_dkv",
+    )(seedf, qf, kf, vf, dof, lse4, delta4)
 
     dq = dq.reshape(B, H, Tq_pad, D)[:, :, :Tq]
     dk = dk.reshape(B, H, Tk_pad, D)[:, :, :Tk]
@@ -518,45 +579,67 @@ def _flash_backward(q, k, v, seed, out, lse, do, causal, scale, block_q,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_core(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                interpret, dropout_p):
-    return _flash_forward(q, k, v, seed, causal, sm_scale, block_q,
-                          block_k, interpret, dropout_p)
+def _forward_call(cfg, seeds, q, k, v):
+    return _over_rows(_forward_local, (4, 3), cfg, seeds, q, k, v)
 
 
-def _flash_core_fwd(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                    interpret, dropout_p):
-    out, lse = _flash_forward(q, k, v, seed, causal, sm_scale, block_q,
-                              block_k, interpret, dropout_p, want_lse=True)
-    return out, (q, k, v, seed, out, lse)
+def _flash_backward(cfg, seeds, q, k, v, out, lse, do, dlse=None):
+    # Δ = rowsum(dO ∘ O) — one cheap fused XLA reduction, fed to both
+    # kernels
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)                           # (B, H, Tq)
+    if dlse is not None:
+        # lse cotangent folds into the delta term: the softmax backward is
+        # ds = p·(dp − Δ) and ∂lse/∂s = p, so ds = p·(dp − (Δ − dlse)) —
+        # the kernels need no change to support flash_attention_lse
+        delta = delta - dlse.astype(jnp.float32)
+    pad_q = lse.shape[2] - delta.shape[2]
+    if pad_q:       # padded rows contribute zeros (their dO is padding too)
+        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q)))
+    return _over_rows(_backward_local, (4, 4, 4), cfg, seeds, q, k, v, do,
+                      lse, delta)
 
 
-def _flash_core_bwd(causal, sm_scale, block_q, block_k, interpret,
-                    dropout_p, res, do):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash_core(cfg, seeds, q, k, v):
+    return _forward_call(cfg, seeds, q, k, v)[0]
+
+
+def _flash_core_fwd(cfg, seeds, q, k, v):
+    out, lse = _forward_call(cfg, seeds, q, k, v)
+    return out, (seeds, q, k, v, out, lse)
+
+
+def _flash_core_bwd(cfg, res, do):
     import numpy as _onp
 
-    q, k, v, seed, out, lse = res
-    D = q.shape[-1]
-    scale = sm_scale if sm_scale is not None else 1.0 / (D ** 0.5)
-    dq, dk, dv = _flash_backward(q, k, v, seed, out, lse, do, causal,
-                                 scale, block_q, block_k, interpret,
-                                 dropout_p)
-    dseed = _onp.zeros((1,), jax.dtypes.float0)   # int seed: zero cotangent
-    return dq, dk, dv, dseed
+    seeds, q, k, v, out, lse = res
+    dq, dk, dv = _flash_backward(cfg, seeds, q, k, v, out, lse, do)
+    # int seeds: zero cotangent
+    return _onp.zeros(seeds.shape, jax.dtypes.float0), dq, dk, dv
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
+def _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
+             dropout_p=0.0):
+    """The static kernel configuration of one call: interpret-or-compile
+    resolved from the backend, the default softmax scale, and the
+    fast-memory check made before anything is traced."""
+    interpret = _default_interpret() if interpret is None else interpret
+    scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
+    _check_vmem(q, k.shape[2], block_q, block_k, interpret)
+    return _Cfg(bool(causal), float(scale), int(block_q), int(block_k),
+                bool(interpret), float(dropout_p))
+
+
 def _flash_lse_impl(q, k, v, causal, sm_scale, block_q, block_k,
                     interpret):
-    interpret = _default_interpret() if interpret is None else interpret
-    seed = jnp.zeros((1,), jnp.int32)
-    out, lse = _flash_forward(q, k, v, seed, causal, sm_scale, block_q,
-                              block_k, interpret, 0.0, want_lse=True)
-    B, H, Tq, _D = q.shape
-    return out, lse.reshape(B, H, -1)[:, :, :Tq]
+    cfg = _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret)
+    seeds = jnp.zeros(q.shape[:2], jnp.int32)
+    out, lse = _forward_call(cfg, seeds, q, k, v)
+    return out, lse[:, :, :q.shape[2]]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -580,23 +663,36 @@ def _flash_lse_bwd(causal, sm_scale, block_q, block_k, interpret, res,
                    cts):
     q, k, v, out, lse = res
     do, dlse = cts
-    interpret = _default_interpret() if interpret is None else interpret
-    D = q.shape[-1]
-    scale = sm_scale if sm_scale is not None else 1.0 / (D ** 0.5)
-    B, H, Tq, _ = q.shape
+    cfg = _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret)
+    Tq = q.shape[2]
     bq = min(block_q, Tq)
-    nq = -(-Tq // bq)
-    lse_flat = jnp.pad(lse, ((0, 0), (0, 0), (0, nq * bq - Tq))) \
-        .reshape(B * H, nq * bq) if nq * bq != Tq \
-        else lse.reshape(B * H, Tq)
-    seed = jnp.zeros((1,), jnp.int32)
-    dq, dk, dv = _flash_backward(q, k, v, seed, out, lse_flat, do, causal,
-                                 scale, block_q, block_k, interpret, 0.0,
-                                 dlse=dlse)
-    return dq, dk, dv
+    pad_q = -(-Tq // bq) * bq - Tq
+    if pad_q:
+        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q)))
+    seeds = jnp.zeros(q.shape[:2], jnp.int32)
+    return _flash_backward(cfg, seeds, q, k, v, out, lse, do, dlse=dlse)
 
 
 flash_attention_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+def _bh_seeds(dropout_key, B, H):
+    """(B, H) int32 seed words: the dropout key hashed with the GLOBAL
+    (batch, head) index.  Built outside the kernels, so under a mesh
+    every device is handed its own rows and the masks do not depend on
+    how B·H is sharded."""
+    # fold ALL key words: threefry key_data for PRNGKey(s), s < 2^32 is
+    # [0, s] — taking only word 0 would give every such key the same mask
+    kd = jax.random.key_data(dropout_key).reshape(-1).astype(jnp.uint32)
+    x = jnp.bitwise_xor(kd[0] * jnp.uint32(2654435761), kd[-1]) \
+        if kd.shape[0] > 1 else kd[0]
+    bh = jax.lax.broadcasted_iota(jnp.uint32, (B, H), 0) * jnp.uint32(H) \
+        + jax.lax.broadcasted_iota(jnp.uint32, (B, H), 1)
+    x = x ^ (bh * jnp.uint32(0x9E3779B9))
+    # murmur3 finalizer: neighbouring (b, h) must not get neighbouring seeds
+    x = (x ^ (x >> 16)) * jnp.uint32(0x85EBCA6B)
+    x = (x ^ (x >> 13)) * jnp.uint32(0xC2B2AE35)
+    return jax.lax.bitcast_convert_type(x ^ (x >> 16), jnp.int32)
 
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
@@ -608,41 +704,118 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
     ``flash_attention`` winner for this workload (the hand-set 512/512
     literals when autotune is off or cold); explicit values always win.
 
-    Forward AND backward run Pallas kernels (interpret mode off-TPU): the
-    backward recomputes per-block probabilities from the saved logsumexp —
-    residual memory stays O(T·D), and dq/dk/dv are back-to-back MXU
-    matmuls (the fused equivalent the reference lacks; its
-    interleaved_matmul kernels are fwd-only, transformer.cc:650-826).
+    Forward AND backward run Pallas kernels (interpreted on the CPU
+    backend): the backward recomputes per-block probabilities from the
+    saved logsumexp — residual memory stays O(T·D), and dq/dk/dv are
+    back-to-back MXU matmuls (the fused equivalent the reference lacks;
+    its interleaved_matmul kernels are fwd-only, transformer.cc:650-826).
     Attention-probability dropout runs IN-kernel from the TPU PRNG: the
     per-tile mask is regenerated — never stored — in fwd, dq and dkv
-    passes, seeded by (key, bh, q-block, k-block)."""
+    passes, seeded by (key ⊕ batch·head, q-block·k-block).
+
+    Traced inside a program jitted over a mesh, the kernels need the
+    engine's ``mesh_rows`` declaration (Mosaic kernels cannot be
+    partitioned automatically): every device then runs them on its own
+    batch rows.  A shape whose per-head K/V (or Q/dO) does not fit the
+    kernels' fast memory (``flash_vmem_bytes``) raises ``MXNetError``
+    when compiled for the chip; ``multi_head_attention(impl="auto")``
+    never picks such a shape."""
     block_q, block_k = _tuned_flash_blocks(q, k, causal, block_q, block_k,
                                            dropout_p=float(dropout_p))
-    interpret = _default_interpret() if interpret is None else interpret
+    cfg = _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
+                   dropout_p)
+    B, H = q.shape[:2]
     if dropout_p > 0.0:
         if dropout_key is None:
             raise ValueError("flash_attention: dropout_p > 0 requires "
                              "dropout_key")
-        # fold ALL key words into the seed: threefry key_data for
-        # PRNGKey(s), s < 2^32 is [0, s] — taking only word 0 would give
-        # every such key the same mask
-        kd = jax.random.key_data(dropout_key).reshape(-1)
-        folded = jnp.bitwise_xor(kd[0] * jnp.uint32(2654435761),
-                                 kd[-1]) if kd.shape[0] > 1 else kd[0]
-        seed = folded.astype(jnp.int32).reshape(1)
+        seeds = _bh_seeds(dropout_key, B, H)
     else:
-        seed = jnp.zeros((1,), jnp.int32)
-    return _flash_core(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                       interpret, float(dropout_p))
+        seeds = jnp.zeros((B, H), jnp.int32)
+    return _flash_core(cfg, seeds, q, k, v)
 
 
 def _default_interpret():
-    return jax.default_backend() != "tpu"
+    """Interpret the kernels on the CPU backend (tests), compile them on
+    the TPU; any other backend has no lowering and is an error, not a
+    quiet interpreter."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise MXNetError(
+            "flash_attention: Pallas TPU kernels lower for the 'tpu' "
+            "backend and are interpreted on 'cpu'; the default backend is "
+            "%r — pass impl='flash' (blockwise) or impl='dense'" % backend)
+    return backend == "cpu"
 
 
-def use_flash(seq_q, seq_k, head_dim, has_mask):
+# ---------------------------------------------------------------------------
+# fast-memory envelope
+# ---------------------------------------------------------------------------
+# Mosaic gives a kernel 16 MiB of scoped VMEM unless it asks for more; a
+# v5e core has 128 MiB, of which the kernels may ask for this much.
+VMEM_DEFAULT_BYTES = 16 << 20
+VMEM_BUDGET_BYTES = 96 << 20
+
+
+def flash_vmem_bytes(seq_q, seq_k, head_dim, itemsize,
+                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+    """Scoped VMEM the hungriest of the three kernels needs, in bytes —
+    an upper estimate, checked against the v5e compiler in
+    tests/python/unittest/test_chip_compile.py.
+
+    The forward and dq kernels keep one head's whole K and V resident
+    (``(Tk, D)`` blocks), the dkv kernel one head's whole Q and dO plus
+    the lse/Δ rows in their 8-sublane layout; Pallas double-buffers every
+    operand block, and a row of D < 128 still fills a 128-lane tile.  On
+    top of the operands comes the f32 working set of one (block_q,
+    block_k) tile: scores, probabilities, mask and their products."""
+    lanes = -(-head_dim // 128) * 128
+    block_q, block_k = min(block_q, seq_q), min(block_k, seq_k)
+    pad = lambda t, b: -(-t // b) * b  # noqa: E731
+    row = lanes * itemsize
+    resident_kv = 2 * 2 * pad(seq_k, block_k) * row
+    resident_q = 2 * 2 * pad(seq_q, block_q) * (row + 8 * 4)
+    blocks = 2 * 4 * max(block_q, block_k) * row
+    tile = 6 * block_q * block_k * 4 + 4 * (block_q + block_k) * lanes * 4
+    return max(resident_kv, resident_q) + blocks + tile
+
+
+def _vmem_params(cfg, seq_q, seq_k, q):
+    """Ask the compiler for the scoped VMEM the shape needs where the
+    default would not do (long T: a head's whole K/V is resident)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    need = flash_vmem_bytes(seq_q, seq_k, q.shape[-1], q.dtype.itemsize,
+                            cfg.block_q, cfg.block_k)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=need if need > VMEM_DEFAULT_BYTES else None)
+
+
+def _check_vmem(q, seq_k, block_q, block_k, interpret):
+    """Raise the repo's own error before Mosaic's "scoped allocation ...
+    exceeded scoped vmem limit" does.  Only a compile for the chip has a
+    limit to exceed; the interpreter has no VMEM."""
+    if interpret:
+        return
+    need = flash_vmem_bytes(q.shape[2], seq_k, q.shape[3],
+                            q.dtype.itemsize, block_q, block_k)
+    if need > VMEM_BUDGET_BYTES:
+        raise MXNetError(
+            "flash_attention: Tq=%d Tk=%d D=%d %s needs ~%d MiB of VMEM "
+            "(the kernels keep a head's whole K/V resident), over the "
+            "%d MiB the kernels may take; use impl='flash' (blockwise) or "
+            "shorter sequences per call (ring_attention shards T)"
+            % (q.shape[2], seq_k, q.shape[3], q.dtype, need >> 20,
+               VMEM_BUDGET_BYTES >> 20))
+
+
+def use_flash(seq_q, seq_k, head_dim, has_mask, itemsize=4):
     """Dispatch heuristic for impl='auto': flash pays off once the score
-    matrix no longer fits the fusion footprint; dense einsum wins short-T."""
+    matrix no longer fits the fusion footprint; dense einsum wins short-T.
+    A shape outside the kernels' fast-memory envelope is never sent to
+    them (``itemsize`` defaults to the float32 worst case)."""
     if has_mask:
         return False
-    return seq_q * seq_k >= 256 * 256 and head_dim <= 256
+    return seq_q * seq_k >= 256 * 256 and head_dim <= 256 and \
+        flash_vmem_bytes(seq_q, seq_k, head_dim, itemsize) \
+        <= VMEM_BUDGET_BYTES

@@ -17,6 +17,9 @@ collectives over ICI:
 """
 from __future__ import annotations
 
+import contextlib
+import functools
+
 import numpy as _np
 
 import jax
@@ -314,6 +317,15 @@ class FusedTrainer:
         from ..contrib.amp import FP32_PARAM_SUFFIXES as _fp32_sufs
 
         user_loss = self._user_loss
+        mesh = self._mesh
+        if mesh is None:
+            rows = contextlib.nullcontext
+        else:
+            # Pallas kernels cannot be partitioned automatically: tell
+            # them, while the step traces, which axes split the batch
+            from ..ops.pallas_attention import mesh_rows
+
+            rows = functools.partial(mesh_rows, mesh, self._batch_axes)
 
         def cast_in(full, xs):
             """Mixed-precision boundary: cast f32 weights + inputs to the
@@ -347,7 +359,11 @@ class FusedTrainer:
                 loss = loss_fn(outs[0], ys[0])
             return jnp.mean(loss), new_states
 
-        def step(params, opt_state, step_i, lr_t, rng, xs, ys):
+        def step(*args):
+            with rows():
+                return step_body(*args)
+
+        def step_body(params, opt_state, step_i, lr_t, rng, xs, ys):
             train_p = {n: v for n, v in params.items() if n in trainable}
             frozen = {n: v for n, v in params.items() if n not in trainable}
             vg = jax.value_and_grad(loss_of, has_aux=True)
@@ -421,30 +437,20 @@ class FusedTrainer:
                     self._state_specs,
                     is_leaf=lambda s: isinstance(s, P))
                 out_state_sh = state_sh
-            with self._mesh:
-                self._step_fn = jax.jit(
-                    step,
-                    in_shardings=(param_sh, state_sh, None, None, None,
-                                  NamedSharding(self._mesh, batch_spec),
-                                  NamedSharding(self._mesh, batch_spec)),
-                    out_shardings=(param_sh, out_state_sh, None),
-                    donate_argnums=(0, 1))
+            self._step_fn = jax.jit(
+                step,
+                in_shardings=(param_sh, state_sh, None, None, None,
+                              NamedSharding(self._mesh, batch_spec),
+                              NamedSharding(self._mesh, batch_spec)),
+                out_shardings=(param_sh, out_state_sh, None),
+                donate_argnums=(0, 1))
         else:
             self._step_fn = jax.jit(step, donate_argnums=(0, 1))
 
     # -- public -------------------------------------------------------------
-    def step(self, x, y):
-        """One fused training step.  ``x``/``y`` may each be a single array
-        or a tuple (multi-input models / multi-label losses); all leading
-        dims are the batch."""
-        from .. import random as mxrandom
-        from ..resilience import inject as _inject
-
-        # mx.resilience drill site: fires BEFORE the donated launch, so
-        # a faulted step leaves params/opt_state untouched and the
-        # supervisor's restore-and-replay is exact
-        _inject.fire("trainer_step", seq=self._step_count)
-
+    def _stage(self, x, y):
+        """(xs, ys) tuples of jax arrays for the step program, built (and
+        the program with them) on first use, batch-sharded on a mesh."""
         def as_jax(v):
             return v._data if isinstance(v, NDArray) else jnp.asarray(v)
 
@@ -459,6 +465,20 @@ class FusedTrainer:
             # with the jitted in_shardings; reshard onto the batch axes
             xs = tuple(jax.device_put(v, self._batch_sharding) for v in xs)
             ys = tuple(jax.device_put(v, self._batch_sharding) for v in ys)
+        return xs, ys
+
+    def step(self, x, y):
+        """One fused training step.  ``x``/``y`` may each be a single array
+        or a tuple (multi-input models / multi-label losses); all leading
+        dims are the batch."""
+        from .. import random as mxrandom
+        from ..resilience import inject as _inject
+
+        # mx.resilience drill site: fires BEFORE the donated launch, so
+        # a faulted step leaves params/opt_state untouched and the
+        # supervisor's restore-and-replay is exact
+        _inject.fire("trainer_step", seq=self._step_count)
+        xs, ys = self._stage(x, y)
         rng = mxrandom.take_key()
         # reference num_update starts at 1 (_update_count increments
         # before _get_lr, optimizer.py:100) — keep the same phase
@@ -469,6 +489,15 @@ class FusedTrainer:
             jnp.float32(lr_t), rng, xs, ys)
         self._step_count += 1
         return NDArray(loss)
+
+    def _lower(self, x, y):
+        """The step program lowered for a batch like ``(x, y)`` — a
+        ``jax.stages.Lowered`` for ``chip_smoke.py`` to read (the kernels
+        and collectives in it) without taking a step."""
+        xs, ys = self._stage(x, y)
+        return self._step_fn.lower(
+            self._params, self._opt_state, jnp.uint32(0), jnp.float32(0),
+            jax.random.PRNGKey(0), xs, ys)
 
     def sync_block(self):
         """Write the trained params back into the Gluon block (gathering

@@ -1,23 +1,12 @@
-"""jax.shard_map version-compat shim shared by pipeline/moe/ring paths."""
+"""The relaxed ``jax.shard_map`` shared by the pipeline/moe/ring paths."""
 from __future__ import annotations
 
-import inspect
-
-try:
-    from jax import shard_map as _shard_map_fn
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
-_PARAMS = inspect.signature(_shard_map_fn).parameters
+import jax
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map with the replication check disabled under whichever
-    keyword this jax version spells it (psum-of-partial outputs are not
-    'replicated' in the varying-manual-axes sense the checker wants)."""
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if "check_vma" in _PARAMS:
-        kw["check_vma"] = False
-    elif "check_rep" in _PARAMS:
-        kw["check_rep"] = False
-    return _shard_map_fn(fn, **kw)
+    """shard_map with the varying-manual-axes check off (psum-of-partial
+    outputs are not 'replicated' in the sense the checker wants, and
+    pallas_call outputs carry no annotation at all)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -23,11 +23,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["ring_attention", "ulysses_attention", "local_attention_block"]
@@ -157,8 +153,8 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
 
     NB impl='flash' inside a CALLER-managed shard_map: pallas_call outputs
     carry no varying-axes annotation, so the enclosing shard_map must be
-    created with ``check_vma=False`` (``check_rep=False`` on older jax) —
-    the mesh= path below does this automatically."""
+    created with ``check_vma=False`` — the mesh= path below does this
+    automatically."""
     body = functools.partial(_ring_attn_sharded, axis_name=axis_name,
                              causal=causal, scale=scale, impl=impl,
                              block=block)
@@ -171,7 +167,7 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
     spec = P(None, None, axis_name, None)
     if impl == "flash":
         # pallas_call's out_shape carries no vma annotation; use the
-        # version-portable relaxed shard_map (shared shim, _smap.py)
+        # relaxed shard_map (_smap.py)
         from ._smap import shard_map_compat
 
         sm = shard_map_compat(body, mesh=mesh,

@@ -36,7 +36,7 @@ Plan grammar (comma-separated entries)::
 Kinds: ``transient`` (default, ``InjectedFault`` — classified
 transient by the supervisor), ``io`` (``InjectedIOError``, an
 ``OSError`` so retry-with-backoff paths engage), ``fatal``
-(``InjectedFault`` the taxonomy refuses to retry), ``abort``
+(``InjectedFault`` the classification refuses to retry), ``abort``
 (``os._exit`` — simulates SIGKILL mid-operation; cleanup handlers
 never run, exactly like a preempted node).
 
@@ -70,7 +70,7 @@ ABORT_EXIT_CODE = 77
 
 class InjectedFault(MXNetError):
     """A planned fault.  ``kind`` is ``transient`` or ``fatal`` — the
-    supervisor's taxonomy routes on it."""
+    supervisor's classification routes on it."""
 
     def __init__(self, msg, kind="transient", site=None, key=None):
         super().__init__(msg)

@@ -72,7 +72,7 @@ def fail_request(req, exc, result):
     Shared by the micro-batch scheduler AND the decode path: anything
     with the ``Request`` resolution surface (``future`` / ``enqueued``
     / ``trace`` / ``request_id``) resolves through here so the
-    ``serve_requests_total{result=...}`` taxonomy and the per-request
+    ``serve_requests_total{result=...}`` classification and the per-request
     root trace span stay consistent across both serving planes."""
     try:
         req.future.set_exception(exc)

@@ -361,6 +361,16 @@ class DecodeRunner:
             self.step = block.load_checkpoint(root, step=step, ctx=ctx)
         self._resolve_params()
         self._apply_fn, self._params = block.export_pure(training=False)
+        device = None
+        if ctx is not None:
+            # a runner given a context keeps weights, probe inputs and
+            # the KV pool on that device (numpy step inputs follow the
+            # committed weights)
+            import jax
+
+            device = ctx.jax_device
+            self._params = {n: jax.device_put(v, device)
+                            for n, v in self._params.items()}
         # mx.shard phase 2: a model sharded over the mesh's mdl axis.
         # Parameters are STORED per the layout table (1/mdl per device)
         # and each program constrains them in-program: gather mode
@@ -406,7 +416,8 @@ class DecodeRunner:
             c.page_size, c.pool_pages, block.num_layers,
             block.num_kv_heads, block.head_dim, c.max_context,
             dtype=c.dtype)
-        self.pool = PagePool(self.page_config, mesh=self.mesh)
+        self.pool = PagePool(self.page_config, mesh=self.mesh,
+                             device=device)
         self._programs = {}
         self._run_lock = threading.RLock()
         self._warmed = False
@@ -431,12 +442,12 @@ class DecodeRunner:
         ``export_pure`` (the contract signature with S=0, T=1)."""
         from .. import ndarray as nd
 
-        b = self._block
+        b, ctx = self._block, self._ctx
         zero_ctx = nd.zeros((1, b.num_layers, 0, b.num_kv_heads,
-                             b.head_dim), dtype=self.config.dtype)
-        ones = nd.array(_np.array([1], dtype="int32"))
-        self._block(nd.zeros((1, 1), dtype="int32"), zero_ctx, zero_ctx,
-                    nd.zeros((1,), dtype="int32"), ones)
+                             b.head_dim), dtype=self.config.dtype, ctx=ctx)
+        ones = nd.array(_np.array([1], dtype="int32"), ctx=ctx)
+        self._block(nd.zeros((1, 1), dtype="int32", ctx=ctx), zero_ctx,
+                    zero_ctx, nd.zeros((1,), dtype="int32", ctx=ctx), ones)
 
     @property
     def block(self):
@@ -685,47 +696,40 @@ class DecodeRunner:
         if self.mesh is not None:
             fn = self._mesh_wrap(fn)
         jitted = jax.jit(fn, donate_argnums=(1, 2))
-        provenance = "fresh"
-        compiled = None
-        try:
-            if self.mesh is None:
-                aval = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
-            else:
-                # committed mesh layouts are part of the program
-                # signature: the compiled executable must expect the
-                # sharded params/pool it will be fed
-                aval = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-                    a.shape, a.dtype, sharding=getattr(a, "sharding",
-                                                       None))
-            params_avals = jax.tree_util.tree_map(aval, self._params)
-            c = self.page_config
-            pool_aval = jax.ShapeDtypeStruct(
-                (c.num_layers, c.num_pages, c.page_size, c.num_kv_heads,
-                 c.head_dim), _np.dtype(c.dtype),
-                sharding=self.pool.sharding)
-            i32 = _np.dtype("int32")
-            avals = [params_avals, pool_aval, pool_aval,
-                     jax.ShapeDtypeStruct((batch, chunk), i32),
-                     jax.ShapeDtypeStruct((batch, c.pages_per_seq), i32),
-                     jax.ShapeDtypeStruct((batch,), i32),
-                     jax.ShapeDtypeStruct((batch,), i32)]
-            if with_floors:
-                avals.append(jax.ShapeDtypeStruct((batch,), i32))
-            if self.bank is not None:
-                # adapter index + flat bank tuple (mx.tenant): bank
-                # shapes are part of the program fingerprint, so a
-                # restored cache entry matches only an identically
-                # shaped bank
-                avals.append(jax.ShapeDtypeStruct((batch,), i32))
-                avals.append(tuple(self.bank.avals()))
-            lowered = jitted.lower(*avals)
-            from ..compile.aot import attach_lowered
+        # committed layouts (a mesh, or the runner's own device) are
+        # part of the program signature: the compiled executable must
+        # expect the params/pool where it will be fed them
+        placed = self.mesh is not None or self._ctx is not None
 
-            compiled, _fp, provenance = attach_lowered(
-                lowered, type(self._block).__name__ + ".decode_step",
-                label)
-        except Exception:
-            compiled = None  # lazy jit path below; still one compile
+        def aval(a):
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=a.sharding if placed else None)
+
+        i32 = _np.dtype("int32")
+        avals = [jax.tree_util.tree_map(aval, self._params),
+                 aval(self.pool.k), aval(self.pool.v),
+                 jax.ShapeDtypeStruct((batch, chunk), i32),
+                 jax.ShapeDtypeStruct(
+                     (batch, self.page_config.pages_per_seq), i32),
+                 jax.ShapeDtypeStruct((batch,), i32),
+                 jax.ShapeDtypeStruct((batch,), i32)]
+        if with_floors:
+            avals.append(jax.ShapeDtypeStruct((batch,), i32))
+        if self.bank is not None:
+            # adapter index + flat bank tuple (mx.tenant): bank shapes
+            # are part of the program fingerprint, so a restored cache
+            # entry matches only an identically shaped bank
+            avals.append(jax.ShapeDtypeStruct((batch,), i32))
+            avals.append(tuple(self.bank.avals()))
+        from ..compile.aot import attach_lowered
+
+        # a program that cannot be lowered fails HERE, at warm-up — not
+        # later behind a lazy jit (attach_lowered itself degrades every
+        # cache failure to a plain compile and returns None only when
+        # even that raised)
+        compiled, _fp, provenance = attach_lowered(
+            jitted.lower(*avals),
+            type(self._block).__name__ + ".decode_step", label)
         prog = _Program(compiled if compiled is not None else jitted,
                         label, provenance)
         self._programs[key] = prog
@@ -818,20 +822,7 @@ class DecodeRunner:
             raise
         except BaseException as exc:  # noqa: BLE001 - classified below
             if getattr(kp, "is_deleted", lambda: False)():
-                import jax.numpy as jnp
-
-                c = self.page_config
-                shape = (c.num_layers, c.num_pages, c.page_size,
-                         c.num_kv_heads, c.head_dim)
-                self.pool.k = jnp.zeros(shape, dtype=c.dtype)
-                self.pool.v = jnp.zeros(shape, dtype=c.dtype)
-                if self.pool.sharding is not None:
-                    import jax
-
-                    self.pool.k = jax.device_put(self.pool.k,
-                                                 self.pool.sharding)
-                    self.pool.v = jax.device_put(self.pool.v,
-                                                 self.pool.sharding)
+                self.pool.reset_storage()
                 err = DecodeError(
                     "decode step failed AFTER pool donation; KV storage "
                     "lost, all live sequences must restart: %r" % (exc,))
@@ -2110,7 +2101,7 @@ class TinyDecoder(_HybridBlock):
         half = self.units // 2
         inv = nd.array(_np.asarray(
             1.0 / (10000.0 ** (_np.arange(half) / max(1, half))),
-            dtype="float32"))
+            dtype="float32"), ctx=positions.context)
         ang = positions.expand_dims(2) * inv.reshape((1, 1, half))
         return nd.concat(nd.sin(ang), nd.cos(ang), dim=2)
 
@@ -2121,7 +2112,8 @@ class TinyDecoder(_HybridBlock):
         S = k_ctx.shape[2]
         H, Dh, C = self.num_kv_heads, self.head_dim, self.units
         ctx_f = ctx_lengths.astype("float32").expand_dims(1)     # [B,1]
-        steps = nd.arange(T, dtype="float32").expand_dims(0)     # [1,T]
+        steps = nd.arange(T, dtype="float32",
+                          ctx=tokens.context).expand_dims(0)      # [1,T]
         q_pos = ctx_f + steps                                    # [B,T]
         x = self.embed(tokens) + self._positional(q_pos)
 
@@ -2131,7 +2123,8 @@ class TinyDecoder(_HybridBlock):
         # — queries past chunk_length produce garbage that is never
         # read: their K/V scatter is dropped and the last-logit
         # selector picks index chunk_length-1)
-        key_ctx_pos = nd.arange(S, dtype="float32").expand_dims(0)
+        key_ctx_pos = nd.arange(S, dtype="float32",
+                                ctx=tokens.context).expand_dims(0)
         ctx_valid = (key_ctx_pos < ctx_f).astype("float32")       # [B,S]
         # invalid context keys take position +1e9 so they FAIL the
         # causal test below (key_pos <= q_pos) and are masked out; a

@@ -123,22 +123,19 @@ class PagePool:
     host-side and lock-protected (admission runs on submitter threads,
     release on the decode loop)."""
 
-    def __init__(self, config, mesh=None):
-        import jax.numpy as jnp
-
+    def __init__(self, config, mesh=None, device=None):
         self.config = config
         c = config
-        shape = (c.num_layers, c.num_pages, c.page_size,
-                 c.num_kv_heads, c.head_dim)
         # mx.shard phase 2: on a mesh with an mdl axis the pool shards
         # over the KV-HEAD axis (per-head attention state is
         # independent, so a head split never slices a page row) — each
         # device holds 1/mdl of the cache, which is what makes
         # multi-chip decode residency real.  Indivisible head counts
-        # stay replicated (correct, just not smaller).
+        # stay replicated (correct, just not smaller).  Without a mesh
+        # the pool lives on ``device`` (None: JAX's default device).
         self.sharding = None
+        self._device = device
         if mesh is not None:
-            import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             raw = getattr(mesh, "mesh", mesh)   # GlobalMesh or raw Mesh
@@ -147,13 +144,7 @@ class PagePool:
             spec = P(None, None, None, "mdl", None) \
                 if mdl > 1 and c.num_kv_heads % mdl == 0 else P()
             self.sharding = NamedSharding(raw, spec)
-            self.k = jax.device_put(jnp.zeros(shape, dtype=c.dtype),
-                                    self.sharding)
-            self.v = jax.device_put(jnp.zeros(shape, dtype=c.dtype),
-                                    self.sharding)
-        else:
-            self.k = jnp.zeros(shape, dtype=c.dtype)
-            self.v = jnp.zeros(shape, dtype=c.dtype)
+        self.reset_storage()
         self._lock = threading.Lock()
         self._free = list(range(c.num_pages - 1, -1, -1))  # pop() -> 0,1,2..
         self._owned = {}                 # owner -> [page ids]
@@ -161,6 +152,19 @@ class PagePool:
         self.high_water = 0
         self.alloc_total = 0
         self.oom_rejects = 0
+
+    def reset_storage(self):
+        """(Re)allocate zeroed K/V storage where the pool lives: on its
+        mesh sharding, its device, or JAX's default device."""
+        import jax.numpy as jnp
+
+        c = self.config
+        shape = (c.num_layers, c.num_pages, c.page_size,
+                 c.num_kv_heads, c.head_dim)
+        where = self.sharding if self.sharding is not None \
+            else self._device
+        self.k = jnp.zeros(shape, dtype=c.dtype, device=where)
+        self.v = jnp.zeros(shape, dtype=c.dtype, device=where)
 
     # -- accounting ---------------------------------------------------------
     @property

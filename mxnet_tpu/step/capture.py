@@ -53,6 +53,7 @@ unconditionally.
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import time as _time
 
@@ -964,6 +965,15 @@ class StepProgram:
         state_shardings = cap.state_shardings
         forward_shardings = cap.forward_shardings
         replicated = cap.replicated
+        if gmesh is None:
+            rows = contextlib.nullcontext
+        else:
+            # Pallas kernels cannot be partitioned automatically: tell
+            # them, while forward and backward trace, that dp splits the
+            # batch
+            from ..ops.pallas_attention import mesh_rows
+
+            rows = functools.partial(mesh_rows, gmesh.mesh, ("dp",))
 
         def step_fn(train_datas, state_trees, other_datas, hscal, rng,
                     input_datas, label_datas):
@@ -1006,9 +1016,10 @@ class StepProgram:
             fwd2 = jax.checkpoint(fwd) if remat == "all" else fwd
             # ones cotangent == autograd.backward's seed on a
             # non-scalar loss: grads are d(sum(loss))/dw
-            loss, vjp, states = jax.vjp(fwd2, list(train_datas),
-                                        has_aux=True)
-            (grads,) = vjp(jnp.ones_like(loss))
+            with rows():
+                loss, vjp, states = jax.vjp(fwd2, list(train_datas),
+                                            has_aux=True)
+                (grads,) = vjp(jnp.ones_like(loss))
             grads = _bucket_allreduce(list(grads), plan_pos, axis_name)
             if gmesh is not None and gmesh.dp > 1 and level >= 2:
                 # ZeRO-2/3: the pending cross-replica sum lands

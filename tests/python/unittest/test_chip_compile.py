@@ -1,0 +1,128 @@
+"""Compile the main path's kernels for the chip, without the chip.
+
+The TPU's compiler is installed in the sandbox and compiles for a DESCRIBED
+v5e:2x2 host (``jax.experimental.topologies``); nothing runs.  This is the
+only file that describes the chip: the call happens inside a module-scoped
+fixture (never at import, in a ``skipif``, in ``parametrize`` or in
+``conftest.py``), because only one process may hold the TPU library and every
+xdist worker imports every test file.  Each case compiles in this process and
+takes about two seconds; JAX's persistent compilation cache is off around
+them (an entry written for a described device cannot be read back).
+
+What interpret mode cannot show and these do: Mosaic accepts the in-kernel
+dropout's PRNG seeding, the kernels lower under a mesh without gathering the
+batch, and the fast-memory envelope (``flash_vmem_bytes``) is on the safe side
+of the compiler's own accounting.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.ops import pallas_attention as pa
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip("no v5e:2x2 topology can be described here: %r" % e)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _fwd_bwd(causal, dropout_p=0.0, **kw):
+    def f(q, k, v, key):
+        def loss(q, k, v):
+            out = pa.flash_attention(
+                q, k, v, causal=causal, interpret=False,
+                dropout_p=dropout_p,
+                dropout_key=key if dropout_p else None, **kw)
+            return out.astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    return f
+
+
+def _compile(fn, shape, dtype, sharding, key_sharding=None):
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=key_sharding or sharding)
+    return jax.jit(fn).lower(x, x, x, key).compile().as_text()
+
+
+@pytest.mark.parametrize("shape,causal,dropout_p", [
+    ((16, 12, 512, 64), False, 0.0),     # BERT-base, batch 16
+    ((16, 12, 512, 64), False, 0.1),     # ... with in-kernel dropout
+    ((4, 12, 2048, 64), True, 0.0),
+    ((1, 12, 8192, 64), True, 0.0),      # asks for more than default VMEM
+])
+def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, causal, dropout_p):
+    text = _compile(_fwd_bwd(causal, dropout_p), shape, jnp.bfloat16,
+                    SingleDeviceSharding(topo.devices[0]))
+    # forward, dq and dkv: Mosaic kernels, not the interpreter
+    assert text.count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("dropout_p", [0.0, 0.1])
+def test_flash_lowers_on_a_dp_mesh_without_gathering_the_batch(topo,
+                                                               dropout_p):
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
+    with pa.mesh_rows(mesh, ("dp",)):
+        text = _compile(_fwd_bwd(False, dropout_p), (16, 12, 512, 64),
+                        jnp.bfloat16, NamedSharding(mesh, P("dp")),
+                        NamedSharding(mesh, P()))
+    assert text.count("tpu_custom_call") == 3
+    assert "all-gather" not in text
+    # every kernel works on a quarter of the batch: 4 * 12 rows of (T, D)
+    assert "bf16[48,512,64]" in text and "bf16[192,512,64]" not in text
+
+
+def test_flash_without_mesh_rows_cannot_lower_sharded(topo):
+    """Why the engines declare ``mesh_rows``: Mosaic kernels have no
+    partitioning rule of their own."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
+    with pytest.raises(Exception, match="shard_map"):
+        _compile(_fwd_bwd(False), (16, 12, 512, 64), jnp.bfloat16,
+                 NamedSharding(mesh, P("dp")), NamedSharding(mesh, P()))
+
+
+@pytest.mark.parametrize("shape,dtype,block", [
+    ((1, 2, 24576, 128), jnp.float32, 1024),   # 83 MiB by the estimate
+    ((1, 2, 65536, 64), jnp.bfloat16, 512),    # 81 MiB
+    ((1, 2, 6144, 256), jnp.float32, 256),     # just over the default
+])
+def test_envelope_estimate_covers_the_compilers_need(topo, shape, dtype,
+                                                     block):
+    """Inside the envelope the kernels ask for ``flash_vmem_bytes`` of
+    VMEM, and that is enough for the compiler at the far end of it."""
+    need = pa.flash_vmem_bytes(shape[2], shape[2], shape[3],
+                               jnp.dtype(dtype).itemsize, block, block)
+    assert pa.VMEM_DEFAULT_BYTES < need <= pa.VMEM_BUDGET_BYTES
+    text = _compile(_fwd_bwd(True, block_q=block, block_k=block), shape,
+                    dtype, SingleDeviceSharding(topo.devices[0]))
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_outside_the_envelope_is_the_repos_own_error(topo):
+    shape = (1, 2, 65536, 128)
+    assert pa.flash_vmem_bytes(65536, 65536, 128, 4) > pa.VMEM_BUDGET_BYTES
+    with pytest.raises(MXNetError, match="VMEM"):
+        _compile(_fwd_bwd(True), shape, jnp.float32,
+                 SingleDeviceSharding(topo.devices[0]))
+    # impl="auto" never sends such a shape to the kernels
+    assert not pa.use_flash(65536, 65536, 128, False, 4)
+    assert pa.use_flash(8192, 8192, 128, False, 4)

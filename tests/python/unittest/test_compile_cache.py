@@ -924,3 +924,30 @@ def test_diagnose_section_flags_compose(tmp_path, capsys, monkeypatch):
     diagnose.main()
     out = capsys.readouterr().out
     assert "Compile Cache" in out and "Serving" in out
+
+
+def test_jax_cache_dir_env_wins_else_fixed_path_in_checkout(monkeypatch):
+    """JAX's persistent cache: where JAX_COMPILATION_CACHE_DIR is set no
+    other path is named; where it is not, one fixed directory inside the
+    checkout (the path is part of every entry's key)."""
+    import os
+
+    import jax
+
+    was_dir = jax.config.jax_compilation_cache_dir
+    was_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert mxcompile.jax_cache_dir() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == was_dir  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = mxcompile.jax_cache_dir()
+        assert fixed == os.path.join(repo, ".jax_cache")
+        assert mxcompile.jax_cache_dir() == fixed               # no pid/time
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          was_min)

@@ -146,9 +146,12 @@ add(["_Div", "floor_divide", "remainder", "fmod", "_Mod"],
     rnd(0.1, 0.9), rnd(1.0, 2.0))
 add(["_Power", "float_power"], pos, rnd(0, 2), rtol=2e-2, atol=2e-2)
 add(["_Hypot", "arctan2", "copysign", "logaddexp"], rnd(), rnd())
+# comparisons are discontinuous at a == b: two close draws can round to
+# one bf16/f16 value (it failed Tier-1 at seed 1307655921); integer-valued
+# draws are the same numbers in every dtype, and give equal pairs too
 add(["_Equal", "_Not_Equal", "_Greater", "_Greater_Equal", "_Lesser",
-     "_Lesser_Equal", "_Logical_And", "_Logical_Or", "_Logical_Xor"],
-    rnd(), rnd())
+     "_Lesser_Equal"], dint, dint)
+add(["_Logical_And", "_Logical_Or", "_Logical_Xor"], rnd(), rnd())
 # isclose's atol/rtol threshold is a discontinuity: integer-valued draws
 # keep every pair decisively close (equal) or far (>=1 apart) in all dtypes
 add("isclose", dint, dint)

@@ -2,7 +2,7 @@
 item 7) against the dense softmax oracle, incl. in-kernel dropout.
 
 Runs in interpret mode on CPU — the same kernel code lowers to Mosaic on
-TPU hardware.
+TPU hardware (tests/python/unittest/test_chip_compile.py compiles it).
 """
 import jax
 import jax.numpy as jnp
@@ -191,3 +191,51 @@ def test_flash_attention_lse_matches_dense_oracle():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-5,
                                    err_msg="d" + name)
+
+
+@pytest.mark.parametrize("dropout_p", [0.0, 0.2])
+def test_flash_under_mesh_rows_matches_unsharded(dropout_p):
+    """The ``mesh_rows`` shard_map (what FusedTrainer / mx.step declare on
+    a mesh) gives the unsharded kernels' output and gradients — dropout
+    included: the seeds are hashed from the GLOBAL (batch, head) index, so
+    the masks do not depend on how B is laid over devices."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    rs = np.random.RandomState(3)
+    q, k, v, g = (jnp.asarray(rs.randn(4, 2, 128, D).astype(np.float32))
+                  for _ in range(4))
+    key = jax.random.PRNGKey(5)
+
+    def fwd_bwd(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: pa.flash_attention(
+            q, k, v, block_q=64, block_k=64, dropout_p=dropout_p,
+            dropout_key=key if dropout_p else None), q, k, v)
+        return (out,) + vjp(g)
+
+    want = jax.jit(fwd_bwd)(q, k, v)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mdl"))
+    rows = NamedSharding(mesh, P("dp"))
+    with pa.mesh_rows(mesh, ("dp",)):
+        # another function object: jit must trace again, not reuse `want`'s
+        sharded = jax.jit(lambda *qkv: fwd_bwd(*qkv))
+        args = [jax.device_put(x, rows) for x in (q, k, v)]
+        assert "shard_map" in str(jax.make_jaxpr(sharded)(*args))
+        got = sharded(*args)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    # each device worked on its own B/2 rows
+    assert got[0].sharding.is_equivalent_to(rows, 4)
+
+
+def test_default_interpret_rejects_other_backends(monkeypatch):
+    """Interpreted on cpu, compiled on tpu; anything else is an error,
+    not a quiet interpreter."""
+    from mxnet_tpu.base import MXNetError
+
+    assert pa._default_interpret() is True          # the CPU suite
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pa._default_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(MXNetError, match="'gpu'"):
+        pa._default_interpret()

@@ -28,6 +28,7 @@ def _monitor_on(tmp_path, monkeypatch):
     tel_was = telemetry.ENABLED
     telemetry.enable()
     telemetry.reset()
+    trace.enable()  # divergence dumps are the flight recorder's
     monitor.reset()
     monitor.enable()
     yield

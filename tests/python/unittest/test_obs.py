@@ -506,6 +506,7 @@ def test_dump_cap_applies_end_to_end(tmp_path, monkeypatch):
 
     monkeypatch.setenv("MXNET_TRACE_DUMP_MAX_EVENTS", "3")
     monkeypatch.setenv("MXNET_TRACE_DUMP_MIN_SECONDS", "0")
+    was_on = trace.is_enabled()
     trace.enable()
     try:
         for i in range(8):
@@ -521,7 +522,10 @@ def test_dump_cap_applies_end_to_end(tmp_path, monkeypatch):
         assert meta["args"]["truncated_events"] > 0
         assert len(doc["traceEvents"]) <= 1 + 2 * 3  # meta + B/E pairs
     finally:
-        trace.disable()
+        # as found: the files that run after this one on the same xdist
+        # worker (test_monitor's dumps) need the recorder left on
+        if not was_on:
+            trace.disable()
 
 
 # ---------------------------------------------------------------------------

@@ -45,6 +45,29 @@ def test_fused_trainer_dp():
     assert out.shape == (16, 10)
 
 
+def test_fused_trainer_lower_reads_without_stepping():
+    """``_lower(x, y)`` hands ``chip_smoke.py`` the step program (text,
+    compile()) and leaves the training state alone."""
+    mesh = _mesh_or_skip({"dp": 4})
+    mx.random.seed(0)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(32, activation="relu", in_units=8),
+            nn.Dense(10, in_units=32))
+    net.initialize()
+    trainer = parallel.FusedTrainer(net, loss="softmax_ce", optimizer="sgd",
+                                    mesh=mesh)
+    X = np.random.RandomState(0).rand(16, 8).astype(np.float32)
+    Y = np.random.RandomState(1).randint(0, 10, 16).astype(np.int32)
+    lowered = trainer._lower(X, Y)                # builds the program too
+    assert "stablehlo" in lowered.as_text()
+    assert trainer._step_count == 0
+    l0 = float(trainer.step(X, Y).asnumpy())
+    compiled = trainer._lower(X, Y).compile()
+    assert "all-reduce" in compiled.as_text()     # the dp grad reduction
+    assert trainer._step_count == 1
+    assert float(trainer.step(X, Y).asnumpy()) < l0
+
+
 def test_fused_trainer_tp_sharding():
     mesh = _mesh_or_skip({"dp": 2, "tp": 4})
     net = nn.HybridSequential()
@@ -710,6 +733,74 @@ def test_ring_attention_flash_impl_matches_dense():
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-3, atol=2e-4,
                                        err_msg="d" + name)
+
+
+def test_ring_attention_flash_under_mesh_rows():
+    """An engine's ``mesh_rows`` declaration leaves alone a kernel that
+    already runs inside a shard_map (the ring's): the arrays there are one
+    device's, and a second shard_map over the same mesh cannot nest."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    mesh = _mesh_or_skip({"dp": 2, "sp": 4})
+    rs = np.random.RandomState(1)
+    q, k, v, g = (jnp.asarray(rs.randn(2, 2, 64, 16).astype(np.float32))
+                  for _ in range(4))
+
+    def fwd_bwd(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: parallel.ring_attention(
+            q, k, v, mesh=mesh, causal=True, impl="flash", block=8),
+            q, k, v)
+        return (out,) + vjp(g)
+
+    want = jax.jit(fwd_bwd)(q, k, v)
+    with pa.mesh_rows(mesh, ("dp",)):
+        # another function object: jit must trace again, not reuse `want`'s
+        got = jax.jit(lambda *qkv: fwd_bwd(*qkv))(q, k, v)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+
+
+class _RingNet(gluon.HybridBlock):
+    """Sequence-parallel attention called "directly inside a pjit'd step"
+    (gluon/nn/transformer.py): 2 heads x 16 over the ``sp`` ring."""
+
+    def __init__(self, mesh, impl):
+        super().__init__()
+        self._mesh, self._impl = mesh, impl
+        self.qkv = nn.Dense(96, in_units=16, flatten=False)
+        self.head = nn.Dense(4, in_units=32)
+
+    def forward(self, x):
+        B, T, _ = x.shape
+        q, k, v = self.qkv(x)._data.reshape(B, T, 3, 2, 16).transpose(
+            2, 0, 3, 1, 4)
+        out = parallel.ring_attention(q, k, v, mesh=self._mesh,
+                                      impl=self._impl, block=8)
+        return self.head(nd.NDArray(
+            out.transpose(0, 2, 1, 3).reshape(B, T, 32).mean(1)))
+
+
+def test_fused_trainer_ring_flash_on_dp_sp_mesh():
+    """FusedTrainer on a {dp, sp} mesh declares ``mesh_rows`` around its
+    trace; a block whose attention is the ring of flash kernels still
+    lowers there and trains like the ring of dense blocks."""
+    mesh = _mesh_or_skip({"dp": 2, "sp": 4})
+    rs = np.random.RandomState(2)
+    X = rs.randn(4, 64, 16).astype(np.float32)
+    Y = rs.randint(0, 4, 4).astype(np.int32)
+    losses = {}
+    for impl in ("dense", "flash"):
+        mx.random.seed(7)
+        net = _RingNet(mesh, impl)
+        net.initialize()
+        trainer = parallel.FusedTrainer(
+            net, loss="softmax_ce", optimizer="sgd",
+            optimizer_params={"learning_rate": 0.5}, mesh=mesh)
+        losses[impl] = [float(trainer.step(X, Y).asnumpy())
+                        for _ in range(3)]
+    assert losses["flash"][-1] < losses["flash"][0]
+    np.testing.assert_allclose(losses["flash"], losses["dense"], rtol=1e-4)
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """mx.resilience tests: deterministic fault-plan replay, exception
-taxonomy routing, backoff/budget-window math, bounded health probes,
+classification routing, backoff/budget-window math, bounded health probes,
 supervisor resume bit-parity vs an uninterrupted run, preemption
 (in-process and a real SIGTERM subprocess drill), bisect isolation of
 poisoned serve requests, and circuit-breaker open/half-open/close."""
@@ -146,10 +146,10 @@ def test_poisoned_is_non_consuming():
 
 
 # ---------------------------------------------------------------------------
-# taxonomy / backoff / budget / health
+# classification / backoff / budget / health
 # ---------------------------------------------------------------------------
 
-def test_classify_taxonomy():
+def test_classify_classes():
     assert classify(OSError("disk")) == "transient"
     assert classify(TimeoutError()) == "transient"
     assert classify(ConnectionError()) == "transient"

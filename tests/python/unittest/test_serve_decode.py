@@ -134,6 +134,33 @@ def test_page_config_limits():
         PageConfig(4, 2, 2, 2, 4, 16)      # max_context > pool
 
 
+def test_runner_given_ctx_keeps_weights_probe_and_pool_there():
+    """A runner given a context lives on that device — not on JAX's
+    default one (virtual device 2 here stands in for a chip that is not
+    the default)."""
+    import jax
+
+    ctx = mx.cpu(2)
+    dev = ctx.jax_device
+    assert dev != jax.devices()[0]
+    mx.random.seed(0)
+    blk = serve.TinyDecoder(vocab_size=32, num_layers=2, num_heads=2,
+                            head_dim=4)
+    blk.initialize(ctx=ctx)
+    runner = serve.DecodeRunner(blk, ctx=ctx, config=_config())
+    for a in list(runner._params.values()) + [runner.pool.k, runner.pool.v]:
+        assert a.devices() == {dev}
+    sched = serve.DecodeScheduler(runner)
+    try:
+        got = sched.submit([1, 2, 3], max_new_tokens=4).result(timeout=60)
+    finally:
+        sched.stop()
+    assert len(got["tokens"]) == 4
+    assert runner.pool.k.devices() == {dev}          # donated and re-bound
+    runner.pool.reset_storage()
+    assert runner.pool.k.devices() == {dev}
+
+
 # ---------------------------------------------------------------------------
 # correctness: paged continuous decode == unpaged incremental reference
 # ---------------------------------------------------------------------------

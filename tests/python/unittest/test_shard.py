@@ -459,8 +459,12 @@ def test_collective_deadline_wraps_sharded_dispatch():
     orig_cfn, orig_jfn = cap.cfn, cap.jfn
 
     def slow_call(*args):
+        # a hang, not a late launch: the abandoned deadline worker must
+        # never start the 4-device program beside the main thread's next
+        # one (two threads enqueueing collectives in different orders
+        # deadlock the CPU backend — it hung the NEXT test)
         time.sleep(1.0)
-        return (orig_cfn or orig_jfn)(*args)
+        raise RuntimeError("abandoned by the deadline")
 
     cap.cfn = None
     cap.jfn = slow_call

@@ -1,7 +1,6 @@
 #!/usr/bin/env python
 """Tune every measurable mx.autotune site at TPU-relevant workload
-keys and persist the winners — the PERF_PLAN hypothesis-capture
-command for tunnel windows (chained into tools/mfu_campaign.sh).
+keys and persist the winners (one on-chip search is ROADMAP D7).
 
 Run with ``MXNET_AUTOTUNE=search`` and ``MXNET_AUTOTUNE_DIR`` pointed
 at the capture output dir; afterwards

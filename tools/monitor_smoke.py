@@ -3,7 +3,7 @@
 
 5-step imperative training with an Inf gradient INJECTED before step 3,
 under ``MXNET_MONITOR=1 MXNET_MONITOR_SENTINEL=skip_step`` — the exact
-configuration the PERF_PLAN arms for tunnel captures — asserting the
+configuration a guarded training run arms — asserting the
 acceptance contracts end to end:
 
 1. the poisoned step is SKIPPED whole: params/optimizer state/update
