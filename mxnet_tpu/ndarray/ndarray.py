@@ -19,6 +19,7 @@ import numpy as _np
 from .. import telemetry as _tel
 from ..base import MXNetError, _as_np_dtype, integer_types, numeric_types
 from ..context import Context, cpu, current_context
+from ..trace.core import span as _span
 
 __all__ = ["NDArray", "waitall", "from_jax", "concatenate"]
 
@@ -115,16 +116,24 @@ class NDArray:
         return self._grad
 
     # ---- sync / transfer --------------------------------------------------
+    def _waiting(self):
+        """The ``mx.wait`` span: the host blocked on the device, in the
+        flight record of a hang and in any profiler trace."""
+        return _span("mx.wait", hist=False,
+                     args={"nbytes": self._data.nbytes})
+
     def wait_to_read(self):
         """Block until pending computation lands (Engine::WaitForVar)."""
-        self._data.block_until_ready()
+        with self._waiting():
+            self._data.block_until_ready()
 
     wait_to_write = wait_to_read
 
     def asnumpy(self):
         import jax
 
-        arr = _np.asarray(jax.device_get(self._data))
+        with self._waiting():
+            arr = _np.asarray(jax.device_get(self._data))
         if _tel.ENABLED:
             _tel.TRANSFER_D2H.inc(arr.nbytes)
         return arr

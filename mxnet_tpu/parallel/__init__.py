@@ -343,6 +343,12 @@ class FusedTrainer:
                        for x in xs)
             return full, xs
 
+        # device scopes (metadata only: the compiled program is the
+        # same).  JAX names the backward after the forward's scope by
+        # itself: a step's operations read ``jvp(mx.step.forward)``,
+        # ``transpose(jvp(mx.step.forward))`` and ``mx.step.optimizer``
+        # in the profiler's trace (chipbench/spans.py sums them)
+        @jax.named_scope("mx.step.forward")
         def loss_of(tp, frozen, rng, xs, ys):
             full = dict(frozen)
             full.update(tp)
@@ -359,7 +365,14 @@ class FusedTrainer:
                 loss = loss_fn(outs[0], ys[0])
             return jnp.mean(loss), new_states
 
-        def step(*args):
+        # the program's NAME carries the scopes' version: JAX's persistent
+        # compile cache keys a program by its name and its stripped IR, not
+        # by op_name metadata, so under an old name an executable cached
+        # before the scopes existed (or under other scope names) is loaded
+        # with its old op_names and the trace shows those (seen on the chip,
+        # PR 24: ResNet-50's step came from PR 23's cache without a scope).
+        # Whoever renames a scope renames the program.
+        def mx_step(*args):
             with rows():
                 return step_body(*args)
 
@@ -417,8 +430,9 @@ class FusedTrainer:
                 grads = jax.tree_util.tree_map(
                     lambda g: g / accum, grads)
 
-            new_train, new_opt = opt_update(step_i, train_p, grads,
-                                            opt_state, lr_t)
+            with jax.named_scope("mx.step.optimizer"):
+                new_train, new_opt = opt_update(step_i, train_p, grads,
+                                                opt_state, lr_t)
             new_params = dict(frozen)
             new_params.update(new_train)
             new_params.update(new_states)  # running stats etc.
@@ -438,14 +452,18 @@ class FusedTrainer:
                     is_leaf=lambda s: isinstance(s, P))
                 out_state_sh = state_sh
             self._step_fn = jax.jit(
-                step,
+                mx_step,
                 in_shardings=(param_sh, state_sh, None, None, None,
                               NamedSharding(self._mesh, batch_spec),
                               NamedSharding(self._mesh, batch_spec)),
                 out_shardings=(param_sh, out_state_sh, None),
                 donate_argnums=(0, 1))
         else:
-            self._step_fn = jax.jit(step, donate_argnums=(0, 1))
+            self._step_fn = jax.jit(mx_step, donate_argnums=(0, 1))
+        # for mx.step.dispatch's and mx.step.recompile's args
+        self._n_leaves = 3 + len(jax.tree_util.tree_leaves(
+            (self._params, self._opt_state)))
+        self._programs = 0
 
     # -- public -------------------------------------------------------------
     def _stage(self, x, y):
@@ -472,23 +490,49 @@ class FusedTrainer:
         or a tuple (multi-input models / multi-label losses); all leading
         dims are the batch."""
         from .. import random as mxrandom
+        from .. import trace as _trace
         from ..resilience import inject as _inject
 
-        # mx.resilience drill site: fires BEFORE the donated launch, so
-        # a faulted step leaves params/opt_state untouched and the
-        # supervisor's restore-and-replay is exact
-        _inject.fire("trainer_step", seq=self._step_count)
-        xs, ys = self._stage(x, y)
-        rng = mxrandom.take_key()
-        # reference num_update starts at 1 (_update_count increments
-        # before _get_lr, optimizer.py:100) — keep the same phase
-        lr_t = (self._lr_scheduler(self._step_count + 1)
-                if self._lr_scheduler is not None else self._lr)
-        self._params, self._opt_state, loss = self._step_fn(
-            self._params, self._opt_state, jnp.uint32(self._step_count),
-            jnp.float32(lr_t), rng, xs, ys)
-        self._step_count += 1
-        return NDArray(loss)
+        # host spans: ring events under one root, and annotations in the
+        # profiler's own trace whenever a session is live (hist=False: a
+        # dotted name is no Prometheus name)
+        with _trace.span("mx.step", hist=False, step_num=self._step_count):
+            # mx.resilience drill site: fires BEFORE the donated launch,
+            # so a faulted step leaves params/opt_state untouched and the
+            # supervisor's restore-and-replay is exact
+            _inject.fire("trainer_step", seq=self._step_count)
+            with _trace.span("mx.step.stage", hist=False) as staged:
+                xs, ys = self._stage(x, y)
+                staged.note(
+                    arrays=len(xs) + len(ys),
+                    put_bytes=sum(v.nbytes for v in xs + ys)
+                    if self._mesh is not None else 0)
+            with _trace.span("mx.step.rng", hist=False):
+                rng = mxrandom.take_key()
+            with _trace.span("mx.step.scalars", hist=False):
+                # reference num_update starts at 1 (_update_count
+                # increments before _get_lr, optimizer.py:100) — keep the
+                # same phase
+                lr_t = (self._lr_scheduler(self._step_count + 1)
+                        if self._lr_scheduler is not None else self._lr)
+                step_i = jnp.uint32(self._step_count)
+                lr_t = jnp.float32(lr_t)
+            with _trace.span("mx.step.dispatch", hist=False,
+                             args={"leaves": self._n_leaves
+                                   + len(xs) + len(ys)}):
+                self._params, self._opt_state, loss = self._step_fn(
+                    self._params, self._opt_state, step_i, lr_t, rng,
+                    xs, ys)
+            programs = self._step_fn._cache_size()
+            if programs != self._programs:
+                if self._programs:  # the first program is no RE-compile
+                    _trace.instant("mx.step.recompile", args={
+                        "step_num": self._step_count,
+                        "shapes": ";".join(
+                            "x".join(map(str, v.shape)) for v in xs + ys)})
+                self._programs = programs
+            self._step_count += 1
+            return NDArray(loss)
 
     def _lower(self, x, y):
         """The step program lowered for a batch like ``(x, y)`` — a

@@ -80,14 +80,17 @@ def resume(profile_process="worker"):
 
 
 def dump(finished=True, profile_process="worker"):
-    """Write the chrome-trace JSON (reference profiler.py:125).  Custom
-    domain/task events are written directly; device activity lives in the
-    xplane directory next to it (TensorBoard-loadable).
+    """Write the chrome-trace JSON (reference profiler.py:125) of this
+    module's own objects: ``Task``/``Frame``/``Event``/``Counter``/
+    ``Marker``.  Device activity, and every ``mx.trace`` /
+    ``mx.telemetry`` span, lives in the xplane directory next to it (the
+    profiler's own trace, TensorBoard-loadable): spans are
+    ``jax.profiler.TraceAnnotation``s there and no longer copied here.
 
     Events carry the REAL pid and the thread id recorded when each
-    event was appended (plus ``thread_name`` metadata), so spans from
-    the serve scheduler, checkpoint writer, and trainer land on
-    separate Perfetto tracks instead of one overlapping tid-0 row."""
+    event was appended (plus ``thread_name`` metadata), so events from
+    different threads land on separate Perfetto tracks instead of one
+    overlapping tid-0 row."""
     if _state["running"] and finished:
         set_state("stop")
     pid = os.getpid()
@@ -113,8 +116,10 @@ def dump(finished=True, profile_process="worker"):
 
 def dumps(reset=False, format="table", sort_by="total", ascending=False):
     """Aggregate stats string (reference profiler.py:154 + aggregate_
-    stats.cc): user span aggregates, plus the device-op table when a
-    trace was captured and aggregate_stats is enabled."""
+    stats.cc): aggregates of ``Task``/``Frame``/``Event`` objects only
+    (``mx.trace`` and ``mx.telemetry`` spans are in the xplane, not in
+    this list), plus the device-op table when a trace was captured and
+    aggregate_stats is enabled."""
     by_name = {}
     for ev in _state["events"]:
         agg = by_name.setdefault(ev["name"], [0, 0.0])

@@ -17,8 +17,9 @@ Design constraints:
 - All mutation goes through one module lock, so metrics are safe to
   update from dataloader worker threads and the engine path.
 - Timers use the monotonic clock (``time.perf_counter``); ``span(...)``
-  and ``@timed(...)`` additionally feed profiler events when an xplane
-  trace is live, so ad-hoc telemetry spans land in the chrome trace too.
+  and ``@timed(...)`` additionally open a ``jax.profiler.TraceAnnotation``,
+  so ad-hoc telemetry spans land in the profiler's own trace (the
+  ``.xplane.pb``) whenever a profiler session is live.
 
 Exporters: ``prometheus()`` (text exposition format), ``snapshot()`` /
 ``dump(path)`` (JSON), ``totals()`` (flat name->value convenience), and
@@ -30,6 +31,8 @@ import json
 import logging
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from .base import get_env
 
@@ -318,52 +321,27 @@ def reset():
 # timers
 # ---------------------------------------------------------------------------
 
-def _feed_profiler(name, start, dur, cat="telemetry", args=None):
-    """Land the span in the chrome trace when an xplane trace is live
-    (``mx.trace`` spans route through here too, with their own cat and
-    trace-id args).
-
-    The running flag is read under ``_events_lock`` — the same lock
-    appends take — so a concurrent ``set_state('stop')`` can't
-    interleave between the check and the append.  The REAL thread id
-    (and name) is recorded at append time so ``profiler.dump`` can put
-    serve-scheduler / checkpoint-writer / trainer spans on separate
-    Perfetto tracks."""
-    from . import profiler
-
-    # unlocked peek first: with no trace live (the steady state) this
-    # must stay a boolean read, not a global lock acquisition on every
-    # span exit across every thread; the flag is re-checked under the
-    # lock so a concurrent set_state('stop') still can't interleave
-    # with the append
-    if not profiler._state["running"]:
-        return
-    with profiler._events_lock:
-        if profiler._state["running"]:
-            t = threading.current_thread()
-            ev = {"name": name, "cat": cat, "ts": start, "dur": dur,
-                  "tid": t.ident, "tname": t.name}
-            if args:
-                ev["args"] = args
-            profiler._state["events"].append(ev)
-
-
 class span:
     """Monotonic-clock timing context: observes ``<name>_seconds`` (or the
-    given histogram) and feeds a profiler event when a trace is live.
+    given histogram) and opens a ``jax.profiler.TraceAnnotation``, so the
+    span lands in the profiler's own trace whenever a profiler session is
+    live (that sink does not look at ``ENABLED``).
 
     >>> with telemetry.span("train_step"):
     ...     step()
     """
 
-    __slots__ = ("name", "_hist", "_start")
+    __slots__ = ("name", "_hist", "_start", "_ann")
 
     def __init__(self, name, hist=None):
         self.name = name
         self._hist = hist
         self._start = None
+        self._ann = None
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         # disabled-at-enter spans stay dead for their whole lifetime:
         # no clock read here, and __exit__ is a single None check (a
         # span that straddles an enable() observes nothing — half a
@@ -372,6 +350,7 @@ class span:
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         if self._start is None or not ENABLED:
             return False
         dur = time.perf_counter() - self._start
@@ -380,7 +359,6 @@ class span:
             hist = histogram(self.name + "_seconds",
                              "duration of %s spans" % self.name)
         hist.observe(dur)
-        _feed_profiler(self.name, self._start, dur)
         return False
 
 
@@ -391,8 +369,6 @@ def timed(name, hist=None):
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            if not ENABLED:
-                return fn(*args, **kwargs)
             with span(name, hist):
                 return fn(*args, **kwargs)
         return wrapper

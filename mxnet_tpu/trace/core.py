@@ -10,7 +10,9 @@ trace (``trace/export.py``).
 
 Design constraints (same discipline as telemetry):
 
-- Disabled cost is one boolean check per hook (``trace.ENABLED``);
+- Disabled cost is one boolean check per hook (``trace.ENABLED``) and
+  the profiler annotation's own enabled-check (about a microsecond a
+  span in all, measured in PERF.md section 6, PR 24);
   ``MXNET_TRACE_DISABLE=1`` flips it at import, ``disable()`` at runtime.
 - Context propagation uses ``contextvars`` — spans nest naturally per
   thread/async-task, and ``use(ctx)`` hands a context across threads
@@ -19,8 +21,16 @@ Design constraints (same discipline as telemetry):
   default 8192): memory is bounded no matter how long the process runs,
   and the LAST N events are exactly what a post-mortem needs.
 - ``span(...)`` additionally feeds the ``mx.telemetry`` histogram for
-  its name (unless ``hist=False``) and a profiler event when an xplane
-  trace is live — one context manager, three sinks.
+  its name (unless ``hist=False``) and opens a
+  ``jax.profiler.TraceAnnotation``: one context manager, three sinks.
+  The annotation lands in the profiler's OWN trace (the ``.xplane.pb``
+  of ``jax.profiler.start_trace``, ``mx.profiler.set_state("run")`` or
+  a capture from XProf), on the profiler's clock, on the thread that
+  ran the span, with ``args`` as the event's stats.  A live profiler
+  session is that sink's only switch: it does not look at ``ENABLED``
+  or at telemetry, and with no session live it costs the annotation's
+  own enabled-check.  (A string value is cut at its first comma there:
+  the profiler's ``name#k=v,k=v#`` encoding.)
 """
 from __future__ import annotations
 
@@ -30,6 +40,8 @@ import random
 import threading
 import time
 from collections import deque, namedtuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from .. import telemetry
 from ..base import get_env
@@ -221,11 +233,6 @@ def _record(name, cat, start, dur, trace_id, span_id, parent, args=None,
     if args:
         ev["args"] = args
     RECORDER.append(ev)
-    # mirror into the live xplane/chrome trace through the ONE profiler
-    # feed (telemetry's — lock-checked, real tid/tname at append time)
-    telemetry._feed_profiler(name, start, dur, cat=cat,
-                             args={"trace": trace_id, "span": span_id,
-                                   "parent": parent, **(args or {})})
 
 
 # ---------------------------------------------------------------------------
@@ -235,7 +242,8 @@ def _record(name, cat, start, dur, trace_id, span_id, parent, args=None,
 class span:
     """Timing context recording into the flight ring (with trace/span/
     parent propagation), the telemetry histogram for its name, and the
-    live profiler trace.
+    profiler's own trace (a ``jax.profiler.TraceAnnotation``, whenever a
+    profiler session is live).
 
     Parameters
     ----------
@@ -248,15 +256,20 @@ class span:
     args : dict — extra event args (kept small: the ring holds refs).
     anomaly : bool — feed this span's duration to the slow-step
         detector (``trace/anomaly.py``) on exit.
+    step_num : int — this span is one training step: ``step_num`` joins
+        ``args`` and the annotation is a ``StepTraceAnnotation``, by
+        which XProf groups the device's work.
     """
 
     __slots__ = ("name", "cat", "args", "_hist", "_anomaly", "_start",
-                 "_ctx", "_parent", "_token")
+                 "_ctx", "_parent", "_token", "_step", "_ann")
 
     def __init__(self, name, hist=None, cat="trace", args=None,
-                 anomaly=False):
+                 anomaly=False, step_num=None):
         self.name = name
         self.cat = cat
+        if step_num is not None:
+            args = dict(args or (), step_num=step_num)
         self.args = args
         self._hist = hist
         self._anomaly = anomaly
@@ -264,15 +277,28 @@ class span:
         self._ctx = None
         self._parent = None
         self._token = None
+        self._step = step_num is not None
+        self._ann = None
+
+    def note(self, **args):
+        """Add ``args`` learned inside the span (the ring reads them at
+        exit, the profiler's event takes them now)."""
+        self.args = dict(self.args or (), **args)
+        self._ann.set_metadata(**args)
 
     def __enter__(self):
+        # made here, not in __init__: the annotation looks for a live
+        # session when it is made
+        self._ann = (StepTraceAnnotation if self._step
+                     else TraceAnnotation)(self.name, **(self.args or {}))
+        self._ann.__enter__()
         tr_on = ENABLED
         if not tr_on and (not telemetry.ENABLED
                           or self._hist is False):
-            # dead for this span's lifetime: tracing off AND nothing
-            # for telemetry to observe (hist=False hot-path spans must
-            # cost one boolean, not two clock reads, when the ring is
-            # disabled)
+            # dead for this span's lifetime but for the annotation:
+            # tracing off AND nothing for telemetry to observe
+            # (hist=False hot-path spans must cost no clock read when
+            # the ring is disabled)
             return self
         self._start = time.perf_counter()
         if tr_on:
@@ -285,6 +311,7 @@ class span:
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         if self._token is not None:
             _CTX.reset(self._token)
             self._token = None
@@ -314,7 +341,10 @@ class span:
 
 def instant(name, cat="trace", args=None, ctx=None):
     """Record one zero-duration marker event (ph 'i') under ``ctx`` (or
-    the active context)."""
+    the active context), and a zero-length annotation in the profiler's
+    trace."""
+    with TraceAnnotation(name, **(args or {})):
+        pass
     if not ENABLED:
         return
     if ctx is None:
@@ -329,7 +359,8 @@ def record_span(name, start, dur, ctx=None, root=False, cat="trace",
     """Record a span with EXPLICIT timing — for phases whose start was
     observed before their identity existed on this thread (e.g. a serve
     request's queue wait, reconstructed at dispatch from its enqueue
-    timestamp).
+    timestamp).  Ring only: the profiler's trace takes no event after
+    the fact.
 
     With ``ctx``: the event joins that trace; ``root=True`` makes the
     event BE the context's own span (ctx.span_id, no parent) — the

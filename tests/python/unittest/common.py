@@ -37,3 +37,34 @@ def with_seed(seed=None):
         return wrapper
 
     return deco
+
+
+def xplane_host_lines(trace_dir):
+    """What a finished ``jax.profiler`` session wrote under ``trace_dir``,
+    host side: ``[[(start_ns, end_ns, name, stats), ...], ...]``, one list
+    a thread's line of a ``/host:`` plane (a line is named by the OS thread,
+    which Python's thread names do not reach, so lines are told apart by
+    position), events sorted by start."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    files = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    assert files, "no *.xplane.pb under %s" % trace_dir
+    lines = []
+    for plane in ProfileData.from_file(files[-1]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            lines.append(sorted(
+                (e.start_ns, e.start_ns + e.duration_ns, e.name,
+                 {k: v for k, v in e.stats}) for e in line.events))
+    return lines
+
+
+def xplane_find(lines, name):
+    """``[(line index, start_ns, end_ns, stats)]`` of the events called
+    ``name``."""
+    return [(i, s, e, stats) for i, line in enumerate(lines)
+            for s, e, n, stats in line if n == name]

@@ -189,20 +189,27 @@ def test_span_and_timed_record_histograms():
     assert telemetry.get_metric("t_fn_seconds")._delegate().count == 1
 
 
-def test_span_feeds_profiler_when_trace_live():
+def test_span_feeds_profiler_when_trace_live(tmp_path):
+    """While mx.profiler runs (a real jax.profiler session), a telemetry
+    span is an event of the xplane it writes, not of a side list."""
+    from common import xplane_find, xplane_host_lines
+
     from mxnet_tpu import profiler
 
     n0 = len(profiler._state["events"])
-    was = profiler._state["running"]
-    profiler._state["running"] = True      # simulate a live trace
+    was = profiler._config["filename"]
+    profiler.set_config(filename=str(tmp_path / "p.json"))
+    profiler.set_state("run")
     try:
         with telemetry.span("t_traced"):
             pass
     finally:
-        profiler._state["running"] = was
-    evs = profiler._state["events"][n0:]
-    assert any(e["name"] == "t_traced" and e["cat"] == "telemetry"
-               for e in evs)
+        profiler.set_state("stop")
+        profiler.set_config(filename=was)
+    assert len(profiler._state["events"]) == n0
+    lines = xplane_host_lines(profiler._state["trace_dir"])
+    assert len(xplane_find(lines, "t_traced")) == 1
+    assert telemetry.get_metric("t_traced_seconds")._delegate().count == 1
 
 
 def test_log_line_compact():

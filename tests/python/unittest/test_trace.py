@@ -13,6 +13,7 @@ import urllib.request
 
 import numpy as np
 import pytest
+from common import xplane_find, xplane_host_lines
 
 import mxnet_tpu as mx
 from mxnet_tpu import autograd, gluon, nd, profiler, telemetry, trace
@@ -166,41 +167,62 @@ def test_trace_dump_chrome_round_trip(tmp_path):
 
 
 def test_profiler_dump_real_tids_and_nesting(tmp_path):
-    """Satellite: profiler.dump must place spans on their real thread
-    tracks (no more pid:0/tid:0 single row) and carry trace nesting."""
-    fname = str(tmp_path / "p.json")
-    profiler.set_config(filename=fname)
+    """Spans land in the profiler's OWN trace: a real jax.profiler
+    session on the CPU backend holds them by name in a host plane, each
+    on the line of the thread that ran it, nested spans nested, args as
+    stats.  (Was: the same asserted on profiler.dump()'s side list, which
+    spans no longer feed.)"""
+    import jax
+
     profiler._state["events"].clear()
-    was = profiler._state["running"]
-    profiler._state["running"] = True  # simulate a live trace
+    jax.profiler.start_trace(str(tmp_path))
     try:
         def worker():
-            with trace.span("prof_worker"):
+            with trace.span("prof_worker", args={"k": 3, "site": "w"}):
                 pass
             with telemetry.span("tel_worker"):
                 pass
 
         with trace.span("prof_outer"):
-            with trace.span("prof_inner"):
-                pass
+            with trace.span("prof_inner") as inner:
+                inner.note(late=5)
         t = threading.Thread(target=worker, name="prof-thread")
         t.start()
         t.join()
+        # a live session is this sink's only switch
+        trace.disable()
+        telemetry.disable()
+        with trace.span("prof_ring_off", hist=False):
+            pass
+        with telemetry.span("tel_off"):
+            pass
+        trace.instant("prof_instant", args={"why": "x"})
     finally:
-        profiler._state["running"] = was
-    out = profiler.dump(finished=False)
-    with open(out) as f:
-        doc = json.load(f)
-    evs = {e["name"]: e for e in doc["traceEvents"]}
-    assert evs["prof_outer"]["pid"] == os.getpid()
-    assert evs["prof_worker"]["tid"] != evs["prof_outer"]["tid"]
-    assert evs["tel_worker"]["tid"] == evs["prof_worker"]["tid"]
-    # parent/child nesting survives into the chrome args
-    assert evs["prof_inner"]["args"]["parent"] == \
-        evs["prof_outer"]["args"]["span"]
-    meta = [e for e in doc["traceEvents"] if e["name"] == "thread_name"]
-    assert any(e["args"]["name"] == "prof-thread" for e in meta)
-    profiler._state["events"].clear()
+        trace.enable()
+        telemetry.enable()
+        jax.profiler.stop_trace()
+    lines = xplane_host_lines(str(tmp_path))
+    (outer,), (inner,), (work,), (tel,) = (
+        xplane_find(lines, n)
+        for n in ("prof_outer", "prof_inner", "prof_worker", "tel_worker"))
+    assert work[0] != outer[0]           # another thread, another line
+    assert tel[0] == work[0]
+    # nesting is by time on the thread's line
+    assert inner[0] == outer[0]
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    assert work[3]["k"] == 3 and work[3]["site"] == "w"
+    assert inner[3]["late"] == 5
+    assert len(xplane_find(lines, "prof_ring_off")) == 1
+    assert len(xplane_find(lines, "tel_off")) == 1
+    (inst,) = xplane_find(lines, "prof_instant")
+    assert inst[3]["why"] == "x"
+    # the ring kept its own event, ids and all; the side list is gone
+    ring = {e["name"]: e for e in trace.events()}
+    assert ring["prof_inner"]["parent"] == ring["prof_outer"]["span"]
+    assert ring["prof_inner"]["args"] == {"late": 5}
+    assert "prof_ring_off" not in ring
+    assert profiler._state["events"] == []
+    assert "prof_outer" not in profiler.dumps()
 
 
 def test_profiler_span_records_tid_at_stop():
