@@ -94,10 +94,12 @@ class BERTForPretraining(HybridBlock):
         seq, pooled = self.bert(inputs, token_types, valid_length)
         h = seq
         if masked_positions is not None:
-            # gather only masked slots: (B, M, C)
+            # gather only masked slots: (B, M, C).  The index stays
+            # (B, M, 1): one slice per slot is a whole row of C.  Broadcast
+            # to (B, M, C) it would gather, and scatter-add in the
+            # backward, B*M*C single elements.
             h = nd.take_along_axis(
-                seq, masked_positions.astype("int32").expand_dims(-1)
-                .broadcast_to(masked_positions.shape + (seq.shape[-1],)),
+                seq, masked_positions.astype("int32").expand_dims(-1),
                 axis=1)
         h = self.mlm_ln(self.mlm_transform(h))
         if self._tie:

@@ -170,6 +170,139 @@ def test_bert_pretraining_step_decreases_loss():
     assert losses[-1] < losses[0]
 
 
+# The masked-slot selection as the zoo wrote it before it selected rows: the
+# index broadcast to (B, M, C), so every element of every slot is a slice of
+# its own (gather slice_sizes=(1, 1, 1), a scatter-add of single elements
+# backward).  Kept here as the form the zoo's selection is held against.
+def _element_indexed_scores(net, ids, types, positions):
+    seq, _ = net.bert(ids, types, None)
+    h = nd.take_along_axis(
+        seq, positions.astype("int32").expand_dims(-1)
+        .broadcast_to(positions.shape + (seq.shape[-1],)), axis=1)
+    h = net.mlm_ln(net.mlm_transform(h))
+    emb = net.bert.word_embed.weight.data()
+    return nd.dot(h.reshape((-1, h.shape[-1])), emb.T) \
+        .reshape(h.shape[:-1] + (emb.shape[0],))
+
+
+_SLOT_B, _SLOT_T, _SLOT_C, _SLOT_V = 3, 10, 24, 50
+_SLOT_POSITIONS = {
+    "distinct": [[1, 4, 7], [0, 2, 9], [3, 5, 6]],
+    "repeated": [[2, 2, 5], [7, 7, 7], [1, 8, 1]],
+    "edges": [[0, 9, 4], [9, 0, 0], [0, 9, 9]],
+}
+
+
+def _slot_net(dtype):
+    mx.random.seed(7)
+    net = model_zoo.BERTForPretraining(
+        vocab_size=_SLOT_V, units=_SLOT_C, hidden_size=48, num_layers=1,
+        num_heads=2, max_length=_SLOT_T, dropout=0.0)
+    net.initialize()
+    if dtype != "float32":
+        net.cast(dtype)
+    rs = np.random.RandomState(3)
+    ids = nd.array(rs.randint(0, _SLOT_V, (_SLOT_B, _SLOT_T)))
+    types = nd.array(rs.randint(0, 2, (_SLOT_B, _SLOT_T)))
+    return net, ids, types
+
+
+@pytest.mark.parametrize("case", sorted(_SLOT_POSITIONS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_bert_masked_slots_equal_the_element_indexed_form(dtype, case):
+    net, ids, types = _slot_net(dtype)
+    positions = nd.array(np.array(_SLOT_POSITIONS[case], np.int32))
+    M = positions.shape[1]
+    # a scalar that weighs every score differently, so a slot that came
+    # from the wrong row or a cotangent that went to one shows
+    weigh = nd.array(np.random.RandomState(5).randn(_SLOT_B, M, _SLOT_V)
+                     .astype(np.float32))
+
+    def scores_and_grads(scores_of):
+        with autograd.record():
+            scores = scores_of()
+            L = nd.sum(scores.astype("float32") * weigh)
+        L.backward()
+        return scores.asnumpy(), {
+            n: p.grad().asnumpy().astype(np.float32)
+            for n, p in net.collect_params().items() if p.grad_req != "null"}
+
+    want, want_g = scores_and_grads(
+        lambda: _element_indexed_scores(net, ids, types, positions))
+    got, got_g = scores_and_grads(lambda: net(ids, types, None, positions)[0])
+    assert got.shape == (_SLOT_B, M, _SLOT_V) and got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)  # a copy of rows: no rounding
+    assert set(got_g) == set(want_g) and len(got_g) > 10
+    # the same cotangents, added in another order where a position repeats:
+    # float32 differs by its own round-off, bfloat16 by a rounding step or
+    # two (2**-8 each) of the sums that repeated rows make.  A leaf whose
+    # gradient is round-off alone (the key bias: softmax does not see it) is
+    # held to a thousandth of the largest leaf's scale.
+    rtol = 1e-5 if dtype == "float32" else 2.0 ** -6
+    largest = max(np.abs(g).max() for g in want_g.values())
+    assert largest > 1.0
+    for name, g in want_g.items():
+        np.testing.assert_allclose(
+            got_g[name], g, rtol=0, err_msg=name,
+            atol=rtol * max(np.abs(g).max(), 1e-3 * largest))
+
+
+def _single_element_moves(jaxpr, width):
+    """The gathers and scatter-adds of ``jaxpr`` (and of every jaxpr inside
+    it) that move single elements of an operand whose last dimension is
+    ``width``: the slice, or the update window, all ones."""
+    found = []
+    for eqn in jaxpr.eqns:
+        operand = eqn.invars[0].aval if eqn.invars else None
+        wide = getattr(operand, "shape", ())[-1:] == (width,)
+        if eqn.primitive.name == "gather" and wide and \
+                all(s == 1 for s in eqn.params["slice_sizes"]):
+            found.append(("gather", operand.shape))
+        if eqn.primitive.name == "scatter-add" and wide and \
+                not eqn.params["dimension_numbers"].update_window_dims:
+            found.append(("scatter-add", operand.shape))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _single_element_moves(sub, width)
+    return found
+
+
+def test_bert_masked_slots_move_rows_not_elements():
+    from mxnet_tpu.gluon import HybridBlock
+
+    net, ids, types = _slot_net("float32")
+    positions = nd.array(np.array(_SLOT_POSITIONS["distinct"], np.int32))
+
+    class Step(HybridBlock):
+        def __init__(self, scores_of):
+            super().__init__()
+            self.net, self._scores_of = net, scores_of
+
+        def forward(self, ids, types, positions):
+            return self._scores_of(ids, types, positions)
+
+    def moves(scores_of):
+        apply_fn, params = Step(scores_of).export_pure(training=True)
+
+        def scalar(params):
+            (scores,), _ = apply_fn(params, jax.random.PRNGKey(0), ids._data,
+                                    types._data, positions._data)
+            return jnp.sum(scores.astype(jnp.float32) ** 2)
+
+        closed = jax.make_jaxpr(jax.value_and_grad(scalar))(params)
+        return _single_element_moves(closed.jaxpr, _SLOT_C)
+
+    # the check sees the old form: one gather and one scatter-add of single
+    # elements of the (B, T, C) hidden states
+    old = moves(lambda i, t, p: _element_indexed_scores(net, i, t, p))
+    shape = (_SLOT_B, _SLOT_T, _SLOT_C)
+    assert ("gather", shape) in old and ("scatter-add", shape) in old
+    # and none in the zoo's; the embedding lookups (rows of C) pass
+    assert moves(lambda i, t, p: net(i, t, None, p)[0]) == []
+
+
 # ---- language models -------------------------------------------------------
 def test_lstm_lm_forward_and_state():
     net = model_zoo.StandardRNNLM(vocab_size=40, embed_size=16,
