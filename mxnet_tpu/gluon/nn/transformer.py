@@ -23,11 +23,13 @@ from ... import ndarray as nd
 from ...base import MXNetError
 from ..block import HybridBlock
 from .basic_layers import Dense, Dropout, Embedding, HybridSequential, \
-    LayerNorm
+    LayerNorm, RMSNorm
+from .moe import MoE
 
 __all__ = ["MultiHeadAttention", "PositionwiseFFN",
            "TransformerEncoderCell", "TransformerEncoder",
-           "PositionalEmbedding", "SinusoidalPositionalEmbedding"]
+           "PositionalEmbedding", "SinusoidalPositionalEmbedding",
+           "GroupedQueryAttention", "MoEDecoderLayer"]
 
 
 class MultiHeadAttention(HybridBlock):
@@ -212,3 +214,98 @@ class SinusoidalPositionalEmbedding(HybridBlock):
 
         add_pe.__name__ = "sinusoidal_pe"
         return apply_op(add_pe, x)
+
+
+class GroupedQueryAttention(HybridBlock):
+    """Self-attention of a modern decoder: ``num_heads`` query heads over
+    ``num_kv_heads`` KV heads of ``head_dim`` (each KV head serves a group
+    of query heads, never repeated in memory), per-head RMSNorm of q and k
+    with learned gains (QK-norm), rotary positions (rotate-half form) on q
+    and k, no bias.
+
+    forward(x, positions, mask=None): x (B, T, units), positions (T,) or
+    (B, T); ``mask`` an array (dense path) or a static
+    ``ops.pallas_attention.AttnMask`` that the flash kernels evaluate tile
+    by tile."""
+
+    def __init__(self, units, num_heads, num_kv_heads=None, head_dim=None,
+                 rope_theta=10000.0, epsilon=1e-6):
+        super().__init__()
+        num_kv_heads = num_kv_heads or num_heads
+        head_dim = head_dim or units // num_heads
+        if num_heads % num_kv_heads:
+            raise MXNetError("num_heads %d not a multiple of num_kv_heads %d"
+                             % (num_heads, num_kv_heads))
+        self._heads, self._kv_heads, self._dim = num_heads, num_kv_heads, \
+            head_dim
+        self._theta = rope_theta
+        self.query_proj = Dense(num_heads * head_dim, use_bias=False,
+                                flatten=False, in_units=units)
+        self.key_proj = Dense(num_kv_heads * head_dim, use_bias=False,
+                              flatten=False, in_units=units)
+        self.value_proj = Dense(num_kv_heads * head_dim, use_bias=False,
+                                flatten=False, in_units=units)
+        self.out_proj = Dense(units, use_bias=False, flatten=False,
+                              in_units=num_heads * head_dim)
+        self.out_proj.weight.sharding = (None, "tp")
+        self.query_norm = RMSNorm(epsilon=epsilon, in_channels=head_dim)
+        self.key_norm = RMSNorm(epsilon=epsilon, in_channels=head_dim)
+
+    def _heads_of(self, proj, norm, x, positions, heads):
+        b, t = x.shape[0], x.shape[1]
+        h = norm(proj(x).reshape((b, t, heads, self._dim)))
+        return nd.rotary_embedding(h, positions, theta=self._theta) \
+            .reshape((b, t, heads * self._dim))
+
+    def forward(self, x, positions, mask=None):
+        q = self._heads_of(self.query_proj, self.query_norm, x, positions,
+                           self._heads)
+        k = self._heads_of(self.key_proj, self.key_norm, x, positions,
+                           self._kv_heads)
+        out = nd.multi_head_attention(
+            q, k, self.value_proj(x), num_heads=self._heads,
+            num_kv_heads=self._kv_heads, mask=mask)
+        return self.out_proj(out)
+
+
+class MoEDecoderLayer(HybridBlock):
+    """Pre-norm decoder layer whose feed-forward is a mixture of gated SiLU
+    experts: ``h = x + Attn(RMSNorm(x))``, ``h + MoE(RMSNorm(h))``, no bias
+    anywhere.  ``first``/``count`` say which of the ``num_experts`` experts
+    this chip holds (``gluon.nn.MoE``).
+
+    ``recompute=True``: inside a traced program (``FusedTrainer``,
+    ``hybridize``) the layer runs under ``jax.checkpoint``: the backward
+    keeps the layer's input only and runs the layer's forward again."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 num_experts, expert_hidden, top_k, first=0, count=None,
+                 rope_theta=10000.0, epsilon=1e-6, norm_topk=True,
+                 recompute=False):
+        super().__init__()
+        self._recompute = bool(recompute)
+        self.input_norm = RMSNorm(epsilon=epsilon, in_channels=units)
+        self.attention = GroupedQueryAttention(
+            units, num_heads, num_kv_heads, head_dim, rope_theta=rope_theta,
+            epsilon=epsilon)
+        self.post_norm = RMSNorm(epsilon=epsilon, in_channels=units)
+        self.moe = MoE(num_experts, expert_hidden, units, top_k=top_k,
+                       in_units=units, activation="silu", gated=True,
+                       use_bias=False, first=first, count=count,
+                       norm_topk=norm_topk)
+
+    def _layer(self, x, positions, mask):
+        h = x + self.attention(self.input_norm(x), positions, mask)
+        return h + self.moe(self.post_norm(h))
+
+    def forward(self, x, positions, mask=None):
+        import jax
+
+        if not (self._recompute and isinstance(x._data, jax.core.Tracer)):
+            return self._layer(x, positions, mask)
+
+        def pure(x_, positions_):
+            return self._layer(nd.NDArray(x_), nd.NDArray(positions_),
+                               mask)._data
+
+        return nd.NDArray(jax.checkpoint(pure)(x._data, positions._data))

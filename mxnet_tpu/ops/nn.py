@@ -430,8 +430,10 @@ def instance_norm(x, gamma, beta, eps=1e-5):
 def rms_norm(x, gamma, axis=-1, eps=1e-6):
     """RMSNorm — modern-transformer staple (no reference equivalent)."""
     ms = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=axis, keepdims=True)
-    out = (x.astype(jnp.float32) * lax.rsqrt(ms + eps)).astype(x.dtype)
-    return out * gamma
+    # the gain multiplies in float32 too; the result keeps x's dtype (a
+    # float32 gamma under bf16 compute must not widen what follows)
+    return (x.astype(jnp.float32) * lax.rsqrt(ms + eps)
+            * gamma.astype(jnp.float32)).astype(x.dtype)
 
 
 @register("l2_normalization")
@@ -579,12 +581,13 @@ def ctc_loss(data, label, data_lengths=None, label_lengths=None,
 @register("multi_head_attention")
 def multi_head_attention(q, k, v, num_heads=1, mask=None, scale=None,
                          causal=False, impl="auto", attn_dropout=0.0,
-                         dropout_key=None):
-    """Batched SDPA: q,k,v (B, T, H*D).  Reference equivalent:
-    _contrib_interleaved_matmul_selfatt_qk/valatt (contrib/transformer.cc:
-    650-826) which exist only to feed cuBLAS strided GEMMs; on TPU one
-    einsum chain fuses and lands on the MXU, and the Pallas flash kernel
-    (mxnet_tpu/ops/pallas_attention.py) takes over for long sequences.
+                         dropout_key=None, num_kv_heads=None):
+    """Batched SDPA: q (B, T, H*D); k, v (B, T, Hkv*D).  Reference
+    equivalent: _contrib_interleaved_matmul_selfatt_qk/valatt
+    (contrib/transformer.cc:650-826) which exist only to feed cuBLAS strided
+    GEMMs; on TPU one einsum chain fuses and lands on the MXU, and the
+    Pallas flash kernel (mxnet_tpu/ops/pallas_attention.py) takes over for
+    long sequences.
 
     impl: 'auto' | 'dense' | 'flash' (blockwise scan) | 'pallas'.
     attn_dropout (+ dropout_key) drops attention probabilities; every
@@ -592,16 +595,41 @@ def multi_head_attention(q, k, v, num_heads=1, mask=None, scale=None,
     inside fwd AND both backward kernels (regenerated, never stored), so
     auto-dispatch sends all long-sequence cases, dropout included, to
     'pallas'; 'flash' (blockwise) remains the pure-JAX fallback.
+
+    ``mask`` is an array broadcast against (B, H, Tq, Tk) (dense path
+    only), or a static ``pallas_attention.AttnMask`` rule, which the
+    kernels evaluate tile by tile.  ``num_kv_heads`` (default: num_heads)
+    KV heads each serve ``num_heads // num_kv_heads`` query heads.
     """
+    from . import pallas_attention as pa
+
+    args = (q, k, v, num_heads, mask, scale, causal, impl, attn_dropout,
+            dropout_key, num_kv_heads)
+    if not isinstance(mask, pa.AttnMask):
+        return _multi_head_attention(*args)
+    # a structured mask names its calls (kernels and the layout work around
+    # them) in the program, forward and backward: mx.attn.<kind>
+    with jax.named_scope("mx.attn.%s" % mask.kind):
+        return _multi_head_attention(*args)
+
+
+def _multi_head_attention(q, k, v, num_heads, mask, scale, causal, impl,
+                          attn_dropout, dropout_key, num_kv_heads):
     from ..base import MXNetError
     from . import pallas_attention as pa
 
     B, Tq, HD = q.shape
     Tk = k.shape[1]
     D = HD // num_heads
+    kv_heads = num_kv_heads or num_heads
+    if num_heads % kv_heads:
+        raise MXNetError("num_heads %d is not a multiple of num_kv_heads %d"
+                         % (num_heads, kv_heads))
+    group = num_heads // kv_heads
+    rule = mask if isinstance(mask, pa.AttnMask) else None
     qh = q.reshape(B, Tq, num_heads, D).transpose(0, 2, 1, 3)
-    kh = k.reshape(B, Tk, num_heads, D).transpose(0, 2, 1, 3)
-    vh = v.reshape(B, Tk, num_heads, D).transpose(0, 2, 1, 3)
+    kh = k.reshape(B, Tk, kv_heads, D).transpose(0, 2, 1, 3)
+    vh = v.reshape(B, Tk, kv_heads, D).transpose(0, 2, 1, 3)
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     if attn_dropout > 0.0 and dropout_key is None:
         raise MXNetError("attn_dropout > 0 requires dropout_key (draw one "
@@ -613,30 +641,42 @@ def multi_head_attention(q, k, v, num_heads=1, mask=None, scale=None,
         # K/V outgrows the kernels' fast memory takes the blockwise scan,
         # never the (Tq, Tk) score matrix
         isz = q.dtype.itemsize
-        if pa.use_flash(Tq, Tk, D, mask is not None, isz):
+        array_mask = mask is not None and rule is None
+        if pa.use_flash(Tq, Tk, D, array_mask, isz):
             impl = "pallas"
-        elif mask is None and pa.flash_vmem_bytes(Tq, Tk, D, isz) \
+        elif mask is None and group == 1 \
+                and pa.flash_vmem_bytes(Tq, Tk, D, isz) \
                 > pa.VMEM_BUDGET_BYTES:
             impl = "flash"
         else:
             impl = "dense"
     if impl in ("pallas", "flash"):
-        if mask is not None:
+        if mask is not None and (rule is None or impl == "flash"):
             raise MXNetError(
-                "impl=%r does not support an arbitrary mask (only causal=); "
-                "use impl='dense' or drop the mask" % impl)
+                "impl=%r does not support an arbitrary mask (only causal= "
+                "and, for 'pallas', a static AttnMask); use impl='dense' or "
+                "drop the mask" % impl)
         if impl == "pallas":
             out = pa.flash_attention(qh, kh, vh, causal, scale,
                                      dropout_p=attn_dropout,
-                                     dropout_key=dropout_key)
+                                     dropout_key=dropout_key, mask=rule)
         else:
+            if group > 1:
+                raise MXNetError("impl='flash' (blockwise) has no grouped "
+                                 "KV heads; use 'pallas' or 'dense'")
             out = pa.blockwise_attention(qh, kh, vh, causal=causal,
                                          sm_scale=scale,
                                          dropout_p=attn_dropout,
                                          dropout_key=dropout_key)
         return out.transpose(0, 2, 1, 3).reshape(B, Tq, HD)
+    if rule is not None:
+        mask = pa.mask_allowed(rule, jnp.arange(Tq, dtype=jnp.int32)[:, None],
+                               jnp.arange(Tk, dtype=jnp.int32)[None, :])
+    if group > 1:   # query heads of a group share their KV head's rows
+        qh = qh.reshape(B, kv_heads, group * Tq, D)
     scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
                         preferred_element_type=jnp.float32) * scale
+    scores = scores.reshape(B, num_heads, Tq, Tk)
     if causal:
         cmask = jnp.tril(jnp.ones((Tq, Tk), bool))
         scores = jnp.where(cmask, scores, -1e30)
@@ -647,5 +687,24 @@ def multi_head_attention(q, k, v, num_heads=1, mask=None, scale=None,
         keep = 1.0 - attn_dropout
         dmask = jax.random.bernoulli(dropout_key, keep, w.shape)
         w = w * dmask.astype(w.dtype) / keep
-    out = jnp.einsum("bhqk,bhkd->bhqd", w, vh)
+    out = jnp.einsum("bhqk,bhkd->bhqd",
+                     w.reshape(B, kv_heads, group * Tq, Tk), vh)
+    out = out.reshape(B, num_heads, Tq, D)
     return out.transpose(0, 2, 1, 3).reshape(B, Tq, HD)
+
+
+@register("rotary_embedding")
+def rotary_embedding(x, positions, theta=10000.0):
+    """Rotary position embedding, rotate-half form (Su et al.
+    arXiv:2104.09864 as GPT-NeoX and the Qwen family write it): x
+    (..., T, H, D), positions (T,) or (B, T); pair (i, i + D/2) of every
+    head is rotated by ``positions * theta**(-2i/D)``.  Angles in float32."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions.astype(jnp.float32)[..., None] * inv      # (..., T, D/2)
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[..., None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[..., None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
+    rot = jnp.concatenate([-x2, x1], -1)
+    return (xf * cos + rot * sin).astype(x.dtype)

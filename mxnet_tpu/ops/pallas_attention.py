@@ -36,6 +36,111 @@ from ..base import MXNetError
 
 _NEG_INF = -1e30
 
+
+class AttnMask(NamedTuple):
+    """A STATIC structured attention mask: a rule on (query index, key
+    index) that the kernels evaluate on the tile's indices (never a
+    ``(Tq, Tk)`` array) and whose fully masked tiles they skip.
+
+    ``kind="block_diffusion"`` (BD3-LMs, Arriola et al. arXiv:2503.09573;
+    SDAR's training): the sequence is ``[xt ; x0]``, ``2 * seq`` positions,
+    noisy copy first; position ``i`` has block ``(i mod seq) // block``.
+    A noisy query sees the noisy keys of its own block and the clean keys
+    of earlier blocks; a clean query sees the clean keys of its own and
+    earlier blocks.  ``seq**2 + seq*block`` of the ``4 seq**2`` pairs."""
+    kind: str
+    seq: int
+    block: int
+
+
+def block_diffusion_mask(seq, block):
+    if seq % block:
+        raise MXNetError("block_diffusion_mask: seq %d is not a multiple "
+                         "of the block length %d" % (seq, block))
+    return AttnMask("block_diffusion", int(seq), int(block))
+
+
+def mask_allowed(mask, q_idx, k_idx):
+    """The rule as a boolean expression of index arrays (broadcast
+    against each other): what the kernels evaluate per tile, and what the
+    dense path and the tests materialise at small sizes."""
+    if mask.kind != "block_diffusion":
+        raise MXNetError("unknown attention mask kind %r" % (mask.kind,))
+    L, b = mask.seq, mask.block
+    q_clean, k_clean = q_idx >= L, k_idx >= L
+    q_blk = jax.lax.div(jnp.where(q_clean, q_idx - L, q_idx), b)
+    k_blk = jax.lax.div(jnp.where(k_clean, k_idx - L, k_idx), b)
+    same = q_blk == k_blk
+    # logic on the comparisons only: Mosaic has no select between booleans
+    return (k_clean & ((k_blk < q_blk) | (q_clean & same))) \
+        | (~k_clean & ~q_clean & same)
+
+
+def _cdiv(a, b):
+    return jax.lax.div(a + (b - 1), b)
+
+
+def _k_tiles(mask, qi, block_q, block_k, seq_q):
+    """The key tiles a query tile has to visit under ``mask``, as two
+    disjoint ranges ``(lo1, hi1, lo2, hi2)`` of tile indices (an empty
+    range has hi <= lo): noisy keys of the rows' own blocks, then clean
+    keys up to the last row's block.  Every tile outside them is masked
+    whole; a tile inside may be partial and is masked in place."""
+    L, b = mask.seq, mask.block
+    q0 = qi * block_q
+    q1 = jnp.minimum(q0 + block_q, seq_q)            # exclusive
+    n1 = jnp.minimum(q1, L)                          # end of the noisy rows
+    has_noisy = q0 < L
+    # noisy rows [q0, n1): noisy keys of blocks blk(q0) .. blk(n1 - 1)
+    last_noisy_blk = jax.lax.div(jnp.maximum(n1 - 1, 0), b)
+    lo1 = jnp.where(has_noisy, jax.lax.div(jax.lax.div(q0, b) * b, block_k),
+                    0)
+    hi1 = jnp.where(has_noisy, _cdiv((last_noisy_blk + 1) * b, block_k), 0)
+    # clean keys: blocks < blk(n1 - 1) for the noisy rows, <= blk(q1 - 1 - L)
+    # for the clean ones
+    end_noisy = jnp.where(has_noisy, last_noisy_blk * b, 0)
+    end_clean = jnp.where(
+        q1 > L, (jax.lax.div(jnp.maximum(q1 - 1 - L, 0), b) + 1) * b, 0)
+    end = jnp.maximum(end_noisy, end_clean)
+    lo2 = jnp.maximum(L // block_k, hi1)
+    hi2 = jnp.where(end > 0, _cdiv(L + end, block_k), 0)
+    return lo1, hi1, lo2, hi2
+
+
+def _q_tiles(mask, j, block_q, block_k, seq_q, seq_k):
+    """The query tiles a key tile has to visit under ``mask`` (the dkv
+    kernel's loop), two disjoint ranges as ``_k_tiles``: noisy queries (of
+    the noisy keys' own blocks, and of later blocks than the first clean
+    key's), then clean queries from the first clean key's block on."""
+    L, b = mask.seq, mask.block
+    nq = -(-seq_q // block_q)
+    k0 = j * block_k
+    k1 = jnp.minimum(k0 + block_k, seq_k)
+    has_noisy, has_clean = k0 < L, k1 > L
+    n1 = jnp.minimum(k1, L)
+    # noisy keys [k0, n1): noisy queries of blocks blk(k0) .. blk(n1 - 1)
+    a_lo = jax.lax.div(jax.lax.div(k0, b) * b, block_q)
+    a_hi = _cdiv((jax.lax.div(jnp.maximum(n1 - 1, 0), b) + 1) * b, block_q)
+    # clean keys from block kb0 on: noisy queries of blocks > kb0
+    kb0 = jax.lax.div(jnp.maximum(k0 - L, 0), b)
+    b_lo = jax.lax.div((kb0 + 1) * b, block_q)
+    b_hi = jnp.where((kb0 + 1) * b < L, -(-L // block_q), 0)
+    big = jnp.int32(nq)
+    lo1 = jnp.minimum(jnp.where(has_noisy, a_lo, big),
+                      jnp.where(has_clean & (b_hi > 0), b_lo, big))
+    hi1 = jnp.maximum(jnp.where(has_noisy, a_hi, 0),
+                      jnp.where(has_clean, b_hi, 0))
+    # clean queries of blocks >= kb0
+    lo2 = jnp.maximum(jax.lax.div(L + kb0 * b, block_q), hi1)
+    hi2 = jnp.where(has_clean, nq, 0)
+    return lo1, hi1, lo2, hi2
+
+
+def _loop_tiles(ranges, body, init):
+    lo1, hi1, lo2, hi2 = ranges
+    carry = jax.lax.fori_loop(lo1, hi1, body, init)
+    return jax.lax.fori_loop(lo2, hi2, body, carry)
+
 # registered hand-set defaults — the mx.autotune sites' reference
 # configs.  MXNET_AUTOTUNE=0 resolves to exactly these literals, so
 # the untuned stack is bit-and-perf identical to the pre-autotune one.
@@ -211,7 +316,8 @@ def _tile_keep_mask(seed_bh, tile, shape, dropout_p, interpret):
 
 
 def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
-                  causal, block_q, block_k, seq_k, dropout_p, interpret):
+                  causal, block_q, block_k, seq_k, dropout_p, interpret,
+                  mask=None, seq_q=None):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale          # (block_q, D)
@@ -232,10 +338,11 @@ def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
         k_idx = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         valid = k_idx < seq_k
-        if causal:
+        if causal or mask is not None:
             q_idx = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            valid = valid & (k_idx <= q_idx)
+            valid = valid & (k_idx <= q_idx if causal
+                             else mask_allowed(mask, q_idx, k_idx))
         s = jnp.where(valid, s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(-1))
         p = jnp.exp(s - m_new[:, None])
@@ -255,7 +362,11 @@ def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
     m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
     a0 = jnp.zeros((block_q, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nk, body, (m0, l0, a0))
+    if mask is None:
+        m, l, acc = jax.lax.fori_loop(0, nk, body, (m0, l0, a0))
+    else:
+        m, l, acc = _loop_tiles(_k_tiles(mask, qi, block_q, block_k, seq_q),
+                                body, (m0, l0, a0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
     # lse rides a (8, block_q) tile — Mosaic requires the last two block
     # dims be (8k, 128k)-aligned, so a flat (1, block_q) row is illegal on
@@ -266,7 +377,7 @@ def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, *, scale, causal, block_q, block_k,
-                   seq_k, dropout_p, interpret):
+                   seq_k, dropout_p, interpret, mask=None, seq_q=None):
     """dq for one (bh, q-block): ds = p∘(msc∘(dO·Vᵀ) − Δ); dq = scale·ds·K."""
     bh = pl.program_id(0)
     qi = pl.program_id(1)
@@ -288,10 +399,11 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         k_idx = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         valid = k_idx < seq_k
-        if causal:
+        if causal or mask is not None:
             q_idx = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            valid = valid & (k_idx <= q_idx)
+            valid = valid & (k_idx <= q_idx if causal
+                             else mask_allowed(mask, q_idx, k_idx))
         s = jnp.where(valid, s, _NEG_INF)
         p = jnp.exp(s - lse[:, None])                  # rows sum to 1
         dp = jax.lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
@@ -305,14 +417,18 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             ds, kblk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(0, nk, body, jnp.zeros((block_q, D),
-                                                  jnp.float32))
+    dq0 = jnp.zeros((block_q, D), jnp.float32)
+    if mask is None:
+        dq = jax.lax.fori_loop(0, nk, body, dq0)
+    else:
+        dq = _loop_tiles(_k_tiles(mask, qi, block_q, block_k, seq_q), body,
+                         dq0)
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, *, scale, causal, block_q,
-                    block_k, seq_q, seq_k, dropout_p, interpret):
+                    block_k, seq_q, seq_k, dropout_p, interpret, mask=None):
     """dk/dv for one (bh, k-block), looping q blocks.
 
     dv = (p∘msc)ᵀ·dO;  dk = scale·dsᵀ·Q  with the SAME per-tile dropout
@@ -341,6 +457,8 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         valid = (k_idx < seq_k) & (q_idx < seq_q)
         if causal:
             valid = valid & (k_idx <= q_idx)
+        elif mask is not None:
+            valid = valid & mask_allowed(mask, q_idx, k_idx)
         s = jnp.where(valid, s, _NEG_INF)
         p = jnp.exp(s - lse[:, None])
         p = jnp.where(valid, p, 0.0)                  # padded q rows -> 0
@@ -367,8 +485,13 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     dk0 = jnp.zeros((block_k, D), jnp.float32)
     dv0 = jnp.zeros((block_k, D), jnp.float32)
     # causal: q blocks strictly left of this k block see only masked score
-    lo = (j * block_k) // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(lo, nq, body, (dk0, dv0))
+    if mask is None:
+        lo = (j * block_k) // block_q if causal else 0
+        dk, dv = jax.lax.fori_loop(lo, nq, body, (dk0, dv0))
+    else:
+        dk, dv = _loop_tiles(
+            _q_tiles(mask, j, block_q, block_k, seq_q, seq_k), body,
+            (dk0, dv0))
     dk_ref[0] = dk.astype(dk_ref.dtype)               # already scale·dsᵀ·Qs
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -395,9 +518,19 @@ def _pad_pack(q, k, v, block_q, block_k):
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     qf = q.reshape(B * H, nq * block_q, D)
-    kf = k.reshape(B * H, Tk + pad_k, D)
-    vf = v.reshape(B * H, Tk + pad_k, D)
+    kf = k.reshape(B * k.shape[1], Tk + pad_k, D)    # H, or fewer KV heads
+    vf = v.reshape(B * v.shape[1], Tk + pad_k, D)
     return qf, kf, vf, nq, nk, pad_q, pad_k
+
+
+def _kv_index(group):
+    """Index map of a whole-K/V block for grid point (b*H + h, tile): with
+    grouped KV heads, query head ``h`` reads KV head ``h // group`` where it
+    lies; K and V are never repeated in memory."""
+    if group == 1:
+        return lambda b, i: (b, 0, 0)
+    return lambda b, i: (b // group, 0, 0)
+
 
 
 class _Cfg(NamedTuple):
@@ -408,6 +541,7 @@ class _Cfg(NamedTuple):
     block_k: int
     interpret: bool
     dropout_p: float
+    mask: AttnMask | None = None
 
 
 _MESH_ROWS = threading.local()
@@ -467,19 +601,20 @@ def _forward_local(cfg, seeds, q, k, v):
     qf, kf, vf, nq, _nk, pad_q, _pad_k = _pad_pack(q, k, v, block_q,
                                                    block_k)
     Tk_pad = kf.shape[1]
+    kv_of = _kv_index(H // k.shape[1])
 
     kernel = functools.partial(
         _flash_kernel, scale=cfg.scale, causal=cfg.causal, block_q=block_q,
         block_k=block_k, seq_k=Tk, dropout_p=cfg.dropout_p,
-        interpret=cfg.interpret)
+        interpret=cfg.interpret, mask=cfg.mask, seq_q=Tq)
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, nq),
         in_specs=[
             _smem_spec(),
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk_pad, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk_pad, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Tk_pad, D), kv_of),
+            pl.BlockSpec((1, Tk_pad, D), kv_of),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
@@ -518,21 +653,23 @@ def _backward_local(cfg, seeds, q, k, v, do, lse, delta):
     lse4 = _widen(lse)
     delta4 = _widen(delta)
     seedf = seeds.reshape(B * H)
+    group = H // k.shape[1]
+    kv_of = _kv_index(group)
 
     smem_spec = _smem_spec()
     params = _vmem_params(cfg, Tq, Tk, q)
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=cfg.scale, causal=cfg.causal, block_q=block_q,
         block_k=block_k, seq_k=Tk, dropout_p=cfg.dropout_p,
-        interpret=cfg.interpret)
+        interpret=cfg.interpret, mask=cfg.mask, seq_q=Tq)
     dq = pl.pallas_call(
         dq_kernel,
         grid=(B * H, nq),
         in_specs=[
             smem_spec,
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk_pad, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk_pad, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Tk_pad, D), kv_of),
+            pl.BlockSpec((1, Tk_pad, D), kv_of),
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, 8, block_q), lambda b, i: (b, i, 0, 0)),
             pl.BlockSpec((1, 1, 8, block_q), lambda b, i: (b, i, 0, 0)),
@@ -547,15 +684,23 @@ def _backward_local(cfg, seeds, q, k, v, do, lse, delta):
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=cfg.scale, causal=cfg.causal,
         block_q=block_q, block_k=block_k, seq_q=Tq, seq_k=Tk,
-        dropout_p=cfg.dropout_p, interpret=cfg.interpret)
+        dropout_p=cfg.dropout_p, interpret=cfg.interpret, mask=cfg.mask)
+    if group == 1:
+        kv_tile = lambda b, j: (b, j, 0)  # noqa: E731
+        dkv_dtypes = (k.dtype, v.dtype)
+    else:
+        # one (dk, dv) a QUERY head, in float32, summed over the group
+        # below: the kernel keeps one head's Q and dO resident, not eight
+        kv_tile = lambda b, j: (b // group, j, 0)  # noqa: E731
+        dkv_dtypes = (jnp.float32, jnp.float32)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(B * H, nk),
         in_specs=[
             smem_spec,
             pl.BlockSpec((1, Tq_pad, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), kv_tile),
+            pl.BlockSpec((1, block_k, D), kv_tile),
             pl.BlockSpec((1, Tq_pad, D), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, nq, 8, block_q), lambda b, j: (b, 0, 0, 0)),
             pl.BlockSpec((1, nq, 8, block_q), lambda b, j: (b, 0, 0, 0)),
@@ -565,8 +710,8 @@ def _backward_local(cfg, seeds, q, k, v, do, lse, delta):
             pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tk_pad, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk_pad, D), v.dtype),
+            jax.ShapeDtypeStruct((B * H, Tk_pad, D), dkv_dtypes[0]),
+            jax.ShapeDtypeStruct((B * H, Tk_pad, D), dkv_dtypes[1]),
         ],
         interpret=cfg.interpret,
         compiler_params=params,
@@ -576,6 +721,9 @@ def _backward_local(cfg, seeds, q, k, v, do, lse, delta):
     dq = dq.reshape(B, H, Tq_pad, D)[:, :, :Tq]
     dk = dk.reshape(B, H, Tk_pad, D)[:, :, :Tk]
     dv = dv.reshape(B, H, Tk_pad, D)[:, :, :Tk]
+    if group > 1:
+        dk, dv = (a.reshape(B, H // group, group, Tk, D).sum(2).astype(
+            like.dtype) for a, like in ((dk, k), (dv, v)))
     return dq, dk, dv
 
 
@@ -623,15 +771,26 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 def _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
-             dropout_p=0.0):
+             dropout_p=0.0, mask=None):
     """The static kernel configuration of one call: interpret-or-compile
     resolved from the backend, the default softmax scale, and the
     fast-memory check made before anything is traced."""
     interpret = _default_interpret() if interpret is None else interpret
     scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     _check_vmem(q, k.shape[2], block_q, block_k, interpret)
+    if mask is not None:
+        if causal:
+            raise MXNetError("flash_attention: causal= and mask= together")
+        if q.shape[2] != 2 * mask.seq or k.shape[2] != 2 * mask.seq:
+            raise MXNetError(
+                "flash_attention: a block_diffusion mask of seq %d needs "
+                "%d query and key positions, got %d and %d"
+                % (mask.seq, 2 * mask.seq, q.shape[2], k.shape[2]))
+    if q.shape[1] % k.shape[1]:
+        raise MXNetError("flash_attention: %d query heads over %d KV heads"
+                         % (q.shape[1], k.shape[1]))
     return _Cfg(bool(causal), float(scale), int(block_q), int(block_k),
-                bool(interpret), float(dropout_p))
+                bool(interpret), float(dropout_p), mask)
 
 
 def _flash_lse_impl(q, k, v, causal, sm_scale, block_q, block_k,
@@ -697,8 +856,14 @@ def _bh_seeds(dropout_key, B, H):
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
                     block_k=None, interpret=None, dropout_p=0.0,
-                    dropout_key=None):
+                    dropout_key=None, mask=None):
     """Flash attention, (B, H, T, D) layout.
+
+    ``k``/``v`` may hold fewer heads than ``q`` (``H`` a multiple of their
+    count): each KV head then serves a group of consecutive query heads,
+    read in place.  ``mask`` is a static ``AttnMask`` (``causal`` is the
+    built-in case): all three kernels skip the tiles it masks whole and
+    mask the partial ones from indices computed in the kernel.
 
     ``block_q``/``block_k`` default to the mx.autotune
     ``flash_attention`` winner for this workload (the hand-set 512/512
@@ -723,7 +888,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
     block_q, block_k = _tuned_flash_blocks(q, k, causal, block_q, block_k,
                                            dropout_p=float(dropout_p))
     cfg = _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
-                   dropout_p)
+                   dropout_p, mask)
     B, H = q.shape[:2]
     if dropout_p > 0.0:
         if dropout_key is None:

@@ -1,0 +1,209 @@
+"""The flash kernels under a static structured mask (block diffusion), with
+grouped KV heads, against dense attention under the rule written as a
+boolean expression; rotary positions and QK-norm against hand-written forms.
+Kernels run in interpret mode on the CPU."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import nd
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.gluon import nn
+from mxnet_tpu.ops import pallas_attention as pa
+
+
+def _rule(seq, block):
+    """The rule by hand, position by position."""
+    t = 2 * seq
+    out = np.zeros((t, t), bool)
+    for i in range(t):
+        for j in range(t):
+            bi, bj = (i % seq) // block, (j % seq) // block
+            if i < seq:
+                out[i, j] = (j < seq and bi == bj) or (j >= seq and bj < bi)
+            else:
+                out[i, j] = j >= seq and bj <= bi
+    return out
+
+
+def _dense(q, k, v, allowed):
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    s = jnp.where(allowed, s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+def _qkv(seq, heads, kv_heads, d=16, batch=2, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    t = 2 * seq
+    return (jax.random.normal(k[0], (batch, heads, t, d)),
+            jax.random.normal(k[1], (batch, kv_heads, t, d)),
+            jax.random.normal(k[2], (batch, kv_heads, t, d)),
+            jax.random.normal(k[3], (batch, heads, t, d)))
+
+
+@pytest.mark.parametrize("seq,block", [(16, 4), (12, 2), (20, 4)])
+def test_rule_as_index_expression_matches_the_rule_by_hand(seq, block):
+    mask = pa.block_diffusion_mask(seq, block)
+    i = jnp.arange(2 * seq, dtype=jnp.int32)
+    got = np.asarray(pa.mask_allowed(mask, i[:, None], i[None, :]))
+    assert (got == _rule(seq, block)).all()
+    assert got.sum() == seq * seq + seq * block
+
+
+@pytest.mark.parametrize("seq,block,bq,bk", [
+    (16, 4, 8, 8), (32, 4, 8, 4), (40, 8, 16, 32),      # L a tile multiple
+    (24, 4, 16, 8), (20, 4, 8, 16), (28, 4, 8, 8), (36, 4, 16, 16)])
+def test_tile_ranges_hold_every_tile_with_an_allowed_pair(seq, block, bq, bk):
+    """The two ranges of each loop cover every tile that holds an allowed
+    pair, stay inside the grid, and (L a multiple of both tiles) hold
+    nothing else: the other tiles are the ones skipped."""
+    mask = pa.block_diffusion_mask(seq, block)
+    t = 2 * seq
+    allowed = _rule(seq, block)
+    nq, nk = -(-t // bq), -(-t // bk)
+    need = np.array([[allowed[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()
+                      for j in range(nk)] for i in range(nq)])
+    exact = seq % bq == 0 and seq % bk == 0
+
+    def tiles(r):
+        lo1, hi1, lo2, hi2 = (int(x) for x in r)
+        assert hi1 <= lo1 or hi2 <= lo2 or lo2 >= hi1   # disjoint
+        return set(range(lo1, hi1)) | set(range(lo2, hi2))
+
+    for i in range(nq):
+        got = tiles(pa._k_tiles(mask, jnp.int32(i), bq, bk, t))
+        want = set(np.nonzero(need[i])[0])
+        assert want <= got <= set(range(nk)), (i, got, want)
+        assert not exact or got == want
+    for j in range(nk):
+        got = tiles(pa._q_tiles(mask, jnp.int32(j), bq, bk, t, t))
+        want = set(np.nonzero(need[:, j])[0])
+        assert want <= got <= set(range(nq)), (j, got, want)
+        assert not exact or got == want
+    if exact and bq == bk == 8 and seq == 16:
+        assert need.sum() == 2 + 3 + 3      # of 16 tiles: half are skipped
+
+
+@pytest.mark.parametrize("seq,block,bq,bk,heads,kv_heads", [
+    (32, 4, 16, 16, 4, 2),     # L a multiple of the tile, grouped KV
+    (24, 4, 16, 16, 2, 2),     # L NOT a multiple of the tile
+    (40, 4, 16, 32, 4, 1),     # uneven tiles, one KV head for all
+])
+def test_masked_kernels_match_dense_values_and_gradients(
+        seq, block, bq, bk, heads, kv_heads):
+    mask = pa.block_diffusion_mask(seq, block)
+    q, k, v, w = _qkv(seq, heads, kv_heads)
+    allowed = jnp.asarray(_rule(seq, block))
+
+    def flash(q, k, v):
+        return pa.flash_attention(q, k, v, mask=mask, block_q=bq, block_k=bk)
+
+    np.testing.assert_allclose(flash(q, k, v), _dense(q, k, v, allowed),
+                               atol=2e-6)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_dense(*a, allowed) * w),
+                    (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):     # dq (flash_bwd_dq), dk, dv (flash_bwd_dkv)
+        np.testing.assert_allclose(g, r, atol=5e-6)
+
+
+def test_grouped_kv_heads_equal_repeated_k_and_v():
+    q, k, v, w = _qkv(32, 8, 2, seed=3)
+    for kw in ({}, {"causal": True}):
+        def grouped(q, k, v):
+            return pa.flash_attention(q, k, v, block_q=16, block_k=16, **kw)
+
+        def repeated(q, k, v):
+            return pa.flash_attention(q, jnp.repeat(k, 4, 1),
+                                      jnp.repeat(v, 4, 1), block_q=16,
+                                      block_k=16, **kw)
+
+        np.testing.assert_allclose(grouped(q, k, v), repeated(q, k, v),
+                                   atol=1e-6)
+        got = jax.grad(lambda *a: jnp.sum(grouped(*a) * w), (0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(repeated(*a) * w),
+                        (0, 1, 2))(q, k, v)
+        for g, r in zip(got, want):
+            np.testing.assert_allclose(g, r, atol=5e-6)
+
+
+def test_multi_head_attention_takes_the_rule_to_the_kernels_or_dense():
+    seq, block = 128, 4          # 256 positions: the kernels' threshold
+    mask = pa.block_diffusion_mask(seq, block)
+    k = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = nd.NDArray(jax.random.normal(k[0], (1, 256, 4 * 16)))
+    kk = nd.NDArray(jax.random.normal(k[1], (1, 256, 2 * 16)))
+    v = nd.NDArray(jax.random.normal(k[2], (1, 256, 2 * 16)))
+    kw = dict(num_heads=4, num_kv_heads=2, mask=mask)
+    dense = nd.multi_head_attention(q, kk, v, impl="dense", **kw).asnumpy()
+    np.testing.assert_allclose(
+        nd.multi_head_attention(q, kk, v, impl="pallas", **kw).asnumpy(),
+        dense, atol=2e-6)
+    # 'auto' lands on the kernels: no (T, T) scores in the program
+    text = jax.jit(lambda a, b, c: nd.multi_head_attention(
+        nd.NDArray(a), nd.NDArray(b), nd.NDArray(c), **kw)._data).lower(
+            q._data, kk._data, v._data).as_text()
+    assert "4x256x256" not in text and "dot_general" in text
+    assert pa.use_flash(8192, 8192, 128, False, 2)
+    with pytest.raises(MXNetError, match="arbitrary mask"):
+        nd.multi_head_attention(q, kk, v, impl="pallas", num_heads=4,
+                                num_kv_heads=2,
+                                mask=nd.NDArray(jnp.ones((256, 256), bool)))
+    with pytest.raises(MXNetError, match="positions"):
+        pa.flash_attention(q._data.reshape(1, 4, 256, 16)[:, :, :128],
+                           kk._data.reshape(1, 2, 256, 16),
+                           v._data.reshape(1, 2, 256, 16), mask=mask)
+
+
+def test_rotary_and_qk_norm_against_a_hand_written_form():
+    mx.random.seed(4)
+    units, heads, kv_heads, d, theta = 32, 4, 2, 8, 100.0
+    attn = nn.GroupedQueryAttention(units, heads, kv_heads, d,
+                                    rope_theta=theta, epsilon=1e-6)
+    attn.initialize()
+    rs = np.random.RandomState(0)
+    attn.query_norm.gamma.set_data(nd.array(1 + 0.1 * rs.randn(d)))
+    attn.key_norm.gamma.set_data(nd.array(1 + 0.1 * rs.randn(d)))
+    x = rs.randn(2, 6, units).astype("float32")
+    pos = np.array([0, 1, 2, 0, 1, 2])      # position i mod 3, as [xt ; x0]
+    got = attn(nd.array(x), nd.array(pos, dtype="int32")).asnumpy()
+
+    p = {n: v.data().asnumpy() for n, v in attn.collect_params().items()}
+
+    def rope(h):            # rotate pairs (i, i + d/2) by pos * theta^(-2i/d)
+        out = np.empty_like(h)
+        for i in range(d // 2):
+            ang = pos * theta ** (-2.0 * i / d)
+            c, s = np.cos(ang)[None, :, None], np.sin(ang)[None, :, None]
+            a, b = h[..., i], h[..., i + d // 2]
+            out[..., i], out[..., i + d // 2] = a * c - b * s, b * c + a * s
+        return out
+
+    def rows(name, n, norm):
+        h = (x @ p[name + "_proj.weight"].T).reshape(2, 6, n, d)
+        if not norm:
+            return h
+        h = h / np.sqrt((h ** 2).mean(-1, keepdims=True) + 1e-6) \
+            * p[name + "_norm.gamma"]
+        return rope(h)
+
+    q, k, v = rows("query", heads, True), rows("key", kv_heads, True), \
+        rows("value", kv_heads, False)
+    k, v = np.repeat(k, 2, axis=2), np.repeat(v, 2, axis=2)
+    s = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    w = np.exp(s - s.max(-1, keepdims=True))
+    w /= w.sum(-1, keepdims=True)
+    o = np.einsum("bhqk,bkhd->bqhd", w, v).reshape(2, 6, heads * d)
+    np.testing.assert_allclose(got, o @ p["out_proj.weight"].T, atol=2e-5)
+
+
+def test_rms_norm_keeps_the_dtype_of_its_input_under_a_float32_gain():
+    x = nd.NDArray(jnp.ones((2, 8), jnp.bfloat16))
+    out = nd.rms_norm(x, nd.NDArray(jnp.full((8,), 2.0, jnp.float32)))
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(out.asnumpy().astype("float32"), 2.0,
+                               rtol=1e-2)

@@ -77,6 +77,28 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, causal, dropout_p):
     assert text.count("tpu_custom_call") == 3
 
 
+def test_block_diffusion_kernels_compile_for_v5e_with_grouped_kv(topo):
+    """SDAR's shape: 8,192 positions [xt ; x0], 32 query heads of 128 over
+    4 KV heads, the rule evaluated in the kernels (Mosaic takes no select
+    between booleans: ``mask_allowed`` is logic on comparisons only)."""
+    mask = pa.block_diffusion_mask(4096, 4)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def f(q, k, v):
+        def loss(q, k, v):
+            return pa.flash_attention(q, k, v, mask=mask, interpret=False) \
+                .astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16, sharding=one)
+    text = jax.jit(f).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "8192,8192" not in text                  # no score matrix
+    assert "bf16[32,8192,128]" in text and "bf16[4,8192,128]" in text
+
+
 @pytest.mark.parametrize("dropout_p", [0.0, 0.1])
 def test_flash_lowers_on_a_dp_mesh_without_gathering_the_batch(topo,
                                                                dropout_p):

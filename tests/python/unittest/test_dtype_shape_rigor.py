@@ -701,6 +701,7 @@ EXERCISED_ELSEWHERE = {
     "_image_random_saturation": "test_image.py",
     "_image_resize": "test_image.py",
     "_image_to_tensor": "test_image.py",
+    "rotary_embedding": "test_block_diffusion_attention.py",
 }
 
 
