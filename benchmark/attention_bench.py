@@ -1,22 +1,42 @@
-"""Attention microbenchmark: Pallas flash kernel vs blockwise-JAX path.
+"""Attention microbenchmark: the Pallas flash kernels, alone on the chip.
 
-VERDICT r3 item 7 deliverable: fwd+bwd timings and MFU at long sequence
-lengths, demonstrating the flash backward kernel beats the
-recompute-through-blockwise path at T=8k.
+    python benchmark/attention_bench.py cells [--impl FILE] [--dtype D]
+        the two shapes the benchmark's cells send to the kernels, forward
+        and backward in one program, each KERNEL's device time read from a
+        profiler trace by the kernel's name:
+          bert_t512   B 64 x H 12 x T 512 x D 64, no mask
+                      (bert_base_t512: 12 such calls a step)
+          sdar_bd4k   B 2 x 32 query heads over 4 KV heads x 8,192 x D 128
+                      under block_diffusion_mask(4096, 4)
+                      (sdar_30b_a3b_bd4k: 6 layers, forward twice)
+        One JSON line a kernel: ms a call, the products' TFLOP/s and their
+        share of the chip's bf16 peak.  ``--impl FILE`` times another
+        version of ``mxnet_tpu/ops/pallas_attention.py`` (say the parent
+        commit's, ``git show HEAD~1:mxnet_tpu/ops/pallas_attention.py``) in
+        the same process tree: the kernel-level before/after of a change to
+        the kernels' bodies.  ``--dtype float32`` feeds the kernels float32
+        (their products then run at float32).
 
-Usage:
     python benchmark/attention_bench.py [T ...]     # default 2048 8192
+        causal forward + backward, Pallas kernels against the blockwise-JAX
+        path, host clock: one JSON line per (T, impl).
 
-Prints one JSON line per (T, impl) with ms/iter and MFU.  FLOP model
-(dense-equivalent attention flops, the standard flash-attention
-accounting): fwd = 4·B·H·T²·D (QKᵀ and PV, MACs×2); bwd = 2.5× fwd
-(dQ, dK, dV matmuls + recomputed P).
+Operation count (multiply-adds x 2 over the pairs the mask allows): the
+forward runs 2 products a pair (QK^T, PV); the backward runs 7 in two
+kernels: ``flash_bwd_dq`` 3 (S again, dP = dO V^T, dQ = dS K) and
+``flash_bwd_dkv`` 4 (S again, dP again, dV = P^T dO, dK = dS^T Q).  That is
+3.5 x the forward as executed; 2.5 x (5 products) is what ONE backward
+kernel would need, and is not what these kernels run.
 """
 from __future__ import annotations
 
+import argparse
+import importlib.util
 import json
 import os
+import re
 import sys
+import tempfile
 import time
 
 import jax
@@ -27,12 +47,115 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
+# products a pair, as chipbench/readers.py counts them
+KERNEL_PRODUCTS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+
 
 def _peak_bf16_tflops():
     """The one peaks table is bench.py's; an unknown device kind exits."""
     from bench import _peak_bf16_tflops as peak
 
     return peak()
+
+
+def _kernels(impl=None):
+    """``mxnet_tpu.ops.pallas_attention``, or the version of it in the file
+    ``impl`` (loaded beside the tree's own, which stays as it is)."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    if impl is None:
+        return pa
+    spec = importlib.util.spec_from_file_location(
+        "mxnet_tpu.ops._attention_bench_impl", impl)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def cell_shapes(pa):
+    """name -> (q shape, kv shape, flash_attention keywords, allowed pairs
+    a query head)."""
+    seq, block = 4096, 4
+    return {
+        "bert_t512": ((64, 12, 512, 64), (64, 12, 512, 64), {}, 512 * 512),
+        "sdar_bd4k": ((2, 32, 2 * seq, 128), (2, 4, 2 * seq, 128),
+                      {"mask": pa.block_diffusion_mask(seq, block)},
+                      seq * seq + seq * block),
+    }
+
+
+def kernel_ms(trace_dir):
+    """{kernel: (calls, summed device ms)} from the profiler's trace: the
+    ``XLA Ops`` events of the first TPU plane, by the kernels' names."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not re.match(r"^/device:TPU:\d+$", plane.name):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for e in line.events:
+                head = e.name.split(" = ", 1)[0]
+                for kernel in KERNEL_PRODUCTS:  # no name holds another
+                    if kernel in head:
+                        n, ms = found.get(kernel, (0, 0.0))
+                        found[kernel] = (n + 1, ms + e.duration_ns / 1e6)
+                        break
+        break
+    return found
+
+
+def bench_cell(name, pa, dtype, iters, impl_label):
+    qs, kvs, kw, pairs = cell_shapes(pa)[name]
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(keys[0], qs, jnp.float32).astype(dtype)
+    k = jax.random.normal(keys[1], kvs, jnp.float32).astype(dtype)
+    v = jax.random.normal(keys[2], kvs, jnp.float32).astype(dtype)
+    w = jax.random.normal(keys[3], qs, jnp.float32).astype(dtype)
+
+    def loss(q, k, v):
+        out = pa.flash_attention(q, k, v, **kw)
+        return (out.astype(jnp.float32) * w.astype(jnp.float32)).sum()
+
+    step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    t0 = time.perf_counter()
+    jax.block_until_ready(step(q, k, v))
+    compile_s = time.perf_counter() - t0
+    jax.block_until_ready(step(q, k, v))
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        for _ in range(iters):
+            out = step(q, k, v)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        found = kernel_ms(d)
+    peak = _peak_bf16_tflops()
+    rows, total = [], 0.0
+    for kernel, products in KERNEL_PRODUCTS.items():
+        calls, ms = found.get(kernel, (0, 0.0))
+        if not calls:
+            raise SystemExit("attention_bench: no %s event in the trace "
+                             "(found %s)" % (kernel, sorted(found)))
+        ms /= calls
+        total += ms
+        tflops = 2.0 * products * qs[0] * qs[1] * pairs * qs[3] \
+            / (ms * 1e-3) / 1e12
+        rows.append({"shape": name, "impl": impl_label,
+                     "dtype": jnp.dtype(dtype).name, "kernel": kernel,
+                     "calls": calls, "ms": round(ms, 4),
+                     "tflops": round(tflops, 2),
+                     "peak_share_pct": round(100.0 * tflops / peak, 2)})
+    rows.append({"shape": name, "impl": impl_label,
+                 "dtype": jnp.dtype(dtype).name, "kernel": "all three",
+                 "ms": round(total, 4), "compile_s": round(compile_s, 1)})
+    return rows
 
 
 def bench_one(T, impl, B=4, H=12, D=64, dtype=jnp.bfloat16, iters=10,
@@ -66,9 +189,9 @@ def bench_one(T, impl, B=4, H=12, D=64, dtype=jnp.bfloat16, iters=10,
     jax.block_until_ready(out)
     float(np.asarray(out[0][0, 0, 0, 0]))
     dt = (time.perf_counter() - t0) / iters
-    # causal halves the realized flops
+    # causal halves the realized flops; 2 products forward, 7 backward
     fwd_flops = 4.0 * B * H * T * T * D / 2.0
-    total = fwd_flops * (1.0 + 2.5)
+    total = fwd_flops * (1.0 + 3.5)
     tflops = total / dt / 1e12
     return {"T": T, "impl": impl, "ms": round(dt * 1e3, 2),
             "model_tflops": round(tflops, 1),
@@ -79,6 +202,22 @@ def main():
     from mxnet_tpu.compile import jax_cache_dir
 
     jax_cache_dir()
+    if sys.argv[1:2] == ["cells"]:
+        ap = argparse.ArgumentParser(prog="attention_bench.py cells")
+        ap.add_argument("--impl", default=None,
+                        help="another version of pallas_attention.py")
+        ap.add_argument("--dtype", default="bfloat16")
+        args = ap.parse_args(sys.argv[2:])
+        if jax.default_backend() != "tpu":
+            raise SystemExit("attention_bench cells: kernel times come from "
+                             "a TPU's trace; the backend here is %r"
+                             % jax.default_backend())
+        pa = _kernels(args.impl)
+        for name in cell_shapes(pa):
+            for row in bench_cell(name, pa, jnp.dtype(args.dtype), 10,
+                                  args.impl or "tree"):
+                print(json.dumps(row), flush=True)
+        return
     Ts = [int(a) for a in sys.argv[1:]] or [2048, 8192]
     for T in Ts:
         for impl in ("pallas", "blockwise"):

@@ -62,84 +62,201 @@ def block_diffusion_mask(seq, block):
 
 def mask_allowed(mask, q_idx, k_idx):
     """The rule as a boolean expression of index arrays (broadcast
-    against each other): what the kernels evaluate per tile, and what the
-    dense path and the tests materialise at small sizes."""
+    against each other): what the kernels evaluate on a cut tile, from a
+    ``(block_q, 1)`` column and a ``(1, block_k)`` row of indices, and what
+    the dense path and the tests materialise at small sizes.
+
+    Divisions, selects and subtractions stay on the operands' own shapes;
+    only two compares and their join are as large as the broadcast."""
     if mask.kind != "block_diffusion":
         raise MXNetError("unknown attention mask kind %r" % (mask.kind,))
     L, b = mask.seq, mask.block
     q_clean, k_clean = q_idx >= L, k_idx >= L
     q_blk = jax.lax.div(jnp.where(q_clean, q_idx - L, q_idx), b)
     k_blk = jax.lax.div(jnp.where(k_clean, k_idx - L, k_idx), b)
-    same = q_blk == k_blk
-    # logic on the comparisons only: Mosaic has no select between booleans
-    return (k_clean & ((k_blk < q_blk) | (q_clean & same))) \
-        | (~k_clean & ~q_clean & same)
+    # a query sees the clean keys of the blocks under `below` and the noisy
+    # keys of block `own` (none for a clean query); a key is coded so that
+    # the compare it does not belong to is false.  Selects between integers
+    # only: Mosaic has no select between booleans
+    below = jnp.where(q_clean, q_blk + 1, q_blk)
+    own = jnp.where(q_clean, -1, q_blk)
+    k_as_clean = jnp.where(k_clean, k_blk, 2 ** 30)
+    k_as_noisy = jnp.where(k_clean, -2, k_blk)
+    return (k_as_clean < below) | (k_as_noisy == own)
+
+
+# Tile-index arithmetic that runs on a traced tile index (a kernel's
+# ``program_id``) and on a Python int alike, so that ``tile_counts`` counts
+# in plain Python from the very functions the kernels loop over.  (Counting
+# with eager jnp operations instead would compile a score of tiny programs
+# while the step is traced.)  Non-negative operands only: ``//`` is
+# ``lax.div`` there.
+def _py(*xs):
+    return all(isinstance(x, (int, bool)) for x in xs)
+
+
+def _div(a, b):
+    return a // b if _py(a) else jax.lax.div(a, b)
 
 
 def _cdiv(a, b):
-    return jax.lax.div(a + (b - 1), b)
+    return _div(a + (b - 1), b)
 
 
-def _k_tiles(mask, qi, block_q, block_k, seq_q):
-    """The key tiles a query tile has to visit under ``mask``, as two
-    disjoint ranges ``(lo1, hi1, lo2, hi2)`` of tile indices (an empty
-    range has hi <= lo): noisy keys of the rows' own blocks, then clean
-    keys up to the last row's block.  Every tile outside them is masked
-    whole; a tile inside may be partial and is masked in place."""
-    L, b = mask.seq, mask.block
+def _min(a, b):
+    return min(a, b) if _py(a, b) else jnp.minimum(a, b)
+
+
+def _max(a, b):
+    return max(a, b) if _py(a, b) else jnp.maximum(a, b)
+
+
+def _where(cond, a, b):
+    return (a if cond else b) if _py(cond) else jnp.where(cond, a, b)
+
+
+def _split(lo, hi, whole_lo, whole_hi, head=True, tail=True):
+    """Tiles ``[lo, hi)`` as ``(lo, hi, cut)`` ranges in ascending order:
+    cut tiles, then the tiles ``[whole_lo, whole_hi)`` (clipped into the
+    range) that the rule allows whole, then cut tiles again.  ``head`` /
+    ``tail`` are False where the caller knows that part empty, which saves
+    tracing the tile body for it."""
+    a = _min(_max(whole_lo, lo), hi)
+    b = _min(_max(whole_hi, a), hi)
+    return ([(lo, a, True)] if head else []) + [(a, b, False)] \
+        + ([(b, hi, True)] if tail else [])
+
+
+def _k_tiles(qi, block_q, block_k, seq_q, seq_k, causal=False, mask=None):
+    """The key tiles that query tile ``qi`` has to visit (the forward and
+    dq kernels' loop), as ``(lo, hi, cut)`` ranges of tile indices in
+    ascending order (an empty range has hi <= lo).  Every tile outside
+    them is masked whole and skipped.  A tile of a ``cut=False`` range is
+    allowed WHOLE: every pair of it is, and it holds no padded key, so the
+    body traced for it builds no index and no mask.  A tile that is not
+    classified cheaply (a query tile astride ``mask.seq``, a padded last
+    tile) counts as cut, never as whole."""
+    nk = -(-seq_k // block_k)
+    last = seq_k // block_k             # a padded last tile is always cut
+    if mask is None and not causal:
+        return _split(0, nk, 0, last, head=False, tail=last < nk)
     q0 = qi * block_q
-    q1 = jnp.minimum(q0 + block_q, seq_q)            # exclusive
-    n1 = jnp.minimum(q1, L)                          # end of the noisy rows
+    if mask is None:
+        # right of the diagonal nothing is visited; a tile is whole when
+        # its last key is not past the tile's first row
+        hi = _min(nk, _cdiv(q0 + block_q, block_k))
+        return _split(0, hi, 0, _min(_div(q0 + 1, block_k), last),
+                      head=False)
+    # block diffusion: noisy keys of the rows' own blocks (all cut), then
+    # clean keys up to the last row's block
+    L, b = mask.seq, mask.block
+    q1 = _min(q0 + block_q, seq_q)                   # exclusive
+    n1 = _min(q1, L)                                 # end of the noisy rows
     has_noisy = q0 < L
     # noisy rows [q0, n1): noisy keys of blocks blk(q0) .. blk(n1 - 1)
-    last_noisy_blk = jax.lax.div(jnp.maximum(n1 - 1, 0), b)
-    lo1 = jnp.where(has_noisy, jax.lax.div(jax.lax.div(q0, b) * b, block_k),
-                    0)
-    hi1 = jnp.where(has_noisy, _cdiv((last_noisy_blk + 1) * b, block_k), 0)
+    last_noisy_blk = _div(_max(n1 - 1, 0), b)
+    lo1 = _where(has_noisy, _div(_div(q0, b) * b, block_k), 0)
+    hi1 = _where(has_noisy, _cdiv((last_noisy_blk + 1) * b, block_k), 0)
     # clean keys: blocks < blk(n1 - 1) for the noisy rows, <= blk(q1 - 1 - L)
     # for the clean ones
-    end_noisy = jnp.where(has_noisy, last_noisy_blk * b, 0)
-    end_clean = jnp.where(
-        q1 > L, (jax.lax.div(jnp.maximum(q1 - 1 - L, 0), b) + 1) * b, 0)
-    end = jnp.maximum(end_noisy, end_clean)
-    lo2 = jnp.maximum(L // block_k, hi1)
-    hi2 = jnp.where(end > 0, _cdiv(L + end, block_k), 0)
-    return lo1, hi1, lo2, hi2
+    end_noisy = _where(has_noisy, last_noisy_blk * b, 0)
+    end_clean = _where(q1 > L, (_div(_max(q1 - 1 - L, 0), b) + 1) * b, 0)
+    end = _max(end_noisy, end_clean)
+    lo2 = _max(L // block_k, hi1)
+    hi2 = _where(end > 0, _cdiv(L + end, block_k), 0)
+    # EVERY row sees the clean keys under its first row's limit: blocks
+    # < blk(q0) if the tile is all noisy, <= blk(q0 - L) if all clean, none
+    # if it lies astride L
+    whole_end = _where(
+        q1 <= L, _div(q0, b) * b,
+        _where(has_noisy, 0, (_div(_max(q0 - L, 0), b) + 1) * b))
+    return [(lo1, hi1, True)] + _split(
+        lo2, hi2, -(-L // block_k), _div(L + whole_end, block_k),
+        head=L % block_k != 0)
 
 
-def _q_tiles(mask, j, block_q, block_k, seq_q, seq_k):
-    """The query tiles a key tile has to visit under ``mask`` (the dkv
-    kernel's loop), two disjoint ranges as ``_k_tiles``: noisy queries (of
-    the noisy keys' own blocks, and of later blocks than the first clean
-    key's), then clean queries from the first clean key's block on."""
-    L, b = mask.seq, mask.block
+def _q_tiles(j, block_q, block_k, seq_q, seq_k, causal=False, mask=None):
+    """The query tiles that key tile ``j`` has to visit (the dkv kernel's
+    loop), classified as ``_k_tiles`` does.  Here a tile with padded QUERY
+    rows is the one that is always cut; padded key rows only make rows of
+    dK and dV that the caller cuts off."""
     nq = -(-seq_q // block_q)
+    last = seq_q // block_q
+    if mask is None and not causal:
+        return _split(0, nq, 0, last, head=False, tail=last < nq)
     k0 = j * block_k
-    k1 = jnp.minimum(k0 + block_k, seq_k)
+    if mask is None:
+        # q tiles left of this key tile see only masked scores; a tile is
+        # whole when its first row is not before the tile's last key
+        return _split(_div(k0, block_q), nq,
+                      _cdiv(k0 + block_k - 1, block_q), last,
+                      tail=last < nq)
+    # block diffusion: noisy queries (of the noisy keys' own blocks, and of
+    # later blocks than the first clean key's), then clean queries from the
+    # first clean key's block on
+    L, b = mask.seq, mask.block
+    k1 = _min(k0 + block_k, seq_k)
     has_noisy, has_clean = k0 < L, k1 > L
-    n1 = jnp.minimum(k1, L)
+    n1 = _min(k1, L)
     # noisy keys [k0, n1): noisy queries of blocks blk(k0) .. blk(n1 - 1)
-    a_lo = jax.lax.div(jax.lax.div(k0, b) * b, block_q)
-    a_hi = _cdiv((jax.lax.div(jnp.maximum(n1 - 1, 0), b) + 1) * b, block_q)
+    a_lo = _div(_div(k0, b) * b, block_q)
+    a_hi = _cdiv((_div(_max(n1 - 1, 0), b) + 1) * b, block_q)
     # clean keys from block kb0 on: noisy queries of blocks > kb0
-    kb0 = jax.lax.div(jnp.maximum(k0 - L, 0), b)
-    b_lo = jax.lax.div((kb0 + 1) * b, block_q)
-    b_hi = jnp.where((kb0 + 1) * b < L, -(-L // block_q), 0)
-    big = jnp.int32(nq)
-    lo1 = jnp.minimum(jnp.where(has_noisy, a_lo, big),
-                      jnp.where(has_clean & (b_hi > 0), b_lo, big))
-    hi1 = jnp.maximum(jnp.where(has_noisy, a_hi, 0),
-                      jnp.where(has_clean, b_hi, 0))
+    kb0 = _div(_max(k0 - L, 0), b)
+    b_lo = _div((kb0 + 1) * b, block_q)
+    b_hi = _where((kb0 + 1) * b < L, -(-L // block_q), 0)
+    lo1 = _min(_where(has_noisy, a_lo, nq),
+               _where(has_clean & (b_hi > 0), b_lo, nq))
+    hi1 = _max(_where(has_noisy, a_hi, 0), _where(has_clean, b_hi, 0))
     # clean queries of blocks >= kb0
-    lo2 = jnp.maximum(jax.lax.div(L + kb0 * b, block_q), hi1)
-    hi2 = jnp.where(has_clean, nq, 0)
-    return lo1, hi1, lo2, hi2
+    lo2 = _max(_div(L + kb0 * b, block_q), hi1)
+    hi2 = _where(has_clean, nq, 0)
+    # a tile of clean keys only is seen WHOLE by the noisy query tiles past
+    # its last key's block, and by the clean ones from that block on
+    kb1 = _div(_max(k1 - 1 - L, 0), b)
+    return _split(lo1, hi1,
+                  _where(has_noisy, hi1, _cdiv((kb1 + 1) * b, block_q)),
+                  L // block_q, tail=L % block_q != 0) \
+        + _split(lo2, hi2,
+                 _where(has_noisy, hi2, _cdiv(L + kb1 * b, block_q)),
+                 last, tail=last < nq)
 
 
 def _loop_tiles(ranges, body, init):
-    lo1, hi1, lo2, hi2 = ranges
-    carry = jax.lax.fori_loop(lo1, hi1, body, init)
-    return jax.lax.fori_loop(lo2, hi2, body, carry)
+    """``body(tile, carry, cut)`` over the ranges in turn, traced once a
+    range: the tile's class is static."""
+    carry = init
+    for lo, hi, cut in ranges:
+        if isinstance(lo, int) and isinstance(hi, int) and hi <= lo:
+            continue
+        carry = jax.lax.fori_loop(lo, hi, functools.partial(body, cut=cut),
+                                  carry)
+    return carry
+
+
+def _cut_valid(qi, j, block_q, block_k, causal, mask, seq_q=None,
+               seq_k=None, transposed=False):
+    """What a CUT tile ``(qi, j)`` masks: a boolean that broadcasts to the
+    tile, from a column of query indices and a row of key indices (the
+    other way round for ``transposed`` scores), or None where the call
+    masks nothing.  ``seq_q`` / ``seq_k`` are given where the call is
+    padded on that side and the kernel has to mask the padding."""
+    k_shape, q_shape = ((block_k, 1), (1, block_q)) if transposed \
+        else ((1, block_k), (block_q, 1))
+    k_idx = j * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, k_shape, 0 if transposed else 1)
+    q_idx = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, q_shape, 1 if transposed else 0)
+    valid = None
+    if causal:
+        valid = k_idx <= q_idx
+    elif mask is not None:
+        valid = mask_allowed(mask, q_idx, k_idx)
+    for idx, seq in ((k_idx, seq_k), (q_idx, seq_q)):
+        if seq is not None:
+            valid = idx < seq if valid is None else valid & (idx < seq)
+    return valid
+
 
 # registered hand-set defaults — the mx.autotune sites' reference
 # configs.  MXNET_AUTOTUNE=0 resolves to exactly these literals, so
@@ -290,8 +407,11 @@ def blockwise_attention(q, k, v, causal=False, sm_scale=None, block_k=None,
 # Pallas kernels (forward + flash backward; reference fwd-only equivalent:
 # src/operator/contrib/transformer.cc:650-826)
 # ---------------------------------------------------------------------------
-def _tile_keep_mask(seed_bh, tile, shape, dropout_p, interpret):
-    """Deterministic per-tile keep mask.
+def _tile_keep_mask(seed_bh, tile, shape, dropout_p, interpret,
+                    transposed=False):
+    """Deterministic per-tile keep mask of a ``shape`` = (block_q,
+    block_k) tile; ``transposed`` hands the SAME mask out keys first, for
+    the dkv kernel's transposed tile.
 
     ``seed_bh`` is this (batch, head)'s word of the seed array (see
     ``_bh_seeds``) and ``tile`` the flat (q-block, k-block) index, so the
@@ -305,14 +425,37 @@ def _tile_keep_mask(seed_bh, tile, shape, dropout_p, interpret):
     requirement (masks need not match across backends)."""
     if interpret:
         key = jax.random.fold_in(jax.random.PRNGKey(seed_bh), tile)
-        return jax.random.bernoulli(key, 1.0 - dropout_p, shape)
+        keep = jax.random.bernoulli(key, 1.0 - dropout_p, shape)
+        return keep.T if transposed else keep
     from jax.experimental.pallas import tpu as pltpu
 
     pltpu.prng_seed(seed_bh, tile)
     bits = pltpu.prng_random_bits(shape)      # int32, all 2^32 patterns
+    if transposed:
+        bits = bits.T
     # signed compare: P(bits < t) = (t + 2^31) / 2^32 = 1 - dropout_p
     thresh = int((1.0 - dropout_p) * 2.0 ** 32) - 2 ** 31
     return bits < jnp.int32(min(thresh, 2 ** 31 - 1))
+
+
+# Every product takes q, k, v and dO at the CALLER's dtype and accumulates
+# in float32 (``preferred_element_type``); p and ds are cast to that dtype
+# for the second products, after the dropout factor.  Softmax statistics,
+# lse, delta and the accumulators are float32 whatever comes in, and the
+# softmax scale multiplies the float32 scores, never a bf16 q.  A float32
+# caller so keeps float32 operands in every product, a bf16 caller feeds
+# the MXU bf16: the input's dtype decides, no switch does.
+_NT = (((1,), (1,)), ((), ()))          # A @ B.T
+_NN = (((1,), (0,)), ((), ()))          # A @ B
+
+
+def _scores(a, b, scale, valid):
+    """The tile's scaled scores ``a @ b.T`` in float32, masked where
+    ``valid`` (None: nothing to mask) is false."""
+    s = jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+    if scale != 1.0:
+        s = s * scale
+    return s if valid is None else jnp.where(valid, s, _NEG_INF)
 
 
 def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
@@ -320,30 +463,18 @@ def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
                   mask=None, seq_q=None):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # (block_q, D)
+    q = q_ref[0]                                      # (block_q, D)
     D = q.shape[-1]
     nk_all = pl.cdiv(seq_k, block_k)
-    nk = nk_all
-    if causal:
-        # skip fully-masked K blocks right of the diagonal
-        nk = jnp.minimum(nk, pl.cdiv((qi + 1) * block_q, block_k))
+    pad_k = seq_k if seq_k % block_k else None
 
-    def body(j, carry):
+    def body(j, carry, cut):
         m, l, acc = carry
-        kblk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (block_q, block_k)
-        k_idx = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = k_idx < seq_k
-        if causal or mask is not None:
-            q_idx = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            valid = valid & (k_idx <= q_idx if causal
-                             else mask_allowed(mask, q_idx, k_idx))
-        s = jnp.where(valid, s, _NEG_INF)
+        kblk = k_ref[0, pl.ds(j * block_k, block_k), :]
+        vblk = v_ref[0, pl.ds(j * block_k, block_k), :]
+        valid = _cut_valid(qi, j, block_q, block_k, causal, mask,
+                           seq_k=pad_k) if cut else None
+        s = _scores(q, kblk, scale, valid)            # (block_q, block_k)
         m_new = jnp.maximum(m, s.max(-1))
         p = jnp.exp(s - m_new[:, None])
         corr = jnp.exp(m - m_new)
@@ -355,18 +486,16 @@ def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
                                    dropout_p, interpret)
             p = p * keep.astype(p.dtype) / (1.0 - dropout_p)
         acc_new = acc * corr[:, None] + jax.lax.dot_general(
-            p, vblk, (((1,), (0,)), ((), ())),
+            p.astype(vblk.dtype), vblk, _NN,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
     a0 = jnp.zeros((block_q, D), jnp.float32)
-    if mask is None:
-        m, l, acc = jax.lax.fori_loop(0, nk, body, (m0, l0, a0))
-    else:
-        m, l, acc = _loop_tiles(_k_tiles(mask, qi, block_q, block_k, seq_q),
-                                body, (m0, l0, a0))
+    m, l, acc = _loop_tiles(
+        _k_tiles(qi, block_q, block_k, seq_q, seq_k, causal, mask), body,
+        (m0, l0, a0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
     # lse rides a (8, block_q) tile — Mosaic requires the last two block
     # dims be (8k, 128k)-aligned, so a flat (1, block_q) row is illegal on
@@ -381,48 +510,35 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     """dq for one (bh, q-block): ds = p∘(msc∘(dO·Vᵀ) − Δ); dq = scale·ds·K."""
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    qs = q_ref[0].astype(jnp.float32) * scale
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0, 0, :]           # row 0 of the (8, block_q) tile
-    delta = delta_ref[0, 0, 0, :]
-    D = qs.shape[-1]
+    q = q_ref[0]
+    do = do_ref[0]
+    lse = lse_ref[0, 0, 0, :][:, None]  # row 0 of the (8, block_q) tile
+    delta = delta_ref[0, 0, 0, :][:, None]
+    D = q.shape[-1]
     nk_all = pl.cdiv(seq_k, block_k)
-    nk = nk_all
-    if causal:
-        nk = jnp.minimum(nk, pl.cdiv((qi + 1) * block_q, block_k))
+    pad_k = seq_k if seq_k % block_k else None
 
-    def body(j, dq):
-        kblk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(qs, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        k_idx = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = k_idx < seq_k
-        if causal or mask is not None:
-            q_idx = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            valid = valid & (k_idx <= q_idx if causal
-                             else mask_allowed(mask, q_idx, k_idx))
-        s = jnp.where(valid, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])                  # rows sum to 1
-        dp = jax.lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
+    def body(j, dq, cut):
+        kblk = k_ref[0, pl.ds(j * block_k, block_k), :]
+        vblk = v_ref[0, pl.ds(j * block_k, block_k), :]
+        valid = _cut_valid(qi, j, block_q, block_k, causal, mask,
+                           seq_k=pad_k) if cut else None
+        s = _scores(q, kblk, scale, valid)
+        p = jnp.exp(s - lse)                           # rows sum to 1
+        dp = jax.lax.dot_general(do, vblk, _NT,
                                  preferred_element_type=jnp.float32)
         if dropout_p > 0.0:
             keep = _tile_keep_mask(seed_ref[bh], qi * nk_all + j, p.shape,
                                    dropout_p, interpret)
             dp = dp * keep.astype(dp.dtype) / (1.0 - dropout_p)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         return dq + jax.lax.dot_general(
-            ds, kblk, (((1,), (0,)), ((), ())),
+            ds.astype(kblk.dtype), kblk, _NN,
             preferred_element_type=jnp.float32)
 
-    dq0 = jnp.zeros((block_q, D), jnp.float32)
-    if mask is None:
-        dq = jax.lax.fori_loop(0, nk, body, dq0)
-    else:
-        dq = _loop_tiles(_k_tiles(mask, qi, block_q, block_k, seq_q), body,
-                         dq0)
+    dq = _loop_tiles(
+        _k_tiles(qi, block_q, block_k, seq_q, seq_k, causal, mask), body,
+        jnp.zeros((block_q, D), jnp.float32))
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
@@ -432,67 +548,59 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     """dk/dv for one (bh, k-block), looping q blocks.
 
     dv = (p∘msc)ᵀ·dO;  dk = scale·dsᵀ·Q  with the SAME per-tile dropout
-    mask as the forward (regenerated, not stored)."""
+    mask as the forward (regenerated, not stored).  The tile is formed
+    TRANSPOSED (``K Qᵀ``, keys down the rows): pᵀ and dsᵀ then enter their
+    products as plain left operands (Mosaic takes no bf16 product
+    contracted over dimension 0 of both operands), and lse and Δ broadcast
+    along the rows as they lie, without a relayout a tile."""
     bh = pl.program_id(0)
     j = pl.program_id(1)
-    kblk = k_ref[0].astype(jnp.float32)               # (block_k, D)
-    vblk = v_ref[0].astype(jnp.float32)
+    kblk = k_ref[0]                                   # (block_k, D)
+    vblk = v_ref[0]
     D = kblk.shape[-1]
-    nq = pl.cdiv(seq_q, block_q)
     nk_all = pl.cdiv(seq_k, block_k)
+    pad_q = seq_q if seq_q % block_q else None
 
-    def body(qi, carry):
+    def body(qi, carry, cut):
         dk, dv = carry
-        qs = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(
-            jnp.float32) * scale
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, qi, 0, :]      # (nq, 8, block_q) layout, row 0
-        delta = delta_ref[0, qi, 0, :]
-        s = jax.lax.dot_general(qs, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        k_idx = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        q_idx = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        valid = (k_idx < seq_k) & (q_idx < seq_q)
-        if causal:
-            valid = valid & (k_idx <= q_idx)
-        elif mask is not None:
-            valid = valid & mask_allowed(mask, q_idx, k_idx)
-        s = jnp.where(valid, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(valid, p, 0.0)                  # padded q rows -> 0
+        q = q_ref[0, pl.ds(qi * block_q, block_q), :]
+        do = do_ref[0, pl.ds(qi * block_q, block_q), :]
+        lse = lse_ref[0, qi, 0, :][None, :]     # (nq, 8, block_q), row 0
+        delta = delta_ref[0, qi, 0, :][None, :]
+        # padded KEY rows need no mask here: they only make rows of dK and
+        # dV that the caller cuts off
+        valid = _cut_valid(qi, j, block_q, block_k, causal, mask,
+                           seq_q=pad_q, transposed=True) if cut else None
+        s = _scores(kblk, q, scale, valid)            # (block_k, block_q)
+        p = jnp.exp(s - lse)
+        if cut and pad_q:
+            p = jnp.where(valid, p, 0.0)              # padded q rows -> 0
         if dropout_p > 0.0:
-            keep = _tile_keep_mask(seed_ref[bh], qi * nk_all + j, p.shape,
-                                   dropout_p, interpret).astype(p.dtype) \
+            keep = _tile_keep_mask(
+                seed_ref[bh], qi * nk_all + j, (block_q, block_k),
+                dropout_p, interpret, transposed=True).astype(p.dtype) \
                 / (1.0 - dropout_p)
         else:
             keep = None
         pm = p * keep if keep is not None else p
         dv = dv + jax.lax.dot_general(
-            pm, do, (((0,), (0,)), ((), ())),
+            pm.astype(do.dtype), do, _NN,
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(vblk, do, _NT,
                                  preferred_element_type=jnp.float32)
         if keep is not None:
             dp = dp * keep
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dk = dk + jax.lax.dot_general(
-            ds, qs, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, _NN,
             preferred_element_type=jnp.float32)
         return dk, dv
 
-    dk0 = jnp.zeros((block_k, D), jnp.float32)
-    dv0 = jnp.zeros((block_k, D), jnp.float32)
-    # causal: q blocks strictly left of this k block see only masked score
-    if mask is None:
-        lo = (j * block_k) // block_q if causal else 0
-        dk, dv = jax.lax.fori_loop(lo, nq, body, (dk0, dv0))
-    else:
-        dk, dv = _loop_tiles(
-            _q_tiles(mask, j, block_q, block_k, seq_q, seq_k), body,
-            (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)               # already scale·dsᵀ·Qs
+    dk, dv = _loop_tiles(
+        _q_tiles(j, block_q, block_k, seq_q, seq_k, causal, mask), body,
+        (jnp.zeros((block_k, D), jnp.float32),
+         jnp.zeros((block_k, D), jnp.float32)))
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -770,6 +878,43 @@ def _flash_core_bwd(cfg, res, do):
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
+@functools.lru_cache(maxsize=None)
+def tile_counts(seq_q, seq_k, block_q, block_k, causal=False, mask=None):
+    """``(visited, whole, cut)`` tiles a head in the forward (the backward
+    kernels visit the same tiles): counted in Python from ``_k_tiles``,
+    the ranges the kernels loop over, one query tile after the other."""
+    block_q, block_k = min(block_q, seq_q), min(block_k, seq_k)
+    n = {True: 0, False: 0}
+    for qi in range(-(-seq_q // block_q)):
+        for lo, hi, cut in _k_tiles(qi, block_q, block_k, seq_q, seq_k,
+                                    causal, mask):
+            n[cut] += max(hi - lo, 0)
+    return n[True] + n[False], n[False], n[True]
+
+
+_TILES_NOTED = set()
+
+
+def _note_tiles(cfg, q, k):
+    """One ``mx.attn.tiles`` instant a distinct call, written where the
+    call is traced: what the kernels will visit, and at which dtype their
+    products run."""
+    what = (q.shape[2], k.shape[2], cfg.block_q, cfg.block_k, cfg.causal,
+            cfg.mask)
+    dtype = str(q.dtype)
+    if what + (dtype,) in _TILES_NOTED:
+        return
+    _TILES_NOTED.add(what + (dtype,))
+    from .. import trace as _trace
+
+    visited, whole, cut = tile_counts(*what)
+    _trace.instant("mx.attn.tiles", args={
+        "kind": "causal" if cfg.causal else
+        cfg.mask.kind if cfg.mask is not None else "none",
+        "visited": visited, "whole": whole, "cut": cut,
+        "operand_dtype": dtype})
+
+
 def _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
              dropout_p=0.0, mask=None):
     """The static kernel configuration of one call: interpret-or-compile
@@ -796,6 +941,7 @@ def _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
 def _flash_lse_impl(q, k, v, causal, sm_scale, block_q, block_k,
                     interpret):
     cfg = _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret)
+    _note_tiles(cfg, q, k)
     seeds = jnp.zeros(q.shape[:2], jnp.int32)
     out, lse = _forward_call(cfg, seeds, q, k, v)
     return out, lse[:, :, :q.shape[2]]
@@ -862,8 +1008,12 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
     ``k``/``v`` may hold fewer heads than ``q`` (``H`` a multiple of their
     count): each KV head then serves a group of consecutive query heads,
     read in place.  ``mask`` is a static ``AttnMask`` (``causal`` is the
-    built-in case): all three kernels skip the tiles it masks whole and
-    mask the partial ones from indices computed in the kernel.
+    built-in case): all three kernels skip the tiles it masks whole, run
+    the tiles it allows whole without any index or mask arithmetic, and
+    mask the tiles it cuts from a column and a row of indices computed in
+    the kernel (``mx.attn.tiles`` counts the three classes).  Every
+    product takes its operands at the dtype of ``q``/``k``/``v`` and
+    accumulates in float32; the softmax state is float32 whatever comes in.
 
     ``block_q``/``block_k`` default to the mx.autotune
     ``flash_attention`` winner for this workload (the hand-set 512/512
@@ -889,6 +1039,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
                                            dropout_p=float(dropout_p))
     cfg = _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
                    dropout_p, mask)
+    _note_tiles(cfg, q, k)
     B, H = q.shape[:2]
     if dropout_p > 0.0:
         if dropout_key is None:
@@ -932,8 +1083,12 @@ def flash_vmem_bytes(seq_q, seq_k, head_dim, itemsize,
     (``(Tk, D)`` blocks), the dkv kernel one head's whole Q and dO plus
     the lse/Δ rows in their 8-sublane layout; Pallas double-buffers every
     operand block, and a row of D < 128 still fills a 128-lane tile.  On
-    top of the operands comes the f32 working set of one (block_q,
-    block_k) tile: scores, probabilities, mask and their products."""
+    top of the operands comes the working set of one (block_q, block_k)
+    tile: scores, probabilities, mask and their products in float32, and
+    the copies of p and ds at the operands' dtype that enter the second
+    products (the compiler lets them share room: the count of six float32
+    tiles still covers its need, which the chip-compile tests check with
+    dropout on)."""
     lanes = -(-head_dim // 128) * 128
     block_q, block_k = min(block_q, seq_q), min(block_k, seq_k)
     pad = lambda t, b: -(-t // b) * b  # noqa: E731

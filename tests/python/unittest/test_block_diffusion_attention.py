@@ -54,38 +54,112 @@ def test_rule_as_index_expression_matches_the_rule_by_hand(seq, block):
     assert got.sum() == seq * seq + seq * block
 
 
+def _tiles(ranges):
+    """{tile: cut} of a range function's ``(lo, hi, cut)`` list, which has
+    to be ascending and disjoint."""
+    out, at = {}, 0
+    for lo, hi, cut in ranges:
+        lo, hi = int(lo), int(hi)
+        if hi > lo:
+            assert lo >= at, (ranges, at)
+            at = hi
+            out.update((t, bool(cut)) for t in range(lo, hi))
+    return out
+
+
+def _check_classes(allowed, bq, bk, k_tiles, q_tiles):
+    """Against the materialised rule ``allowed`` (real rows and keys only):
+    every tile with an allowed pair is visited, both loops stay inside the
+    grid, and a tile called whole has no masked pair and no padding."""
+    tq, tk = allowed.shape
+    nq, nk = -(-tq // bq), -(-tk // bk)
+    pad = np.zeros((nq * bq, nk * bk), bool)
+    pad[:tq, :tk] = allowed
+    tile = lambda i, j: pad[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]  # noqa
+    seen = {}
+    for i in range(nq):
+        got = _tiles(k_tiles(jnp.int32(i)))
+        assert got == _tiles(k_tiles(i))    # traced index and Python int
+        assert set(got) <= set(range(nk)), (i, got)
+        for j in range(nk):
+            assert j in got or not tile(i, j).any(), (i, j)
+        for j, cut in got.items():
+            # the forward's rows beyond Tq are thrown away: real rows only
+            rows = tile(i, j)[:max(0, min(bq, tq - i * bq))]
+            assert cut or (rows.all() and (j + 1) * bk <= tk), (i, j)
+        seen[i] = got
+    for j in range(nk):
+        got = _tiles(q_tiles(jnp.int32(j)))
+        assert got == _tiles(q_tiles(j))
+        assert set(got) <= set(range(nq)), (j, got)
+        for i in range(nq):
+            assert i in got or not tile(i, j).any(), (i, j)
+        for i, cut in got.items():
+            cols = tile(i, j)[:, :max(0, min(bk, tk - j * bk))]
+            assert cut or (cols.all() and (i + 1) * bq <= tq), (i, j)
+    return seen
+
+
 @pytest.mark.parametrize("seq,block,bq,bk", [
     (16, 4, 8, 8), (32, 4, 8, 4), (40, 8, 16, 32),      # L a tile multiple
-    (24, 4, 16, 8), (20, 4, 8, 16), (28, 4, 8, 8), (36, 4, 16, 16)])
+    (24, 4, 16, 8), (20, 4, 8, 16), (28, 4, 8, 8), (36, 4, 16, 16),
+    (64, 4, 16, 16), (64, 16, 16, 16), (64, 32, 16, 8),  # b < = > the tile
+    (44, 4, 16, 16), (44, 4, 32, 8),    # a tile astride L; 2L no multiple
+    (18, 2, 8, 16), (50, 10, 16, 16), (12, 12, 8, 8)])
 def test_tile_ranges_hold_every_tile_with_an_allowed_pair(seq, block, bq, bk):
-    """The two ranges of each loop cover every tile that holds an allowed
-    pair, stay inside the grid, and (L a multiple of both tiles) hold
-    nothing else: the other tiles are the ones skipped."""
+    """The ranges of each loop cover every tile that holds an allowed pair,
+    stay inside the grid, and (L a multiple of both tiles) hold nothing
+    else: the other tiles are the ones skipped.  A tile they call whole is
+    allowed whole; with blocks shorter than the tiles every tile that the
+    rule cuts is called cut and every other visited one whole."""
     mask = pa.block_diffusion_mask(seq, block)
     t = 2 * seq
     allowed = _rule(seq, block)
     nq, nk = -(-t // bq), -(-t // bk)
-    need = np.array([[allowed[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()
-                      for j in range(nk)] for i in range(nq)])
-    exact = seq % bq == 0 and seq % bk == 0
+    seen = _check_classes(
+        allowed, bq, bk,
+        lambda i: pa._k_tiles(i, bq, bk, t, t, mask=mask),
+        lambda j: pa._q_tiles(j, bq, bk, t, t, mask=mask))
+    if seq % bq == 0 and seq % bk == 0:
+        for i in range(nq):
+            for j in range(nk):
+                part = allowed[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]
+                assert (j in seen[i]) == part.any(), (i, j)
+                if j in seen[i] and block < min(bq, bk):
+                    assert seen[i][j] == (not part.all()), (i, j)
+        q_seen = {j: _tiles(pa._q_tiles(jnp.int32(j), bq, bk, t, t,
+                                        mask=mask)) for j in range(nk)}
+        assert {(i, j): c for i in seen for j, c in seen[i].items()} == \
+            {(i, j): c for j in q_seen for i, c in q_seen[j].items()}
+    if (seq, block, bq, bk) == (16, 4, 8, 8):
+        assert sum(map(len, seen.values())) == 2 + 3 + 3    # of 16 tiles
 
-    def tiles(r):
-        lo1, hi1, lo2, hi2 = (int(x) for x in r)
-        assert hi1 <= lo1 or hi2 <= lo2 or lo2 >= hi1   # disjoint
-        return set(range(lo1, hi1)) | set(range(lo2, hi2))
 
-    for i in range(nq):
-        got = tiles(pa._k_tiles(mask, jnp.int32(i), bq, bk, t))
-        want = set(np.nonzero(need[i])[0])
-        assert want <= got <= set(range(nk)), (i, got, want)
-        assert not exact or got == want
-    for j in range(nk):
-        got = tiles(pa._q_tiles(mask, jnp.int32(j), bq, bk, t, t))
-        want = set(np.nonzero(need[:, j])[0])
-        assert want <= got <= set(range(nq)), (j, got, want)
-        assert not exact or got == want
-    if exact and bq == bk == 8 and seq == 16:
-        assert need.sum() == 2 + 3 + 3      # of 16 tiles: half are skipped
+def test_the_cells_call_visits_80_tiles_a_head_56_whole_and_24_cut(
+        monkeypatch):
+    """``mx.attn.tiles`` at sdar_30b_a3b_bd4k's shape, from a trace of the
+    call alone (``eval_shape`` runs nothing)."""
+    from mxnet_tpu import trace
+
+    mask = pa.block_diffusion_mask(4096, 4)
+    assert pa.tile_counts(8192, 8192, 512, 512, False, mask) == (80, 56, 24)
+    assert pa.tile_counts(512, 512, 512, 512) == (1, 1, 0)      # BERT's
+    monkeypatch.setattr(pa, "_TILES_NOTED", set())
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16)
+
+    def noted():
+        return [e["args"] for e in trace.events()
+                if e["name"] == "mx.attn.tiles"
+                and e["args"]["visited"] == 80]
+
+    before = len(noted())
+    for _ in range(2):      # one instant a distinct call, not one a trace
+        jax.eval_shape(lambda q, k, v: pa.flash_attention(q, k, v, mask=mask),
+                       q, kv, kv)
+    assert noted()[before:] == [{
+        "kind": "block_diffusion", "visited": 80, "whole": 56, "cut": 24,
+        "operand_dtype": "bfloat16"}]
 
 
 @pytest.mark.parametrize("seq,block,bq,bk,heads,kv_heads", [

@@ -11,9 +11,13 @@ them (an entry written for a described device cannot be read back).
 
 What interpret mode cannot show and these do: Mosaic accepts the in-kernel
 dropout's PRNG seeding, the kernels lower under a mesh without gathering the
-batch, and the fast-memory envelope (``flash_vmem_bytes``) is on the safe side
-of the compiler's own accounting.
+batch, the fast-memory envelope (``flash_vmem_bytes``) is on the safe side
+of the compiler's own accounting, and a bf16 caller's products reach the
+MXU as bf16 (the kernels' Mosaic modules are read before they are handed to
+the compiler).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -77,6 +81,69 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, causal, dropout_p):
     assert text.count("tpu_custom_call") == 3
 
 
+@pytest.fixture
+def mosaic_modules(monkeypatch):
+    """The text of every Mosaic module lowered while the test runs."""
+    from jax._src import tpu_custom_call
+
+    seen = []
+    lower = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def grab(module, **kw):
+        seen.append(str(module))
+        return lower(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", grab)
+    return seen
+
+
+@pytest.mark.parametrize("cell", ["bert_base_t512", "sdar_30b_a3b_bd4k"])
+def test_the_cells_kernels_feed_the_mxu_bf16(topo, mosaic_modules, cell):
+    """Both cells' shapes (``bf16[768,512,64]``; 32 query heads of 128 over
+    4 KV heads, 8,192 positions under the block-diffusion mask): every
+    ``tpu.matmul`` of the three kernels takes bf16 operands and accumulates
+    in float32, and no block of Q, K, V or dO is widened (no ``extf`` at
+    all: the only casts are p's and ds's ``truncf``)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    if cell == "bert_base_t512":
+        q = kv = jax.ShapeDtypeStruct((64, 12, 512, 64), jnp.bfloat16,
+                                      sharding=one)
+        kw, products = {}, (2, 3, 4)
+    else:
+        q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                                 sharding=one)
+        kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+                                  sharding=one)
+        # the body is traced once a class of tiles: 3, 3 and 4 ranges
+        kw, products = {"mask": pa.block_diffusion_mask(4096, 4)}, \
+            (2 * 3, 3 * 3, 4 * 4)
+
+    def f(q, k, v):
+        def loss(q, k, v):
+            return pa.flash_attention(q, k, v, interpret=False, **kw) \
+                .astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(f).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    if cell == "bert_base_t512":
+        assert "bf16[768,512,64]" in text
+    assert len(mosaic_modules) == 3             # forward, dq, dkv
+    for module, n in zip(mosaic_modules, products):
+        matmuls = re.findall(
+            r"tpu\.matmul.*?: (vector<[^>]*>), (vector<[^>]*>), "
+            r"(vector<[^>]*>)", module)
+        assert len(matmuls) == n, (len(matmuls), n)
+        for lhs, rhs, acc in matmuls:
+            assert lhs.endswith("xbf16>") and rhs.endswith("xbf16>") \
+                and acc.endswith("xf32>"), (lhs, rhs, acc)
+        assert "arith.extf" not in module
+    if cell == "bert_base_t512":    # no mask, no padding: no index, no select
+        for module in mosaic_modules:
+            assert "tpu.iota" not in module and "arith.select" not in module
+
+
 def test_block_diffusion_kernels_compile_for_v5e_with_grouped_kv(topo):
     """SDAR's shape: 8,192 positions [xt ; x0], 32 query heads of 128 over
     4 KV heads, the rule evaluated in the kernels (Mosaic takes no select
@@ -122,20 +189,23 @@ def test_flash_without_mesh_rows_cannot_lower_sharded(topo):
                  NamedSharding(mesh, P("dp")), NamedSharding(mesh, P()))
 
 
-@pytest.mark.parametrize("shape,dtype,block", [
-    ((1, 2, 24576, 128), jnp.float32, 1024),   # 83 MiB by the estimate
-    ((1, 2, 65536, 64), jnp.bfloat16, 512),    # 81 MiB
-    ((1, 2, 6144, 256), jnp.float32, 256),     # just over the default
+@pytest.mark.parametrize("shape,dtype,block,dropout_p", [
+    ((1, 2, 24576, 128), jnp.float32, 1024, 0.0),  # 83 MiB by the estimate
+    ((1, 2, 65536, 64), jnp.bfloat16, 512, 0.0),   # 81 MiB
+    ((1, 2, 6144, 256), jnp.float32, 256, 0.0),    # just over the default
+    # bf16 copies of p and ds beside the float32 tile, and the keep mask
+    ((1, 2, 65536, 64), jnp.bfloat16, 512, 0.1),
+    ((1, 2, 16384, 128), jnp.bfloat16, 1024, 0.1),
 ])
 def test_envelope_estimate_covers_the_compilers_need(topo, shape, dtype,
-                                                     block):
+                                                     block, dropout_p):
     """Inside the envelope the kernels ask for ``flash_vmem_bytes`` of
     VMEM, and that is enough for the compiler at the far end of it."""
     need = pa.flash_vmem_bytes(shape[2], shape[2], shape[3],
                                jnp.dtype(dtype).itemsize, block, block)
     assert pa.VMEM_DEFAULT_BYTES < need <= pa.VMEM_BUDGET_BYTES
-    text = _compile(_fwd_bwd(True, block_q=block, block_k=block), shape,
-                    dtype, SingleDeviceSharding(topo.devices[0]))
+    text = _compile(_fwd_bwd(True, dropout_p, block_q=block, block_k=block),
+                    shape, dtype, SingleDeviceSharding(topo.devices[0]))
     assert text.count("tpu_custom_call") == 3
 
 

@@ -98,7 +98,9 @@ def test_flash_dropout_gradient_finite_difference():
     dq, dk, dv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     rs = np.random.RandomState(2)
     d = jnp.asarray(rs.randn(*q.shape).astype(np.float32))
-    eps = 1e-3
+    # float32 sums of 12,288 outputs: at 1e-3 the difference quotient's own
+    # round-off is 1-2% of dq (it moves with where the kernels round)
+    eps = 1e-2
     for name, darg, idx in (("dq", dq, 0), ("dk", dk, 1), ("dv", dv, 2)):
         args = [q, k, v]
         ap = list(args)
@@ -239,3 +241,180 @@ def test_default_interpret_rejects_other_backends(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     with pytest.raises(MXNetError, match="'gpu'"):
         pa._default_interpret()
+
+
+# ---------------------------------------------------------------------------
+# the tile body: classes of tiles, and the dtype of the products' operands
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("tq,tk,bq,bk", [
+    (64, 64, 16, 16), (64, 64, 16, 32), (64, 64, 32, 8),
+    (72, 72, 16, 16), (40, 100, 16, 32), (100, 40, 32, 16), (50, 50, 64, 64)])
+def test_causal_tile_classes_against_the_materialised_triangle(tq, tk, bq,
+                                                               bk):
+    from test_block_diffusion_attention import _check_classes
+
+    allowed = np.tril(np.ones((tq, tk), bool))
+    _check_classes(
+        allowed, min(bq, tq), min(bk, tk),
+        lambda i: pa._k_tiles(i, min(bq, tq), min(bk, tk), tq, tk,
+                              causal=True),
+        lambda j: pa._q_tiles(j, min(bq, tq), min(bk, tk), tq, tk,
+                              causal=True))
+    if (tq, tk, bq, bk) == (64, 64, 16, 16):    # 4 cut on the diagonal
+        assert pa.tile_counts(tq, tk, bq, bk, True) == (10, 6, 4)
+
+
+@pytest.mark.parametrize("tq,tk,bq,bk,want", [
+    (64, 64, 16, 16, (16, 16, 0)),      # nothing to mask: no tile is cut
+    (72, 88, 32, 32, (9, 6, 3)),        # the padded last key tile a row
+    (512, 512, 512, 512, (1, 1, 0)),    # BERT's call
+])
+def test_unmasked_calls_cut_only_the_padded_last_tile(tq, tk, bq, bk, want):
+    assert pa.tile_counts(tq, tk, bq, bk) == want
+
+
+_CALLS = {      # (Tq, Tk, query heads, KV heads, keywords)
+    "plain": (64, 64, 2, 2, {}),
+    "padded": (72, 88, 2, 2, {}),
+    "causal": (96, 96, 2, 2, {"causal": True}),
+    "causal_padded_grouped": (80, 80, 4, 2, {"causal": True}),
+    "causal_uneven_tiles": (96, 96, 2, 2, {"causal": True, "block_q": 16}),
+    "dropout": (64, 64, 2, 2, {"dropout_p": 0.2}),
+    "dropout_causal_padded": (72, 72, 4, 2, {"causal": True,
+                                             "dropout_p": 0.2}),
+    "masked_grouped": (128, 128, 4, 2,
+                       {"mask": pa.block_diffusion_mask(64, 4)}),
+    "masked_astride": (88, 88, 2, 1,       # tiles astride L, a padded end
+                       {"mask": pa.block_diffusion_mask(44, 4),
+                        "block_q": 16, "block_k": 16}),
+    "masked_dropout": (64, 64, 2, 2,
+                       {"mask": pa.block_diffusion_mask(32, 4),
+                        "block_q": 16, "block_k": 16, "dropout_p": 0.1}),
+}
+
+
+def _all_four(tq, tk, heads, kv_heads, kw, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (2, heads, tq, 16)).astype(dtype)
+    k = jax.random.normal(ks[1], (2, kv_heads, tk, 16)).astype(dtype)
+    v = jax.random.normal(ks[2], (2, kv_heads, tk, 16)).astype(dtype)
+    w = jax.random.normal(ks[3], (2, heads, tq, 16))
+    kw = dict({"block_q": 32, "block_k": 32}, **kw)
+    if kw.get("dropout_p"):
+        kw["dropout_key"] = jax.random.PRNGKey(5)
+
+    def loss(q, k, v):
+        return (pa.flash_attention(q, k, v, **kw).astype(jnp.float32)
+                * w).sum()
+
+    return (pa.flash_attention(q, k, v, **kw),) + jax.grad(
+        loss, (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("call", sorted(_CALLS))
+def test_whole_cut_split_is_bitwise_neutral(call, dtype, monkeypatch):
+    """Skipping the mask arithmetic on a tile that is allowed whole changes
+    no bit: the same call with every visited tile classified as cut."""
+    tq, tk, heads, kv_heads, kw = _CALLS[call]
+    _, whole, _ = pa.tile_counts(tq, tk, kw.get("block_q", 32),
+                                 kw.get("block_k", 32),
+                                 kw.get("causal", False), kw.get("mask"))
+    assert whole > 0                    # or the case shows nothing
+    got = _all_four(tq, tk, heads, kv_heads, kw, dtype)
+    for name in ("_k_tiles", "_q_tiles"):
+        monkeypatch.setattr(
+            pa, name, lambda *a, _real=getattr(pa, name), **k: [
+                (lo, hi, True) for lo, hi, _ in _real(*a, **k)])
+    want = _all_four(tq, tk, heads, kv_heads, kw, dtype)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)),
+                                      err_msg=name)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (the kernels'
+    bodies, their loops, the custom_vjp's branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("kw", [{}, {"causal": True}, {"dropout_p": 0.1},
+                                {"mask": pa.block_diffusion_mask(64, 4)}],
+                         ids=["plain", "causal", "dropout", "masked"])
+def test_a_float32_call_keeps_float32_operands_in_every_product(kw):
+    """The operands' dtype follows the caller's: float32 in, float32
+    products, and no cast of p or ds (no float is converted at all)."""
+    kw = dict(kw, **({"dropout_key": jax.random.PRNGKey(0)}
+                     if "dropout_p" in kw else {}))
+    x = jnp.ones((1, 2, 128, 16), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: pa.flash_attention(q, k, v, block_q=32, block_k=32,
+                                           **kw).sum(), (0, 1, 2)))(x, x, x)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) >= 2 + 3 + 4
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.float32] * 2
+        assert e.params["preferred_element_type"] == jnp.float32
+    casts = [e for e in eqns if e.primitive.name == "convert_element_type"
+             and jnp.issubdtype(e.invars[0].aval.dtype, jnp.floating)
+             and e.invars[0].aval.shape]    # not a weakly typed constant
+    assert not casts, casts
+
+
+def test_a_bf16_call_feeds_the_products_bf16_and_accumulates_in_float32():
+    x = jnp.ones((1, 2, 128, 16), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: pa.flash_attention(
+            q, k, v, block_q=32, block_k=32).astype(jnp.float32).sum(),
+        (0, 1, 2)))(x, x, x)
+    dots = [e for e in _eqns(jaxpr.jaxpr)
+            if e.primitive.name == "dot_general"]
+    assert len(dots) == 2 + 3 + 4
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+        assert e.outvars[0].aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("kw,kv_heads", [
+    ({}, 4), ({"causal": True}, 4), ({"causal": True}, 2),
+    ({"mask": pa.block_diffusion_mask(128, 4)}, 2)],
+    ids=["plain", "causal", "causal_grouped", "masked_grouped"])
+def test_bf16_kernels_agree_with_the_dense_bf16_path(kw, kv_heads):
+    """Forward and the three gradients against ``ops/nn.py``'s dense path
+    on the same bf16 arrays (bf16 products accumulated in float32, the
+    probabilities cast to bf16 for the second product): within two
+    rounding steps of bf16 at the tensor's scale."""
+    from mxnet_tpu import nd
+
+    heads, d, t = 4, 32, 256
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    q = jax.random.normal(ks[0], (2, t, heads * d)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (2, t, kv_heads * d)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (2, t, kv_heads * d)).astype(jnp.bfloat16)
+    w = jax.random.normal(ks[3], (2, t, heads * d))
+
+    def run(impl):
+        def f(q, k, v):
+            return nd.multi_head_attention(
+                nd.NDArray(q), nd.NDArray(k), nd.NDArray(v),
+                num_heads=heads, num_kv_heads=kv_heads, impl=impl,
+                **kw)._data
+
+        def loss(q, k, v):
+            return (f(q, k, v).astype(jnp.float32) * w).sum()
+
+        return (f(q, k, v),) + jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    for name, a, b in zip(("out", "dq", "dk", "dv"), run("pallas"),
+                          run("dense")):
+        assert a.dtype == jnp.bfloat16
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+        step = 2.0 ** -8 * np.abs(b).max()
+        assert np.abs(a - b).max() <= 2 * step, (name, np.abs(a - b).max(),
+                                                 step)
