@@ -16,7 +16,10 @@ users write (the loss fetched every ``log_every`` steps).  Set-up makes
 weights and a pool of batches on the device from ``--seed``, builds the
 trainer, drives its first steps (they compile, warm up, and are what the
 plain reference follows afterwards), and hands the same trainer to the
-measured window.  After the window: counts of compiles, where the state
+measured window.  The window lasts ``--seconds``, or until the traffic's
+``min_steps`` steps are done if that is later (how a cell with a long step
+gets enough samples under its percentile); a traced run stops at
+``trace_steps``.  After the window: counts of compiles, where the state
 lives, peak memory; then the trainer is freed and the reference runs.
 
 A run that finds no TPU, or fewer chips than the cell needs, exits non-zero
@@ -42,6 +45,12 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 STEP_SPAN, FETCH_SPAN = "chipbench.step", "chipbench.fetch"
 SAMPLE = 4096  # elements of a leaf's first gradient kept for check.grad_diff
+# The fewest samples a whole run's step_ms_p95 may be taken over.
+# statistics.quantiles(samples, n=20)[-1] stands at position 0.95 * (n + 1),
+# counted from 1: from n = 39 on that is the second largest sample or below,
+# so one stalled step of the shared host is not the reading (at n = 29 it is
+# the mean of the two largest).
+MIN_SAMPLES = 40
 
 
 def load_json(*parts):
@@ -110,6 +119,16 @@ def off_device(tree, devices):
         return ["nothing to check"]
     return [str((a.shape, a.devices())) for a in leaves
             if a.devices() != devices]
+
+
+def enough_samples(workload, n):
+    """A whole run's percentile needs MIN_SAMPLES samples behind it."""
+    if n < MIN_SAMPLES:
+        raise SystemExit(
+            "chipbench: cell %s: step_ms_p95 over %d samples would be all "
+            "but the largest of them; a window has to hold %d (a longer "
+            "step wants `min_steps` in the cell's traffic file)"
+            % (workload, n, MIN_SAMPLES))
 
 
 def seed_key(seed):
@@ -202,16 +221,22 @@ class Cell:
 
     def make(self, seed):
         """(weights, pool of batches) on the device(s) from the seed, in
-        one jitted call; batches laid over the mesh as the trainer wants."""
+        one jitted call; batches laid over the mesh as the trainer wants.
+        Batch ``i`` (from 1) is ``make_batch`` under ``fold_in(key, i)``,
+        traced once for the whole pool, however many it holds."""
         import jax
+        import jax.numpy as jnp
 
         b, cfg, traffic = self.builder, self.cfg, self.traffic
-        key = seed_key(seed)
+        key, pool = seed_key(seed), traffic["pool"]
 
         def make_all(key):
+            stacked = jax.vmap(lambda i: b.make_batch(
+                cfg, traffic, jax.random.fold_in(key, i)))(
+                    1 + jnp.arange(pool))
             return (b.make_weights(cfg, jax.random.fold_in(key, 0)),
-                    [b.make_batch(cfg, traffic, jax.random.fold_in(key, i))
-                     for i in range(1, traffic["pool"] + 1)])
+                    [jax.tree_util.tree_map(lambda a: a[i], stacked)
+                     for i in range(pool)])
 
         if self.mesh is None:
             return jax.jit(make_all)(key)
@@ -291,6 +316,7 @@ def run_cell(workload, seed, seconds, trace, rehearse=False):
         options.host_tracer_level = 2
         jax.profiler.start_trace(trace_dir, profiler_options=options)
     max_steps = traffic["trace_steps"] if trace else None
+    min_steps = 0 if trace else traffic.get("min_steps", 0)
     compiles_before = compiles.count
     arrivals, losses, steps_done = [], [], 0
     setup_s = time.perf_counter() - T0
@@ -306,8 +332,8 @@ def run_cell(workload, seed, seconds, trace, rehearse=False):
                 losses.append(float(loss.asnumpy()))
             now = time.perf_counter()
             arrivals.append((steps_done, now))
-            if now - t_start >= seconds or \
-                    (max_steps and steps_done >= max_steps):
+            if (max_steps and steps_done >= max_steps) or \
+                    (now - t_start >= seconds and steps_done >= min_steps):
                 break
     window_s = arrivals[-1][1] - t_start
     if trace:
@@ -363,6 +389,7 @@ def run_cell(workload, seed, seconds, trace, rehearse=False):
                         "window_s": reduced["window_s"]}
         breakdown = reduced["breakdown"]
     elif not rehearse:
+        enough_samples(workload, len(samples))
         rate = "train_%s_per_s" % builder.UNIT
         metrics[rate] = {
             "value": steps_done * builder.units_per_step(cell.cfg, traffic)
@@ -383,6 +410,7 @@ def run_cell(workload, seed, seconds, trace, rehearse=False):
     result.update({
         "workload": workload, "seed": seed, "rehearsal": bool(rehearse),
         "window_s": window_s, "step_ms_median": statistics.median(samples),
+        "step_samples": len(samples),
         "setup_phases_s": phases, "reference_s": reference_s,
         "compile_s": compiles.seconds, "first_losses": program["losses"],
         "worst_leaves": leaves, "numbers": numbers})

@@ -187,18 +187,20 @@ def test_every_reader_this_cell_reports_finds_no_trace(tmp_path, monkeypatch):
     sys.modules.setdefault("block_readers", block_readers)
     for name in names:
         assert _load(name, "metrics").read({}) is None
-    # this PR's entries were appended, at the end, straight after PR 24's
-    # five, which keep their order, unit and end-to-end metric: all that
-    # test_spans.py's pinned test asserts but "the LAST five" (conftest.py)
-    assert [m["name"] for m in manifest["per_layer"][-4:]] == [
-        "flash_bd_fwd_roofline", "flash_bd_bwd_roofline", "moe_route_ms",
-        "moe_experts_ms"]
-    theirs = manifest["per_layer"][-9:-4]
+    # this PR's entries stand straight after PR 24's five, which keep their
+    # order, unit and end-to-end metric.  Nothing here says "the last": a
+    # later PR appends its own entries, cells and configurations
+    order = [m["name"] for m in manifest["per_layer"]]
+    at = order.index(list(spans.METRICS)[0])
+    theirs = manifest["per_layer"][at:at + 5]
     assert [m["name"] for m in theirs] == list(spans.METRICS)
     assert {(m["moves"], m["unit"]) for m in theirs} == {
         ("step_ms_p95", "ms")}
-    assert manifest["workloads"][-1]["name"] == CELL
-    assert manifest["configs"][-1]["name"] == "sdar_30b_a3b"
+    assert order[at + 5:at + 9] == [
+        "flash_bd_fwd_roofline", "flash_bd_bwd_roofline", "moe_route_ms",
+        "moe_experts_ms"]
+    assert CELL in [w["name"] for w in manifest["workloads"]]
+    assert "sdar_30b_a3b" in [c["name"] for c in manifest["configs"]]
 
 
 # ------------------------------ the reference against the program, float32

@@ -155,7 +155,6 @@ def test_every_new_metric_has_a_reader_that_finds_no_trace(tmp_path,
         manifest = json.load(f)
     mine = [m for m in manifest["per_layer"] if m["name"] in spans.METRICS]
     assert [m["name"] for m in mine] == list(spans.METRICS)
-    assert manifest["per_layer"][-5:] == mine          # appended, at the end
     monkeypatch.setattr(spans, "ROOT", str(tmp_path))
     for m in mine:
         assert m["moves"] == "step_ms_p95" and m["unit"] == "ms"
