@@ -12,7 +12,7 @@ SO := build/libmxtpu_native.so
 .PHONY: native test cpptest telemetry-smoke checkpoint-smoke serve-smoke \
 	decode-smoke compile-cache-smoke trainer-smoke step-smoke \
 	trace-smoke monitor-smoke faults-smoke dist-faults-smoke \
-	zero-smoke shard-smoke autotune-smoke data-smoke obs-smoke \
+	zero-smoke shard-smoke data-smoke obs-smoke \
 	fleet-smoke cache-smoke tenant-smoke smoke-all clean
 
 native: $(SO)
@@ -186,18 +186,6 @@ dist-faults-smoke:
 	JAX_PLATFORMS=cpu python -m pytest \
 	  tests/python/unittest/test_dist_ft.py -q -m 'not slow'
 
-# mx.autotune smoke: search-tune two sites on CPU (winner measured
-# under the bitwise numerics guard and durably committed) -> a fresh
-# interpreter serves the tuned configs with ZERO re-measurement
-# (telemetry-asserted) and bit-identical outputs -> a corrupted record
-# is quarantined and degrades to the hand-set default with
-# autotune_fallback_total counted -> the store dir removed entirely
-# still runs clean; then the subsystem's pytest suite
-autotune-smoke:
-	JAX_PLATFORMS=cpu python tools/autotune_smoke.py
-	JAX_PLATFORMS=cpu python -m pytest \
-	  tests/python/unittest/test_autotune.py -q -m 'not slow'
-
 # mx.obs observability-plane smoke: 2-rank fleet drill (cross-rank
 # aggregation merged on BOTH ranks + seeded slow rank fires exactly one
 # straggler episode), serve SLO burn-rate OK -> PAGE -> OK round trip
@@ -264,7 +252,6 @@ SMOKES := \
 	monitor-smoke \
 	checkpoint-smoke \
 	step-smoke \
-	autotune-smoke \
 	serve-smoke \
 	obs-smoke \
 	zero-smoke \
@@ -278,10 +265,10 @@ SMOKES := \
 	dist-faults-smoke
 # approx wall time:        telemetry ~15s, trace ~25s, compile-cache
 # ~35s, trainer ~35s, monitor ~40s, checkpoint ~45s, step ~45s,
-# autotune ~50s, serve ~60s, obs ~75s, zero ~90s, shard ~90s,
+# serve ~60s, obs ~75s, zero ~90s, shard ~90s,
 # decode ~100s, tenant ~100s, cache ~2min, faults ~2min, data ~3min,
 # fleet ~3min, dist-faults ~4min (multi-process drills last; total
-# ~21min cold)
+# ~20min cold)
 smoke-all:
 	@set -e; for t in $(SMOKES); do \
 	  echo "== $$t =="; \

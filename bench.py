@@ -1131,53 +1131,6 @@ def _bench_shard_pipeline(iters=8):
     }
 
 
-def _bench_autotune():
-    """mx.autotune sweep rows: tuned-vs-default deltas for the
-    allreduce bucket-size sweep (ResNet-50-shaped gradient profile)
-    and the flash-attention block sweep (BERT-shaped T=512 workload).
-    Each entry carries the measured default/winner ms, the speedup,
-    and the per-candidate audit (incl. numerics-guard rejections).  Runs
-    against a throwaway store so a bench never pollutes (or reads)
-    the deployed TuningStore."""
-    import shutil
-    import tempfile
-
-    from mxnet_tpu import autotune
-
-    store_dir = tempfile.mkdtemp(prefix="mx-bench-autotune-")
-    out = {}
-    prev_mode = autotune.mode()  # restore a user-armed MXNET_AUTOTUNE
-    try:
-        autotune.enable("search", root=store_dir)
-
-        def row(site, key, **kw):
-            res = autotune.tune(site, key, **kw)
-            r = res.as_dict()
-            r["speedup_vs_default"] = round(
-                res.default_ms / res.winner_ms, 3) \
-                if res.winner_ms else None
-            r["rejected_numerics"] = sum(
-                1 for c in res.candidates
-                if c["status"] == "rejected_numerics")
-            return r
-
-        # ResNet-50 fp32 master grads: ~161 arrays, ~102 MiB
-        out["allreduce_bucket_sweep"] = row(
-            "allreduce_bucket", (161, 102 << 20, 1),
-            budget_ms=60000, repeats=3, warmup=1)
-        # BERT-base-shaped attention: B=1, H=12, T=512, D=64
-        out["flash_attention_block_sweep"] = row(
-            "flash_attention", (1, 12, 512, 512, 64, "float32", False),
-            budget_ms=120000, repeats=3, warmup=1)
-    finally:
-        # restore the pre-sweep mode (enable() re-resolves the store
-        # from the env) — a bare disable() would latch a user-armed
-        # MXNET_AUTOTUNE=1 off for every later bench row
-        autotune.enable(prev_mode)
-        shutil.rmtree(store_dir, ignore_errors=True)
-    return out
-
-
 def main():
     extra = {}
     # JAX's persistent compilation cache, before anything compiles
@@ -1288,10 +1241,6 @@ def main():
             # decoding accepted-tokens-per-target-step
             ("serve_cache", _bench_serve_cache,
              "serve_cache_per_token_cost"),
-            # mx.autotune tuned-vs-default sweeps: allreduce bucket
-            # size on a ResNet-50 gradient profile + flash-attention
-            # block grid at BERT's T=512
-            ("autotune_sweeps", _bench_autotune, "autotune_sweeps"),
             # flash fwd+bwd kernel vs blockwise recompute
             ("attention_T2k", lambda: _attn(2048), "attention_T2k"),
             ("attention_T8k", lambda: _attn(8192), "attention_T8k"),

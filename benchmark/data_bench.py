@@ -137,7 +137,7 @@ def captured_ring_row(rec, args, steps=8):
     def loader():
         return mxdata.StreamLoader(
             rec, batch_size=batch, seed=1, decode_fn=_stream_decode,
-            num_workers=args.threads, prefetch=None)  # env/autotune depth
+            num_workers=args.threads, prefetch=None)  # MXNET_DATA_PREFETCH depth
 
     # pre-staged: batches already device-resident before the clock
     _net, prog = build()

@@ -78,7 +78,6 @@ from . import image  # noqa: E402
 from . import checkpoint  # noqa: E402  (async/sharded/atomic persistence)
 from . import serve  # noqa: E402  (dynamic-batching inference serving)
 from . import compile  # noqa: E402,A004  (persistent compile cache + AOT)
-from . import autotune  # noqa: E402  (self-tuning kernels/buckets/flags)
 from . import monitor  # noqa: E402  (training-health numerics + sentinel)
 from . import resilience  # noqa: E402  (fault injection + preempt + supervisor)
 from . import dist  # noqa: E402  (multi-host membership + pod checkpoints)

@@ -267,8 +267,7 @@ ENV_VARS = {
         "mx.data prefetch ring depth: batches asynchronously staged "
         "onto their device/mesh shardings ahead of the training loop "
         "(data/ring.py; the PERF_PLAN H3 fix).  >= 2 keeps captured-"
-        "step dispatch off the H2D critical path; also tunable via "
-        "the data_prefetch autotune site."),
+        "step dispatch off the H2D critical path."),
     "MXNET_DATA_WORKERS": (
         int, 2,
         "Reader worker threads per host in mx.data.StreamLoader "
@@ -375,10 +374,9 @@ ENV_VARS = {
     "MXNET_SERVE_SPEC_K": (
         int, 0,
         "Speculative decoding draft proposal count per round "
-        "(serve/spec.py; needs DecodeRunner(draft=...)); 0 resolves "
-        "the 'spec_k' autotune site / the built-in default.  Greedy "
-        "acceptance keeps output bit-identical to single-step "
-        "decode."),
+        "(serve/spec.py; needs DecodeRunner(draft=...)); 0 means "
+        "the built-in default of 4.  Greedy acceptance keeps output "
+        "bit-identical to single-step decode."),
     "MXNET_FLEET_PUBLISH_SECONDS": (
         float, 1.0,
         "Min seconds between a replica's discovery-record publishes "
@@ -428,10 +426,9 @@ ENV_VARS = {
         int, 8,
         "Adapter bank capacity: how many LoRA adapters are "
         "device-resident per decode runner (tenant/adapters.py).  "
-        "Resolved through the 'adapter_slots' autotune site when "
-        "MXNET_AUTOTUNE is on; changing it re-specializes the decode "
-        "programs (one-time recompile, then hot add/remove swaps "
-        "slots with zero recompiles)."),
+        "Changing it re-specializes the decode programs (one-time "
+        "recompile, then hot add/remove swaps slots with zero "
+        "recompiles)."),
     "MXNET_TENANT_MAX_RANK": (
         int, 8,
         "Max LoRA rank the adapter bank accepts; lower-rank adapters "
@@ -456,45 +453,6 @@ ENV_VARS = {
         "Default per-tenant waiting-queue depth; a tenant whose "
         "backlog reaches it gets 503 + Retry-After while other "
         "tenants keep flowing (tenant/quota.py)."),
-    "MXNET_AUTOTUNE": (
-        str, "0",
-        "mx.autotune mode: 0 (default) = hand-set literals everywhere, "
-        "zero store I/O; 1 = consumers look tuned configs up in the "
-        "persistent TuningStore at build time (a miss or ANY store "
-        "failure degrades to the default, counted in "
-        "autotune_fallback_total); search = additionally run the "
-        "measured search where it is safe (serve/decode warm-up idle "
-        "tuners, tools/autotune_smoke.py, bench sweep rows, explicit "
-        "autotune.tune()).  A tuned winner is always bit-identical to "
-        "the default — the measure harness rejects candidates that "
-        "change numerics (autotune/)."),
-    "MXNET_AUTOTUNE_DIR": (
-        str, None,
-        "TuningStore directory (default <MXNET_HOME>/autotune — next "
-        "to the mx.compile cache).  Records are partitioned by the "
-        "compile cache's environment fingerprint, so platform/"
-        "topology/version/XLA-flag drift is a clean miss back to "
-        "defaults."),
-    "MXNET_AUTOTUNE_BUDGET_MS": (
-        float, 2000.0,
-        "Wall-clock budget per tune() search and per idle-tuning "
-        "pass; unmeasured candidates are recorded as skipped and the "
-        "default stays in force for them."),
-    "MXNET_AUTOTUNE_REPEATS": (
-        int, 5,
-        "Timed repeats per measured candidate (trimmed mean: min and "
-        "max dropped at >=4)."),
-    "MXNET_AUTOTUNE_WARMUP": (
-        int, 2,
-        "Discarded warm-up runs per measured candidate (after the "
-        "compile/correctness run)."),
-    "MXNET_AUTOTUNE_PRUNE": (
-        int, 0,
-        "When > 0, the table cost model (autotune/model.py) prunes "
-        "each search grid to the top-N predicted candidates before "
-        "measuring; a cold model (no stored measurements for the "
-        "site) always falls back to exhaustive measurement.  0 "
-        "disables pruning."),
     "MXNET_TELEMETRY_DISABLE": (
         bool, False,
         "Disable the runtime telemetry registry (mx.telemetry); hooks "

@@ -50,21 +50,6 @@ def default_workers():
     return max(1, get_env("MXNET_DATA_WORKERS", int, 2))
 
 
-def _tuned_prefetch(local_batch, sample_nbytes):
-    """Resolve (depth, workers) through the ``data_prefetch`` autotune
-    site — structural (order-preserving by construction), so a tuned
-    config changes overlap, never the sample stream."""
-    from .. import autotune
-
-    default = {"depth": default_depth(), "workers": default_workers()}
-    key = (int(local_batch), int(sample_nbytes))
-    cfg = autotune.lookup("data_prefetch", key, default)
-    try:
-        return max(1, int(cfg["depth"])), max(1, int(cfg["workers"]))
-    except Exception:
-        return default["depth"], default["workers"]
-
-
 class StreamLoader:
     """Sharded streaming loader with a device-resident prefetch ring.
 
@@ -81,7 +66,7 @@ class StreamLoader:
         staged batches land on its ``batch_sharding`` — the placement
         the captured step program consumes without a second copy.
     num_workers / prefetch : reader threads and ring depth (default:
-        env knobs, through the ``data_prefetch`` autotune site).
+        ``MXNET_DATA_WORKERS`` / ``MXNET_DATA_PREFETCH``).
     num_hosts / host : world coordinates override (drills).
     """
 
@@ -129,14 +114,10 @@ class StreamLoader:
                 "local batch of %d" % (self.host, self.num_hosts,
                                        len(self._entries),
                                        self.local_batch))
-        tuned = None
-        if num_workers is None or prefetch is None:
-            est = max(1, self._probe_sample_bytes()) if self._entries \
-                else 1
-            tuned = _tuned_prefetch(self.local_batch, est)
-        self.num_workers = tuned[1] if num_workers is None \
+        self.num_workers = default_workers() if num_workers is None \
             else int(num_workers)
-        self.prefetch = tuned[0] if prefetch is None else int(prefetch)
+        self.prefetch = default_depth() if prefetch is None \
+            else int(prefetch)
         if self.num_workers < 1 or self.prefetch < 1:
             raise MXNetError(
                 "StreamLoader needs num_workers >= 1 and prefetch >= 1 "
@@ -158,17 +139,6 @@ class StreamLoader:
         self._install_preempt_hook()
         with _LIVE_LOCK:
             _LIVE.add(self)
-
-    def _probe_sample_bytes(self):
-        shard = self._set.shards[self._entries[0][0]]
-        # file size / record count ~ mean framed record size; a cheap
-        # workload feature for the data_prefetch autotune key
-        import os as _os
-
-        try:
-            return _os.path.getsize(shard.path) // max(1, len(shard))
-        except OSError:
-            return 1
 
     # -- resilience ------------------------------------------------------------
     def _install_preempt_hook(self):

@@ -36,37 +36,8 @@ def default_bucket_bytes():
     keys fuse into one collective program (reference:
     MXNET_KVSTORE_BIGARRAY_BOUND splits big arrays; here the knob
     bounds how many small keys fuse into one psum launch).  Re-read
-    from the environment per call — mx.autotune varies the effective
-    bucket size at plan time, so nothing may cache this at import."""
+    from the environment per call: nothing caches it at import."""
     return int(get_env("MXNET_KVSTORE_BUCKET_BYTES", int, 4 << 20))
-
-
-def tuned_bucket_bytes(sizes_dtypes, world=None):
-    """``(bucket_bytes, provenance)`` for one gradient list: the
-    mx.autotune ``allreduce_bucket`` winner for this workload key —
-    (n_arrays, total_bytes, world) — else the hand-set default.
-    Provenance is ``tuned`` or ``default`` (consumed by the step
-    capture report and diagnose)."""
-    base = default_bucket_bytes()
-    from .. import autotune as _at
-
-    if not _at.is_enabled():
-        return base, "default"
-    if world is None:
-        world = jax.process_count()
-    total = int(sum(int(s) for s, _d in sizes_dtypes))
-    cfg, prov = _at.lookup_info(
-        "allreduce_bucket", (len(sizes_dtypes), total, int(world)), base)
-    if prov != "tuned":
-        return base, "default"
-    try:
-        bb = int(cfg)
-    except (TypeError, ValueError):
-        bb = 0
-    if bb <= 0:
-        _at.fallback("invalid_config")
-        return base, "default"
-    return bb, "tuned"
 
 
 def plan_buckets(sizes_dtypes, bucket_bytes=None):
@@ -107,9 +78,9 @@ def observe_bucket_fill(bucket_nbytes, op=None, bucket_bytes=None):
     that observation point never runs, so it feeds its static plan
     through here each dispatch — keeping the two paths comparable in
     telemetry.  ``bucket_bytes`` is the bucket size the plan was
-    ACTUALLY built with (a custom ``plan_buckets(bucket_bytes=...)`` or
-    an autotuned winner); normalizing against anything else would lie
-    about fill the moment the size varies, so callers with a plan must
+    ACTUALLY built with (a custom ``plan_buckets(bucket_bytes=...)``);
+    normalizing against anything else would lie about fill the moment
+    the size varies, so callers with a plan must
     pass theirs — None falls back to the current env default.  ``op``
     additionally accounts the collective itself (one call per bucket,
     PAYLOAD bytes — the same semantics the eager ``_allreduce_many``
@@ -238,10 +209,10 @@ class CollectiveKVStore(KVStoreBase):
         out = [None] * len(datas)
         sizes = [(d.size * d.dtype.itemsize, str(d.dtype))
                  for d in datas]
-        # the plan's ACTUAL bucket size (autotuned winner or env
-        # default) — threaded through to the fill observation below so
-        # fill numbers stay truthful when the size varies
-        bucket_bytes, _prov = tuned_bucket_bytes(sizes)
+        # the plan's ACTUAL bucket size, read once — threaded through
+        # to the fill observation below so fill numbers stay truthful
+        # when the environment changes between the two
+        bucket_bytes = default_bucket_bytes()
         plan = plan_buckets(sizes, bucket_bytes=bucket_bytes)
         for b, idxs in enumerate(plan):
             bucket = [(i, datas[i]) for i in idxs]
@@ -249,8 +220,7 @@ class CollectiveKVStore(KVStoreBase):
             tel_on = _tel.ENABLED
             t0 = _time.perf_counter() if tel_on else 0.0
             # one flight-recorder span per collective program: bucket
-            # index / key count / bytes are exactly the per-(op, phase)
-            # measurements the autotune direction needs (ROADMAP 3)
+            # index / key count / bytes
             with _trace.span("allreduce_bucket", hist=False,
                              args={"bucket": b, "keys": len(idxs),
                                    "bytes": nbytes}):

@@ -159,30 +159,6 @@ def _conv_dims(ndim, layout):
     return layout, w_layout, out_layout
 
 
-def _tuned_conv_layout(x, weight, stride, layout):
-    """PERF_PLAN H1 hook: for default-layout 2-D convs, consult the
-    mx.autotune ``conv_layout`` site.  Only an explicit tuned "NHWC"
-    winner changes anything (the conv runs with NHWC dimension numbers
-    between a transpose-in/transpose-out pair — models stay NCHW);
-    autotune off, a cold store, or any malformed record keeps today's
-    NCHW path untouched."""
-    if layout is not None or x.ndim != 4:
-        return "NCHW"
-    from .. import autotune as _at
-
-    if not _at.is_enabled():
-        return "NCHW"
-    n, c, h, w = x.shape
-    o, _i, kh, kw = weight.shape
-    cfg = _at.lookup(
-        "conv_layout",
-        (n, c, h, w, o, kh, kw, int(stride[0]), str(x.dtype)), "NCHW")
-    if cfg not in ("NCHW", "NHWC"):
-        _at.fallback("invalid_config")
-        return "NCHW"
-    return cfg
-
-
 @register("convolution")
 def convolution(x, weight, bias=None, kernel=None, stride=None, dilate=None,
                 pad=None, num_filter=None, num_group=1, no_bias=False,
@@ -195,28 +171,13 @@ def convolution(x, weight, bias=None, kernel=None, stride=None, dilate=None,
     dn_layout = _conv_dims(nd, layout)
     x, weight = _amp_pair(x, weight)
     # (see fully_connected) bf16 convs accumulate f32 on the MXU natively
-    if _tuned_conv_layout(x, weight, stride, layout) == "NHWC":
-        # H1 tuned winner: identical conv math through NHWC dimension
-        # numbers — XLA folds the operand transposes into its layout
-        # assignment where that pays
-        dn = lax.conv_dimension_numbers(
-            (x.shape[0], x.shape[2], x.shape[3], x.shape[1]),
-            (weight.shape[2], weight.shape[3], weight.shape[1],
-             weight.shape[0]),
-            ("NHWC", "HWIO", "NHWC"))
-        y = lax.conv_general_dilated(
-            x.transpose(0, 2, 3, 1), weight.transpose(2, 3, 1, 0),
-            window_strides=stride, padding=[(p, p) for p in pad],
-            rhs_dilation=dilate, dimension_numbers=dn,
-            feature_group_count=num_group).transpose(0, 3, 1, 2)
-    else:
-        dn = lax.conv_dimension_numbers(
-            x.shape, weight.shape, dn_layout[:2] + (dn_layout[2],))
-        y = lax.conv_general_dilated(
-            x, weight, window_strides=stride,
-            padding=[(p, p) for p in pad],
-            rhs_dilation=dilate, dimension_numbers=dn,
-            feature_group_count=num_group)
+    dn = lax.conv_dimension_numbers(
+        x.shape, weight.shape, dn_layout[:2] + (dn_layout[2],))
+    y = lax.conv_general_dilated(
+        x, weight, window_strides=stride,
+        padding=[(p, p) for p in pad],
+        rhs_dilation=dilate, dimension_numbers=dn,
+        feature_group_count=num_group)
     if bias is not None and not no_bias:
         lay = dn_layout[0]
         c_axis = lay.index("C")
@@ -326,28 +287,6 @@ def adaptive_avg_pooling(x, output_size=1):
 # ---- normalization (reference nn/batch_norm.cc etc.) ----------------------
 
 
-def _tuned_bn_stat_dtype(x, axis, stat_dtype):
-    """PERF_PLAN H2 hook: the batch-stat reduction dtype.  Explicit
-    ``stat_dtype`` wins; otherwise the mx.autotune ``bn_stat_dtype``
-    winner — which under the bitwise numerics guard can only ever be a
-    value that measured bit-identical to f32 — else today's f32.  The
-    reduction ``axis`` is part of the key: bit-identity certified for
-    one reduction geometry says nothing about another."""
-    if stat_dtype is not None:
-        return stat_dtype
-    from .. import autotune as _at
-
-    if not _at.is_enabled():
-        return "float32"
-    cfg = _at.lookup("bn_stat_dtype",
-                     tuple(x.shape) + (int(axis), str(x.dtype)),
-                     "float32")
-    if cfg not in ("float32", "bfloat16"):
-        _at.fallback("invalid_config")
-        return "float32"
-    return cfg
-
-
 @register("batch_norm", num_outputs=3)
 def batch_norm(x, gamma, beta, moving_mean, moving_var, eps=1e-5,
                momentum=0.9, fix_gamma=False, use_global_stats=False,
@@ -358,9 +297,8 @@ def batch_norm(x, gamma, beta, moving_mean, moving_var, eps=1e-5,
     op side effect there; here it is an explicit functional output that the
     Gluon layer writes back (XLA-friendly: no hidden state in the graph).
 
-    ``stat_dtype`` (None -> mx.autotune ``bn_stat_dtype`` site, default
-    "float32") is the dtype the batch mean/var reduce in — PERF_PLAN
-    hypothesis H2.  The f32 default path is byte-for-byte today's code.
+    ``stat_dtype`` (None -> "float32") is the dtype the batch mean/var
+    reduce in; "bfloat16" is the only other value.
     """
     reduce_axes = tuple(i for i in range(x.ndim) if i != axis)
     shape = [1] * x.ndim
@@ -370,8 +308,7 @@ def batch_norm(x, gamma, beta, moving_mean, moving_var, eps=1e-5,
     # f32 gamma/beta/running stats); output back in x's dtype
     xf = x.astype(jnp.float32) if x.dtype != jnp.float32 else x
     if training and not use_global_stats:
-        sd = _tuned_bn_stat_dtype(x, axis, stat_dtype)
-        if sd == "bfloat16":
+        if stat_dtype == "bfloat16":
             xs = xf.astype(jnp.bfloat16)
             m = jnp.mean(xs, axis=reduce_axes).astype(jnp.float32)
             v = jnp.var(xs, axis=reduce_axes).astype(jnp.float32)

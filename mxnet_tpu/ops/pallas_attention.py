@@ -258,72 +258,11 @@ def _cut_valid(qi, j, block_q, block_k, causal, mask, seq_q=None,
     return valid
 
 
-# registered hand-set defaults — the mx.autotune sites' reference
-# configs.  MXNET_AUTOTUNE=0 resolves to exactly these literals, so
-# the untuned stack is bit-and-perf identical to the pre-autotune one.
+# The block sizes a call uses when it names none: one pair for every
+# shape and mask of the flash kernels, one K block for the scan.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 DEFAULT_BLOCKWISE_K = 256
-
-
-def _tuned_flash_blocks(q, k, causal, block_q, block_k, dropout_p=0.0):
-    """Resolve (block_q, block_k): explicit caller values win, else
-    the mx.autotune ``flash_attention`` winner for this workload key,
-    else the hand-set defaults.  A malformed stored config degrades to
-    the defaults with a counted fallback — never an error.
-
-    Dropout pins the defaults: the in-kernel keep mask is seeded per
-    (q-block, k-block) TILE, so different block sizes draw different
-    masks — a tuned winner measured bit-identical on the dropout-free
-    path would still change dropout numerics.  Only explicit block
-    arguments override blocks under dropout."""
-    if block_q is not None and block_k is not None:
-        return int(block_q), int(block_k)
-    from .. import autotune as _at
-
-    bq, bk = DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
-    if dropout_p > 0.0:
-        return (int(block_q) if block_q is not None else bq,
-                int(block_k) if block_k is not None else bk)
-    if _at.is_enabled():
-        B, H, Tq, D = q.shape
-        cfg = _at.lookup(
-            "flash_attention",
-            (B, H, Tq, k.shape[2], D, str(q.dtype), bool(causal)),
-            (bq, bk))
-        try:
-            bq, bk = int(cfg[0]), int(cfg[1])
-        except (TypeError, ValueError, IndexError):
-            _at.fallback("invalid_config")
-            bq, bk = DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
-    return (int(block_q) if block_q is not None else bq,
-            int(block_k) if block_k is not None else bk)
-
-
-def _tuned_blockwise_k(q, k, causal, block_k, dropout_p=0.0):
-    """``block_k`` for ``blockwise_attention``: explicit value, tuned
-    winner, or today's literal 256.  Dropout pins the default — the
-    per-block threefry mask is folded by k-block index, so a different
-    block_k draws different masks (same contract as the flash
-    kernel)."""
-    if block_k is not None:
-        return int(block_k)
-    from .. import autotune as _at
-
-    bk = DEFAULT_BLOCKWISE_K
-    if dropout_p > 0.0:
-        return bk
-    if _at.is_enabled():
-        B, H, Tq, D = q.shape
-        cfg = _at.lookup(
-            "blockwise_attention",
-            (B, H, Tq, k.shape[2], D, str(q.dtype), bool(causal)), bk)
-        try:
-            bk = int(cfg)
-        except (TypeError, ValueError):
-            _at.fallback("invalid_config")
-            bk = DEFAULT_BLOCKWISE_K
-    return bk
 
 
 # ---------------------------------------------------------------------------
@@ -342,12 +281,10 @@ def blockwise_attention(q, k, v, causal=False, sm_scale=None, block_k=None,
     online.  Deterministic per ``dropout_key``, so the vjp recomputation
     sees the same mask.
 
-    ``block_k=None`` (default) resolves through the mx.autotune
-    ``blockwise_attention`` site: the hand-set literal 256 when
-    autotune is off or cold (and always under dropout — the per-block
-    mask partition must not move with a tuned block size)."""
-    block_k = _tuned_blockwise_k(q, k, causal, block_k,
-                                 dropout_p=float(dropout_p))
+    ``block_k=None`` (default) is ``DEFAULT_BLOCKWISE_K`` (256).  Under
+    dropout the per-block mask is folded by k-block index, so another
+    ``block_k`` draws another mask."""
+    block_k = DEFAULT_BLOCKWISE_K if block_k is None else int(block_k)
     if dropout_p > 0.0 and dropout_key is None:
         raise ValueError(
             "blockwise_attention: dropout_p > 0 requires dropout_key "
@@ -1015,9 +952,9 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
     product takes its operands at the dtype of ``q``/``k``/``v`` and
     accumulates in float32; the softmax state is float32 whatever comes in.
 
-    ``block_q``/``block_k`` default to the mx.autotune
-    ``flash_attention`` winner for this workload (the hand-set 512/512
-    literals when autotune is off or cold); explicit values always win.
+    ``block_q``/``block_k`` default to ``DEFAULT_BLOCK_Q`` /
+    ``DEFAULT_BLOCK_K`` (512 / 512) for every shape and mask; an explicit
+    value wins, each on its own.
 
     Forward AND backward run Pallas kernels (interpreted on the CPU
     backend): the backward recomputes per-block probabilities from the
@@ -1035,8 +972,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
     kernels' fast memory (``flash_vmem_bytes``) raises ``MXNetError``
     when compiled for the chip; ``multi_head_attention(impl="auto")``
     never picks such a shape."""
-    block_q, block_k = _tuned_flash_blocks(q, k, causal, block_q, block_k,
-                                           dropout_p=float(dropout_p))
+    block_q = DEFAULT_BLOCK_Q if block_q is None else int(block_q)
+    block_k = DEFAULT_BLOCK_K if block_k is None else int(block_k)
     cfg = _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
                    dropout_p, mask)
     _note_tiles(cfg, q, k)

@@ -70,17 +70,6 @@ def _tenant_enabled():
         return False
 
 
-def _autotune_enabled():
-    """mx.autotune self-tuning: built in, but OFF unless armed
-    (MXNET_AUTOTUNE=1|search or mxnet_tpu.autotune.enable())."""
-    try:
-        from . import autotune as _autotune
-
-        return _autotune.is_enabled()
-    except Exception:
-        return False
-
-
 def _step_capture_enabled():
     """mx.step whole-program training-step capture: ON by default,
     killed by MXNET_STEP_CAPTURE=0 (re-read per access — the kill
@@ -148,7 +137,6 @@ def _detect():
     out["MONITOR"] = _DynamicFeature("MONITOR", _monitor_enabled)
     out["STEP_CAPTURE"] = _DynamicFeature("STEP_CAPTURE",
                                           _step_capture_enabled)
-    out["AUTOTUNE"] = _DynamicFeature("AUTOTUNE", _autotune_enabled)
     out["OBS"] = _DynamicFeature("OBS", _obs_enabled)
     out["SERVE_CACHE"] = _DynamicFeature("SERVE_CACHE",
                                          _serve_cache_enabled)

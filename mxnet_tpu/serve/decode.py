@@ -127,8 +127,8 @@ class DecodeConfig:
         ``MXNET_SERVE_PREFIX_CACHE``, default OFF — opt-in so the
         warm-up program table is unchanged for existing deployments).
     spec_k : speculative draft proposal count when a draft model is
-        given (``MXNET_SERVE_SPEC_K``; 0 = resolve via the ``spec_k``
-        autotune site / the built-in default).
+        given (``MXNET_SERVE_SPEC_K``; 0 = the built-in default, see
+        ``serve.spec.resolve_k``).
     """
 
     def __init__(self, page_size=None, pool_pages=None, max_live=None,
@@ -158,8 +158,7 @@ class DecodeConfig:
             raise ValueError("no prefill bucket <= max_context=%d"
                              % self.max_context)
         if batch_sizes is None:
-            default_set = _pow2_up_to(1, max(1, self.max_live))
-            batch_sizes = self._tuned_batch_sizes(default_set)
+            batch_sizes = _pow2_up_to(1, max(1, self.max_live))
         self.batch_sizes = tuple(sorted(set(int(b) for b in batch_sizes)))
         if self.batch_sizes[-1] < self.max_live:
             raise ValueError(
@@ -175,31 +174,6 @@ class DecodeConfig:
             if prefix_cache is None else bool(prefix_cache)
         self.spec_k = get_env("MXNET_SERVE_SPEC_K", int, 0) \
             if spec_k is None else int(spec_k)
-
-    def _tuned_batch_sizes(self, default_set):
-        """The mx.autotune ``decode_bucket`` winner for this
-        ``max_live`` (committed by the decode runner's idle tuner in a
-        previous process), validated — every tuned set must still
-        cover ``max_live`` — else the power-of-two default.  Decode
-        outputs are bucket-table-invariant by the padding design, so a
-        tuned table changes compile count and step latency, never
-        tokens."""
-        from .. import autotune as _at
-
-        if not _at.is_enabled():
-            return default_set
-        cfg, prov = _at.lookup_info("decode_bucket", (self.max_live,),
-                                    list(default_set))
-        if prov != "tuned":
-            return default_set
-        try:
-            buckets = sorted(set(int(b) for b in cfg))
-        except (TypeError, ValueError):
-            buckets = []
-        if not buckets or buckets[0] < 1 or buckets[-1] < self.max_live:
-            _at.fallback("invalid_config")
-            return default_set
-        return buckets
 
     def as_dict(self):
         return {
@@ -769,19 +743,6 @@ class DecodeRunner:
                 self._dispatch(prog, self._null_inputs(
                     batch, chunk, floors=(kind == "chunk")))
         self._warmed = True
-        # mx.autotune idle-time tuning (MXNET_AUTOTUNE=search): every
-        # decode bucket program is warm and idempotent against null
-        # inputs (drop-mode page tables leave the pool untouched), so
-        # measure each one and commit the cheapest candidate bucket
-        # SET — the next process's DecodeConfig looks it up at build
-        # time.  Budget-bounded; failures degrade to the untuned table
-        from .. import autotune as _autotune
-
-        if _autotune.search_enabled():
-            try:
-                _autotune.measure.decode_idle_tune(self)
-            except Exception:
-                _autotune.fallback("serve_idle")
         spec = getattr(self, "spec", None)
         if spec is not None and not spec.warmed:
             fresh += spec.warm_up()

@@ -244,20 +244,6 @@ class ModelRunner:
                 else:
                     self._warm_provenance[label] = "warm"
         self._warmed = True
-        # mx.autotune idle-time tuning (MXNET_AUTOTUNE=search): the
-        # bucket table is compiled and no traffic has arrived — measure
-        # each bucket's execute latency into the TuningStore
-        # (serve_bucket records: cost-model features + diagnose
-        # provenance).  Bounded by MXNET_AUTOTUNE_BUDGET_MS; ANY
-        # failure degrades silently — warm-up readiness never depends
-        # on tuning
-        from .. import autotune as _autotune
-
-        if _autotune.search_enabled():
-            try:
-                _autotune.measure.serve_idle_tune(self)
-            except Exception:
-                _autotune.fallback("serve_idle")
         return built
 
     def _bucket_centry(self, b, sig):

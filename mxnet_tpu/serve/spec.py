@@ -35,10 +35,9 @@ Mechanics:
   ``("draft", bucket)`` class; batch-mates keep speculating), and a
   lost draft pool bumps the plane epoch so stale sequences detach
   lazily.  Only a TARGET pool loss propagates to the scheduler.
-- **K is a structural autotune site** (``spec_k``): like
-  ``decode_bucket`` it can never change tokens — only the
-  acceptance-rate x K economics — so the idle tuner may commit a
-  winner without a parity certificate beyond the structural proof.
+- **K is structural**: like the decode bucket table it can never
+  change tokens — only the acceptance-rate x K economics
+  (``resolve_k``: explicit > ``MXNET_SERVE_SPEC_K`` > 4).
 """
 from __future__ import annotations
 
@@ -55,27 +54,13 @@ _K_DEFAULT = 4
 _K_MAX = 16
 
 
-def resolve_k(k, max_live):
+def resolve_k(k=None):
     """The per-round proposal count: explicit argument >
-    ``MXNET_SERVE_SPEC_K`` > the committed ``spec_k`` autotune winner
-    for this ``max_live`` > 4.  Clamped to [1, 16]."""
+    ``MXNET_SERVE_SPEC_K`` > 4.  Clamped to [1, 16]."""
     if k is None:
-        env = get_env("MXNET_SERVE_SPEC_K", int, 0)
-        if env > 0:
-            k = env
-    if k is None:
-        from .. import autotune as _at
-
-        if _at.is_enabled():
-            cfg, prov = _at.lookup_info("spec_k", (int(max_live),),
-                                        _K_DEFAULT)
-            if prov == "tuned":
-                try:
-                    k = int(cfg)
-                except (TypeError, ValueError):
-                    _at.fallback("invalid_config")
-    if k is None:
-        k = _K_DEFAULT
+        k = get_env("MXNET_SERVE_SPEC_K", int, 0)
+        if k <= 0:
+            k = _K_DEFAULT
     return max(1, min(_K_MAX, int(k)))
 
 
@@ -91,7 +76,7 @@ class SpecPlane:
 
         cfg = target.config
         self.target = target
-        self.k = resolve_k(k, cfg.max_live)
+        self.k = resolve_k(k)
         draft_cfg = DecodeConfig(
             page_size=cfg.page_size, pool_pages=cfg.pool_pages,
             max_live=cfg.max_live, max_new_tokens=cfg.max_new_tokens,

@@ -63,9 +63,9 @@ from .. import obs as _obs
 from .. import telemetry as _tel
 from .. import trace as _trace
 from ..base import MXNetError, get_env
-from ..kvstore.collective import (observe_bucket_fill,
-                                  observe_collective, plan_buckets,
-                                  tuned_bucket_bytes)
+from ..kvstore.collective import (default_bucket_bytes,
+                                  observe_bucket_fill,
+                                  observe_collective, plan_buckets)
 from ..ndarray.ndarray import NDArray
 from ..optimizer import multi_tensor as _mt
 from ..resilience import inject as _inject
@@ -237,7 +237,7 @@ class _Captured:
 
     __slots__ = ("sig", "train_idx", "train_names", "other_names",
                  "group_list", "labels", "pos_of", "bucket_plan",
-                 "bucket_bytes", "bucket_prov",
+                 "bucket_bytes",
                  "bucket_nbytes", "n_slots", "slot_fns", "jfn", "cfn",
                  "cfn_ok", "fingerprint", "provenance", "gate",
                  "monitor", "remat", "segments", "donation",
@@ -247,7 +247,6 @@ class _Captured:
 
     def __init__(self):
         self.bucket_bytes = 0
-        self.bucket_prov = "default"
         self.slot_fns = None
         self.jfn = None
         self.cfn = None
@@ -567,7 +566,6 @@ class StepProgram:
                 "donation": dict(cap.donation),
                 "bucket_plan": [list(b) for b in cap.bucket_plan],
                 "bucket_bytes": int(cap.bucket_bytes),
-                "bucket_bytes_provenance": cap.bucket_prov,
             } for cap in self._programs.values()],
             "fallbacks": list(self._fallbacks),
         }
@@ -777,11 +775,9 @@ class StepProgram:
         grad_arrs = [g._data for _, _, g in items]
         grad_sizes = [(a.size * a.dtype.itemsize, str(a.dtype))
                       for a in grad_arrs]
-        # mx.autotune: the plan's bucket size may be a tuned winner —
-        # recorded (with provenance) in report() and threaded through
-        # every fill observation this program feeds
-        cap.bucket_bytes, cap.bucket_prov = tuned_bucket_bytes(
-            grad_sizes, world=self._world)
+        # the plan's bucket size: recorded in report() and threaded
+        # through every fill observation this program feeds
+        cap.bucket_bytes = default_bucket_bytes()
         cap.bucket_plan = plan_buckets(
             grad_sizes, bucket_bytes=cap.bucket_bytes)
         cap.bucket_nbytes = [

@@ -1088,42 +1088,6 @@ DIST_POD_COMMITS = counter(
 DIST_LEAVES = counter(
     "dist_member_leaves_total",
     "clean membership departures by reason", ("reason",))
-# mx.autotune (autotune/): self-tuning kernels, buckets, and flags —
-# measured micro-bench search with a bitwise numerics guard, winners
-# persisted in the env-fingerprinted TuningStore next to the compile
-# cache.  Every degrade-to-default path is counted so a tuned fleet
-# that silently fell back to hand-set literals is visible.
-AUTOTUNE_LOOKUPS = counter(
-    "autotune_lookup_total",
-    "build-time tuned-config lookups by site and result (tuned = a "
-    "stored winner was served; default = hand-set literal)",
-    ("site", "result"))
-AUTOTUNE_MEASURE = counter(
-    "autotune_measure_total",
-    "candidate configs measured by the search harness / idle tuners "
-    "(a warm store means a fresh process re-measures NOTHING)",
-    ("site",))
-AUTOTUNE_REJECT = counter(
-    "autotune_reject_total",
-    "candidates rejected by the measure guards (numerics = output "
-    "not bit-identical to the default config's; shape; nonfinite; "
-    "error)", ("site", "reason"))
-AUTOTUNE_FALLBACK = counter(
-    "autotune_fallback_total",
-    "degrades to the hand-set default by reason (store_unavailable / "
-    "store_corrupt / store_error / store_write / invalid_config / "
-    "measure_error / serve_idle / ...)", ("reason",))
-AUTOTUNE_STORE_COMMITS = counter(
-    "autotune_store_commits_total",
-    "tuning records durably committed to the TuningStore")
-AUTOTUNE_STORE_QUARANTINE = counter(
-    "autotune_store_quarantine_total",
-    "corrupt/torn tuning records parked at *.corrupt (never trusted "
-    "again; lookups degraded to defaults)")
-AUTOTUNE_TUNE_SECONDS = histogram(
-    "autotune_tune_seconds",
-    "wall time of one tune() search (default + all candidates)",
-    buckets=(0.01, 0.05, 0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0))
 # mx.data (data/): sharded streaming input pipeline.  The ring gauges
 # are the H3 health signal: steady state is occupancy ~ depth and a
 # flat stall counter — a climbing stall count means reads/decode (not
@@ -1133,7 +1097,7 @@ AUTOTUNE_TUNE_SECONDS = histogram(
 DATA_RING_DEPTH = gauge(
     "data_ring_depth",
     "configured prefetch ring depth (batches staged ahead; "
-    "MXNET_DATA_PREFETCH or the data_prefetch autotune site)")
+    "MXNET_DATA_PREFETCH)")
 DATA_RING_OCCUPANCY = gauge(
     "data_ring_occupancy",
     "device-staged batches currently waiting in the prefetch ring")

@@ -31,8 +31,7 @@ class TenantConfig:
     """Knobs of the multi-tenant plane (README "Multi-tenant
     serving").
 
-    slots : adapter bank capacity (``MXNET_TENANT_SLOTS``); resolved
-        through the ``adapter_slots`` autotune site when enabled.
+    slots : adapter bank capacity (``MXNET_TENANT_SLOTS``).
     max_rank : bank-wide LoRA rank ceiling (``MXNET_TENANT_MAX_RANK``)
         — lower-rank adapters zero-pad, higher-rank ones are rejected.
     default_weight : WFQ weight for tenants that don't set one
@@ -46,9 +45,8 @@ class TenantConfig:
     def __init__(self, slots=None, max_rank=None, default_weight=None,
                  max_live=None, max_pages=None, queue_depth=None,
                  targets=None):
-        env_slots = get_env("MXNET_TENANT_SLOTS", int, 8) \
+        self.slots = get_env("MXNET_TENANT_SLOTS", int, 8) \
             if slots is None else int(slots)
-        self.slots = self._tuned_slots(env_slots, slots is not None)
         self.max_rank = get_env("MXNET_TENANT_MAX_RANK", int, 8) \
             if max_rank is None else int(max_rank)
         self.default_weight = get_env(
@@ -63,30 +61,6 @@ class TenantConfig:
         self.targets = list(targets) if targets is not None else None
         if self.slots < 1:
             raise ValueError("TenantConfig needs slots >= 1")
-
-    @staticmethod
-    def _tuned_slots(default, explicit):
-        """The ``adapter_slots`` autotune site winner (committed by a
-        bench sweep in a previous process), validated >= 1 — an
-        explicit ``slots=`` always wins."""
-        if explicit:
-            return int(default)
-        from .. import autotune as _at
-
-        if not _at.is_enabled():
-            return int(default)
-        cfg, prov = _at.lookup_info("adapter_slots", (int(default),),
-                                    int(default))
-        if prov != "tuned":
-            return int(default)
-        try:
-            slots = int(cfg)
-        except (TypeError, ValueError):
-            slots = 0
-        if slots < 1:
-            _at.fallback("invalid_config")
-            return int(default)
-        return slots
 
     def default_quota(self):
         return TenantQuota(self.max_live, self.max_pages,

@@ -4,8 +4,8 @@ Covers: deterministic shard assignment + epoch order, the prefetch
 ring's occupancy/stall accounting, bit-identical mid-epoch cursor
 resume (standalone and through Trainer checkpoints), the data_read
 fault site, preemption drain (StreamLoader AND the gluon DataLoader
-worker processes), the unsharded-iterator guard, the data_prefetch
-autotune site, mesh-sharded staging consumed by the captured step,
+worker processes), the unsharded-iterator guard, ring depth and workers
+from the environment, mesh-sharded staging consumed by the captured step,
 and the data_* telemetry families.
 """
 from __future__ import annotations
@@ -513,45 +513,28 @@ def test_mesh_staged_batches_feed_captured_step(shard_dir):
 
 
 # ---------------------------------------------------------------------------
-# autotune site + guards
+# ring depth and workers: explicit, else the environment; guards
 # ---------------------------------------------------------------------------
 
-def test_data_prefetch_site_registered_defaults_match_env():
-    from mxnet_tpu import autotune
-
-    site = autotune.sites()["data_prefetch"]
-    assert site.parity == "structural"
-    cfg = site.default_config((32, 1024))
-    assert cfg == {"depth": mxdata.default_depth(),
-                   "workers": mxdata.default_workers()}
-    cands = site.candidates((32, 1024))
-    assert {"depth": 2, "workers": 2} in cands
-    assert site.validate((32, 1024), {"depth": 3, "workers": 2})
-    assert not site.validate((32, 1024), {"depth": 0, "workers": 2})
-    assert not site.validate((32, 1024), ["nope"])
-    with pytest.raises(MXNetError, match="structural"):
-        site.make_bench((32, 1024), cfg)
-
-
-def test_stream_loader_consumes_tuned_prefetch(shard_dir, monkeypatch):
-    from mxnet_tpu import autotune
-
+@pytest.mark.parametrize("env,kw,want", [
+    ({}, {}, (2, 2)),                                   # the defaults
+    ({"MXNET_DATA_PREFETCH": "5", "MXNET_DATA_WORKERS": "3"}, {}, (5, 3)),
+    ({"MXNET_DATA_PREFETCH": "5", "MXNET_DATA_WORKERS": "3"},
+     {"prefetch": 2, "num_workers": 1}, (2, 1)),        # explicit wins
+    ({"MXNET_DATA_PREFETCH": "5", "MXNET_DATA_WORKERS": "3"},
+     {"prefetch": 4}, (4, 3)),                          # each on its own
+    ({"MXNET_DATA_PREFETCH": "0", "MXNET_DATA_WORKERS": "0"}, {}, (1, 1)),
+])
+def test_stream_loader_depth_and_workers(shard_dir, monkeypatch, env, kw,
+                                         want):
     pat = _write_shards(shard_dir)
-    calls = {}
-
-    def fake_lookup(site, key, default=None):
-        calls["site"] = site
-        return {"depth": 5, "workers": 3}
-
-    monkeypatch.setattr(autotune, "lookup", fake_lookup)
-    ldr = mxdata.StreamLoader(pat, batch_size=6, seed=0)
-    assert calls["site"] == "data_prefetch"
-    assert ldr.prefetch == 5 and ldr.num_workers == 3
-    # explicit args always win over the tuned record
-    exp = mxdata.StreamLoader(pat, batch_size=6, seed=0,
-                              num_workers=1, prefetch=2)
-    assert exp.prefetch == 2 and exp.num_workers == 1
-    ldr.close(), exp.close()
+    for name in ("MXNET_DATA_PREFETCH", "MXNET_DATA_WORKERS"):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in env.items():
+        monkeypatch.setenv(name, value)
+    ldr = mxdata.StreamLoader(pat, batch_size=6, seed=0, **kw)
+    assert (ldr.prefetch, ldr.num_workers) == want
+    ldr.close()
 
 
 def test_unsharded_iterators_guarded(shard_dir, monkeypatch):

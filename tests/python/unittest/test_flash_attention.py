@@ -418,3 +418,66 @@ def test_bf16_kernels_agree_with_the_dense_bf16_path(kw, kv_heads):
         step = 2.0 ** -8 * np.abs(b).max()
         assert np.abs(a - b).max() <= 2 * step, (name, np.abs(a - b).max(),
                                                  step)
+
+
+def _blocks_seen(monkeypatch):
+    """Record the (block_q, block_k) each flash call resolves to."""
+    seen = []
+    real = pa._cfg_for
+
+    def spy(q, k, causal, sm_scale, block_q, block_k, *a, **kw):
+        seen.append((block_q, block_k))
+        return real(q, k, causal, sm_scale, block_q, block_k, *a, **kw)
+
+    monkeypatch.setattr(pa, "_cfg_for", spy)
+    return seen
+
+
+_DEFAULT_BLOCK_CALLS = {    # (call, T, keywords)
+    "flash": (pa.flash_attention, 1024, {}),
+    "flash_causal": (pa.flash_attention, 1024, {"causal": True}),
+    "flash_block_diffusion": (pa.flash_attention, 1024,
+                              {"mask": pa.block_diffusion_mask(512, 4)}),
+    "lse": (pa.flash_attention_lse, 1024, {}),
+    "lse_causal": (pa.flash_attention_lse, 1024, {"causal": True}),
+    "blockwise": (pa.blockwise_attention, 512, {}),
+    "blockwise_causal": (pa.blockwise_attention, 512, {"causal": True}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DEFAULT_BLOCK_CALLS))
+def test_blocks_left_out_are_the_module_constants(case):
+    """A call that names no block runs the call that names 512 / 512 (the
+    flash kernels) or 256 (the scan), bit for bit, at a length that holds
+    more than one such block."""
+    call, T, kw = _DEFAULT_BLOCK_CALLS[case]
+    assert (pa.DEFAULT_BLOCK_Q, pa.DEFAULT_BLOCK_K,
+            pa.DEFAULT_BLOCKWISE_K) == (512, 512, 256)
+    blocks = {"block_k": 256} if call is pa.blockwise_attention else \
+        {"block_q": 512, "block_k": 512}
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q, k, v = (jax.random.normal(key, (1, 1, T, 16)) for key in ks)
+    got = jax.tree_util.tree_leaves(call(q, k, v, **kw))
+    want = jax.tree_util.tree_leaves(call(q, k, v, **kw, **blocks))
+    other = jax.tree_util.tree_leaves(call(
+        q, k, v, **kw, **{name: 128 for name in blocks}))
+    for a, b, c in zip(got, want, other):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        # another block sums in another order: the comparison can fail
+        assert np.asarray(a).tobytes() != np.asarray(c).tobytes()
+
+
+@pytest.mark.parametrize("kw,want", [
+    ({}, (512, 512)),
+    ({"block_q": 64}, (64, 512)),
+    ({"block_k": 32}, (512, 32)),
+    ({"block_q": 16, "block_k": 32}, (16, 32)),
+    ({"block_q": 64, "dropout_p": 0.1}, (64, 512)),
+])
+def test_an_explicit_block_wins_each_on_its_own(kw, want, monkeypatch):
+    seen = _blocks_seen(monkeypatch)
+    q, k, v = _rand(64)
+    if kw.get("dropout_p"):
+        kw = dict(kw, dropout_key=jax.random.PRNGKey(1))
+    pa.flash_attention(q, k, v, **kw)
+    assert seen == [want]

@@ -10,6 +10,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 
@@ -43,6 +45,39 @@ def test_import_succeeds_without_any_platform():
         env_extra={"JAX_PLATFORMS": "no_such_platform"})
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "OK" in proc.stdout
+
+
+def _sources_holding(word):
+    package = os.path.join(REPO, "mxnet_tpu")
+    found, seen = [], 0
+    for folder, _, files in os.walk(package):
+        if word in folder.lower():
+            found.append(folder)
+        for name in files:
+            if name.endswith((".py", ".cc", ".h", ".md")):
+                seen += 1
+                with open(os.path.join(folder, name), errors="replace") as f:
+                    if word in f.read().lower():
+                        found.append(os.path.join(folder, name))
+    assert seen > 100
+    return found
+
+
+@pytest.mark.parametrize("where", ["attribute", "feature", "variable",
+                                   "instrument", "source"])
+def test_no_tuning_subsystem_is_left(where):
+    # Block sizes, bucket bytes, bucket tables, K, ring depth and slots are
+    # constants or environment defaults in the one module that uses each.
+    import mxnet_tpu as mx
+    from mxnet_tpu import config, runtime, telemetry
+
+    word = "autotune"
+    names = {"attribute": lambda: dir(mx),
+             "feature": lambda: runtime.Features(),
+             "variable": lambda: config.ENV_VARS,
+             "instrument": lambda: telemetry._REGISTRY,
+             "source": lambda: _sources_holding(word)}[where]()
+    assert [n for n in names if word in n.lower()] == []
 
 
 def test_bench_fails_without_a_tpu():

@@ -75,6 +75,21 @@ def test_convolution_forward():
     assert_almost_equal(out.asnumpy(), ref, rtol=1e-3, atol=1e-4)
 
 
+@pytest.mark.parametrize("layout,spatial", [("NCW", (8,)), ("NCHW", (8, 8)),
+                                            ("NCDHW", (4, 4, 4))])
+def test_convolution_default_layout_is_the_named_one(layout, spatial):
+    """``convolution(x, w)`` is, bit for bit, the call that names the
+    channel-first layout of its rank: one path, no other behind it."""
+    rs = np.random.RandomState(1)
+    x = nd.array(rs.rand(2, 3, *spatial).astype(np.float32))
+    w = nd.array(rs.rand(4, 3, *(3,) * len(spatial)).astype(np.float32))
+    kw = dict(kernel=(3,) * len(spatial), num_filter=4, no_bias=True)
+    got = nd.convolution(x, w, **kw).asnumpy()
+    want = nd.convolution(x, w, layout=layout, **kw).asnumpy()
+    assert got.shape[:2] == (2, 4)
+    assert got.tobytes() == want.tobytes()
+
+
 def test_pooling():
     import torch
     import torch.nn.functional as F
@@ -109,6 +124,23 @@ def test_batch_norm():
         beta[None, :, None, None]
     assert_almost_equal(out.asnumpy(), ref, rtol=1e-3, atol=1e-4)
     assert_almost_equal(nm.asnumpy(), 0.9 * mean + 0.1 * bm, rtol=1e-4)
+
+
+@pytest.mark.parametrize("stat_dtype,same", [("float32", True),
+                                             ("bfloat16", False)])
+def test_batch_norm_stat_dtype_default_is_float32(stat_dtype, same):
+    """``stat_dtype=None`` reduces the batch statistics in float32, bit
+    for bit; "bfloat16" is the other value and moves them."""
+    rs = np.random.RandomState(2)
+    x = nd.array(rs.rand(4, 3, 5, 5).astype(np.float32))
+    args = [nd.array(rs.rand(3).astype(np.float32)) for _ in range(2)] + \
+        [nd.array(np.zeros(3, np.float32)), nd.array(np.ones(3, np.float32))]
+    got = nd.batch_norm(x, *args, training=True)
+    want = nd.batch_norm(x, *args, training=True, stat_dtype=stat_dtype)
+    equal = [a.asnumpy().tobytes() == b.asnumpy().tobytes()
+             for a, b in zip(got, want)]
+    assert equal == [same] * 3
+    assert all(np.isfinite(b.asnumpy()).all() for b in want)
 
 
 def test_layer_norm():

@@ -386,6 +386,26 @@ def test_spec_verify_drill_degrades_one_sequence_alone():
     spec.pool.check()
 
 
+@pytest.mark.parametrize("k,env,want", [
+    (None, None, 4),        # the built-in default
+    (None, "0", 4),         # 0 in the environment means "not set"
+    (None, "6", 6),         # the environment over the default
+    (3, "6", 3),            # an explicit K over the environment
+    (0, None, 1),           # clamped from below
+    (99, None, 16),         # clamped from above
+    (None, "40", 16),       # the environment's value is clamped too
+])
+def test_spec_k_explicit_then_environment_then_four(k, env, want,
+                                                    monkeypatch):
+    from mxnet_tpu.serve.spec import resolve_k
+
+    if env is None:
+        monkeypatch.delenv("MXNET_SERVE_SPEC_K", raising=False)
+    else:
+        monkeypatch.setenv("MXNET_SERVE_SPEC_K", env)
+    assert resolve_k(k) == want
+
+
 def test_spec_stats_surface_in_runner_stats():
     spec = serve.DecodeRunner(_decoder(seed=0), config=_config(),
                               draft=_decoder(seed=1))

@@ -125,6 +125,25 @@ def test_page_pool_double_free_and_unknown_owner_raise():
         pool.alloc("b", 2) and pool.alloc("b", 1)   # duplicate owner
 
 
+@pytest.mark.parametrize("max_live,want", [
+    (1, (1,)), (3, (1, 2, 3)), (8, (1, 2, 4, 8)), (12, (1, 2, 4, 8, 12)),
+    (32, (1, 2, 4, 8, 16, 32))])
+def test_default_batch_buckets_are_powers_of_two_closed_by_max_live(
+        max_live, want):
+    cfg = serve.DecodeConfig(max_live=max_live, max_context=16,
+                             prefill_lengths=(8,))
+    assert cfg.batch_sizes == want
+
+
+def test_explicit_batch_buckets_must_cover_max_live():
+    cfg = serve.DecodeConfig(max_live=4, max_context=16,
+                             prefill_lengths=(8,), batch_sizes=(4, 2, 4))
+    assert cfg.batch_sizes == (2, 4)       # taken as given, sorted
+    with pytest.raises(ValueError, match="max_live=8"):
+        serve.DecodeConfig(max_live=8, max_context=16,
+                           prefill_lengths=(8,), batch_sizes=(2, 4))
+
+
 def test_page_config_limits():
     cfg = PageConfig(4, 8, 2, 2, 4, 16)
     assert cfg.pages_per_seq == 4

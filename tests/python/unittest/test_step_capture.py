@@ -624,6 +624,33 @@ def test_capture_api_and_report():
     assert allreduce["buckets"] == len(program["bucket_plan"])
 
 
+def test_report_carries_the_bucket_size_the_plan_was_built_with(
+        monkeypatch):
+    """``bucket_bytes`` in the report is ``MXNET_KVSTORE_BUCKET_BYTES`` as
+    it stood at capture (the default when unset) and shapes the plan; the
+    report says nothing of where a size came from, since there is one
+    place."""
+    from mxnet_tpu.kvstore import collective
+
+    x, y = _data()
+    monkeypatch.delenv("MXNET_KVSTORE_BUCKET_BYTES", raising=False)
+    net, trainer = _make()
+    prog = trainer.capture(net, gluon.loss.L2Loss())
+    prog(x, y)
+    program = prog.report()["programs"][0]
+    assert program["bucket_bytes"] == collective.default_bucket_bytes() \
+        == 4 << 20
+    assert len(program["bucket_plan"]) == 1
+    assert "bucket_bytes_provenance" not in program
+    monkeypatch.setenv("MXNET_KVSTORE_BUCKET_BYTES", "64")
+    net, trainer = _make()
+    prog = trainer.capture(net, gluon.loss.L2Loss())
+    prog(x, y)
+    program = prog.report()["programs"][0]
+    assert program["bucket_bytes"] == 64
+    assert len(program["bucket_plan"]) > 1
+
+
 def test_non_hybrid_block_rejected():
     class Plain(gluon.Block):
         def forward(self, x):

@@ -183,6 +183,26 @@ def test_tenant_config_env(monkeypatch):
         TenantConfig(slots=0)
 
 
+@pytest.mark.parametrize("slots,env,want", [
+    (None, None, 8),        # the built-in default
+    (None, "4", 4),         # the environment over the default
+    (2, "4", 2),            # an explicit bank size over the environment
+    (0, None, ValueError),  # a bank needs a slot, wherever the 0 came from
+    (None, "0", ValueError),
+])
+def test_tenant_slots_explicit_then_environment_then_eight(
+        slots, env, want, monkeypatch):
+    if env is None:
+        monkeypatch.delenv("MXNET_TENANT_SLOTS", raising=False)
+    else:
+        monkeypatch.setenv("MXNET_TENANT_SLOTS", env)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="slots >= 1"):
+            TenantConfig(slots=slots)
+    else:
+        assert TenantConfig(slots=slots).slots == want
+
+
 def test_registry_register_get_unknown():
     plane = TenantPlane(TenantConfig(slots=2, max_rank=4))
     t = plane.register("acme", weight=2.0)

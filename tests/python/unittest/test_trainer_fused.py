@@ -297,6 +297,43 @@ def test_plan_buckets_dtype_splits_and_oversize():
     assert one == [[0, 1, 2]]
 
 
+def test_plan_buckets_reads_the_environment_at_each_call(monkeypatch):
+    """``MXNET_KVSTORE_BUCKET_BYTES`` set after import is the size the
+    next plan closes its buckets at: nothing caches the default."""
+    sizes = [(1 << 20, "float32")] * 8
+    monkeypatch.delenv("MXNET_KVSTORE_BUCKET_BYTES", raising=False)
+    assert collective.default_bucket_bytes() == 4 << 20
+    assert len(collective.plan_buckets(sizes)) == 2
+    monkeypatch.setenv("MXNET_KVSTORE_BUCKET_BYTES", str(2 << 20))
+    assert collective.default_bucket_bytes() == 2 << 20
+    assert len(collective.plan_buckets(sizes)) == 4
+    # an explicit size wins over the environment
+    assert len(collective.plan_buckets(sizes, bucket_bytes=8 << 20)) == 1
+
+
+def test_observe_bucket_fill_uses_plan_bucket_bytes():
+    """The fill histogram must normalize against the plan's ACTUAL
+    bucket size, not the env default."""
+    telemetry.reset()
+    # one 1 MiB bucket against a 1 MiB plan = fill 1.0 (not the 0.25
+    # that normalizing against the 4 MiB env default would report)
+    collective.observe_bucket_fill([1 << 20], bucket_bytes=1 << 20)
+    tot = telemetry.totals()
+    assert tot["allreduce_bucket_fill_count"] == 1
+    assert abs(tot["allreduce_bucket_fill_sum"] - 1.0) < 1e-9
+
+
+def test_observe_bucket_fill_env_not_cached(monkeypatch):
+    monkeypatch.setenv("MXNET_KVSTORE_BUCKET_BYTES", str(1 << 20))
+    assert collective.default_bucket_bytes() == 1 << 20
+    telemetry.reset()
+    collective.observe_bucket_fill([1 << 20])  # denom from env NOW
+    tot = telemetry.totals()
+    assert abs(tot["allreduce_bucket_fill_sum"] - 1.0) < 1e-9
+    monkeypatch.setenv("MXNET_KVSTORE_BUCKET_BYTES", str(4 << 20))
+    assert collective.default_bucket_bytes() == 4 << 20
+
+
 def test_pushpull_all_local_store_and_trainer_wiring():
     mx.random.seed(0)
     params = _params(_DENSE_SPEC)

@@ -768,12 +768,10 @@ def step_info():
         print("  donation   :")
         for name, d in prog["donation"].items():
             print("    %-20s %s" % (name, d))
-        print("  bucket plan: %d bucket(s) %s  bucket_bytes=%.1f MiB "
-              "(%s)"
+        print("  bucket plan: %d bucket(s) %s  bucket_bytes=%.1f MiB"
               % (len(prog["bucket_plan"]),
                  [len(b) for b in prog["bucket_plan"]],
-                 prog.get("bucket_bytes", 0) / 1048576.0,
-                 prog.get("bucket_bytes_provenance", "default")))
+                 prog.get("bucket_bytes", 0) / 1048576.0))
     if rep["fallbacks"]:
         print("fallbacks    :")
         for f in rep["fallbacks"]:
@@ -973,7 +971,7 @@ def data_info():
     from mxnet_tpu import telemetry
 
     print("ring depth   :", mxdata.default_depth(),
-          "(MXNET_DATA_PREFETCH / data_prefetch autotune site)")
+          "(MXNET_DATA_PREFETCH)")
     print("workers      :", mxdata.default_workers(),
           "(MXNET_DATA_WORKERS)")
     num_hosts, host = mxdata.world_coords()
@@ -1016,60 +1014,6 @@ def data_info():
             print("  %-32s p50=%.6f p95=%.6f p99=%.6f"
                   % (name, qs.get(0.5, 0.0), qs.get(0.95, 0.0),
                      qs.get(0.99, 0.0)))
-
-
-def autotune_info():
-    """Audit mx.autotune: mode, store location/health, and the
-    per-site winner table with provenance (tuned / default /
-    quarantined) plus this process's lookup/fallback telemetry."""
-    section("Autotune")
-    from mxnet_tpu import autotune, telemetry
-    from mxnet_tpu.base import get_env
-
-    print("mode         :", autotune.mode(),
-          "" if autotune.is_enabled() else
-          "(set MXNET_AUTOTUNE=1|search)")
-    print("dir          :", get_env("MXNET_AUTOTUNE_DIR", str, None)
-          or autotune.default_store_dir())
-    st = autotune.get_store() if autotune.is_enabled() else None
-    if st is None and not autotune.is_enabled():
-        # a read-only audit should work even with the feature off
-        try:
-            st = autotune.TuningStore()
-        except Exception:
-            st = None
-    stats = st.stats() if st is not None else {}
-    print("env fp       :", stats.get("env_fingerprint") or "(unavailable)")
-    rows = []
-    if st is not None:
-        for site_name, kh, rec in st.records():
-            rows.append((site_name, "tuned", rec.get("key"),
-                         rec.get("config"), rec.get("ms"),
-                         rec.get("default_ms")))
-    tuned_sites = {r[0] for r in rows}
-    for name, site in sorted(autotune.sites().items()):
-        if name not in tuned_sites:
-            rows.append((name, "default", None, None, None, None))
-    if st is not None:
-        for q in st.quarantined():
-            parts = q.split(os.sep)
-            rows.append((parts[-2] if len(parts) >= 2 else "?",
-                         "quarantined", None, None, None, None))
-    print("winners      : %d tuned record(s), %d site(s) registered"
-          % (len(tuned_sites), len(autotune.sites())))
-    print("  %-20s %-12s %-10s %-10s %s"
-          % ("site", "provenance", "ms", "default", "config / key"))
-    for site_name, prov, key, cfg, ms, dms in sorted(rows):
-        print("  %-20s %-12s %-10s %-10s %s"
-              % (site_name, prov,
-                 "%.3f" % ms if isinstance(ms, (int, float)) else "-",
-                 "%.3f" % dms if isinstance(dms, (int, float)) else "-",
-                 "%s @ %s" % (cfg, key) if cfg is not None else
-                 "(hand-set literal)"))
-    tot = {k: v for k, v in telemetry.totals(nonzero=True).items()
-           if k.startswith("autotune_")}
-    print("telemetry    : %s" % (tot or "(no autotune activity "
-                                 "this process)"))
 
 
 def compile_cache_info():
@@ -1318,10 +1262,6 @@ def main():
                     help="audit the imperative Trainer's multi-tensor "
                          "update engine: group table, programs/step, "
                          "collective bucket fill")
-    ap.add_argument("--autotune", action="store_true",
-                    help="audit mx.autotune: mode, TuningStore "
-                         "health, and the per-site winner table with "
-                         "provenance (tuned/default/quarantined)")
     ap.add_argument("--step", action="store_true",
                     help="audit mx.step whole-step capture: capture a "
                          "representative program and print segments, "
@@ -1390,13 +1330,11 @@ def main():
     # (each skips the environment dump, all honor --telemetry)
     if args.compile_cache or args.serve or args.checkpoints or \
             args.trainer or args.step or args.trace or args.monitor or \
-            args.resilience or args.autotune or args.data or \
+            args.resilience or args.data or \
             args.dist is not None or args.fleet or args.fleet_router \
             or args.cache or args.tenant or args.shard:
         if args.compile_cache:
             compile_cache_info()
-        if args.autotune:
-            autotune_info()
         if args.data:
             data_info()
         if args.resilience:
