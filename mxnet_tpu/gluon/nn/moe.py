@@ -20,10 +20,49 @@ the only bound that holds for every routing.  Rows past the last real one
 belong to no group: the grouped product skips them (XLA's TPU lowering
 walks the tiles the groups fill) and nothing reads them.
 
-Every movement of rows is a GATHER, forward and backward (the sort is a
-permutation, so the transpose of "gather rows into expert order" is "gather
-them back"), written as two ``custom_vjp`` functions: autodiff alone would
-emit scatter-adds, which a TPU runs row by row.
+**The buffer has the bound's rows; moving rows costs by the real ones.**
+``rows``, the count the router produced, is known only on the device, so a
+movement of rows into a buffer is a loop over fixed-size chunks of the
+sorted assignment list whose trip count is ``ceil(rows / chunk)``
+(``_rows_of``; the chunk is a rule of the static shape, ``chunk_rows``): a
+turn gathers one chunk of rows into its place.  The chunks past the last
+real row are never written, so they hold whatever the memory held.  What
+reads a buffer is a grouped product, which walks the real rows only, or
+selects by ``real``: nothing MULTIPLIES an unwritten row by zero to be rid
+of it (0 x NaN is NaN), so the expert biases and the weights past the last
+real row come and go through ``jnp.where``.  Nothing else passes over the
+unbiased layer's ``R x d`` (an elementwise pass costs twice what gathering
+all of it does): nothing selects, scales or converts there.  Rows go into
+expert order by such a loop (``_to_rows``).  Its transpose, adding buffer
+rows into their positions (``_to_positions``), moves the real rows once
+more, into POSITION order, where the rows of a tile of 128 positions are
+one stretch, and sums them by a grouped product
+(``jax.lax.ragged_dot_general``, the ragged dimension contracted) with each
+row's one-hot place in its tile, accumulated in float32: no
+``(positions, top_k, d)`` float32 intermediate is gathered and summed over
+the slots.  Each is the other's backward, as two ``custom_vjp`` functions
+whose rules nobody differentiates again.  No scatter of rows and no gather
+or scatter of single elements anywhere: a TPU runs those one by one.
+
+**Which movement loops is a rule of the static shape** (``loops``): a turn
+of the loop moves a row at a seventh of a plain gather's rate, so the loop
+is taken where the share of the buffer a balanced router fills,
+``top_k x count / num_experts`` of a position's ``min(top_k, count)`` rows,
+is under the measured crossover of that movement, and one plain gather of
+the whole buffer (the form before PR 31) elsewhere.  A layer that holds all
+its experts has ``rows == R`` whatever the routing and never loops.
+
+**Rounding.**  The routing weight multiplies the experts' HIDDEN rows, in
+float32 inside the fusion that makes them (the second product is linear),
+and the product is rounded to the rows' dtype before the second grouped
+product; before PR 31 the weight multiplied that product's results in
+float32 on their way into the float32 sum over a position's slots.  In
+bf16 that is one rounding more on the way to the output, and the weights'
+gradient is a reduction over ``hidden`` rounded ``dh`` instead of
+``<y, dout>`` in float32 (bounded against the older form by
+tests/python/unittest/test_moe_dropless.py at the widths of the benchmark's
+cell).  The weights reach the buffer's order and their gradients leave it
+as the payload of a sort.
 
 ``parallel.moe_apply`` (capacity-limited ``all_to_all`` dispatch over an
 ``ep`` mesh axis) takes the ungated, biased form of this block with all
@@ -47,92 +86,217 @@ _ACTIVATIONS = {"relu": jax.nn.relu, "gelu": jax.nn.gelu,
                 "silu": jax.nn.silu}
 
 
+# positions a tile of `_sum_tiles`' grouped product covers: the MXU's width
+_TILE = 128
+
+
 def route(x, gate, top_k, first, count, norm_topk=True):
     """Route ``x`` (N, d) over all the router's experts; lay out the
     assignments that picked a held expert in expert order.
 
-    Returns a dict: ``weights`` (N, k) float32 (renormalised over the top-k
-    when ``norm_topk``), ``held`` (N, k) bool, ``pos`` (N, k) the row of each
-    assignment in the sorted buffer (meaningful where ``held``),
-    ``row_token`` / ``row_slot`` (R,) the position and top-k slot a row came
-    from, ``group_sizes`` (count,) rows per held expert, ``rows`` their sum;
-    ``R = N * min(k, count)``."""
-    n, k = x.shape[0], top_k
+    Returns ``layout``'s dict and ``weights`` (N, k) float32 (renormalised
+    over the top-k when ``norm_topk``)."""
     logits = jnp.einsum("td,ed->te", x, gate,
                         preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)
+    top_p, top_e = jax.lax.top_k(probs, top_k)
     weights = top_p / jnp.sum(top_p, -1, keepdims=True) if norm_topk \
         else top_p
+    return dict(layout(top_e, weights, first, count), weights=weights)
+
+
+@jax.custom_vjp
+def _by_expert(key, weights):
+    """``key`` (N * k,) sorted (stable), each entry's index and weight
+    riding the sort: (sorted key, order, weights in that order)."""
+    return jax.lax.sort(
+        (key, jnp.arange(key.shape[0], dtype=jnp.int32), weights),
+        num_keys=1, is_stable=True)
+
+
+def _by_expert_fwd(key, weights):
+    out = _by_expert.fun(key, weights)
+    return out, out[1]
+
+
+def _by_expert_bwd(order, cts):
+    # `order` is a permutation: sorting by it undoes the expert order
+    with jax.named_scope("mx.moe.route"):
+        return None, jax.lax.sort((order, cts[2]), num_keys=1)[1]
+
+
+_by_expert.defvjp(_by_expert_fwd, _by_expert_bwd)
+
+
+def layout(top_e, weights, first, count):
+    """The sorted buffer of the assignments ``top_e`` (N, k), a position's
+    distinct experts, with routing weights ``weights`` (N, k): ``held``
+    (N, k) bool; ``order`` (N * k,) the assignments (``position * k +
+    slot``) in expert order, those of absent experts last; ``row_token`` /
+    ``row_slot`` (R,) the position and top-k slot a row of the buffer came
+    from, ``real`` (R,) whether an assignment fills the row and ``row_w``
+    (R,) float32 its weight (0 where none does);
+    ``group_sizes`` (count,) rows per held expert, ``rows`` their sum;
+    ``R = N * min(k, count)``.  The real rows again in POSITION order (a
+    position's rows adjacent): ``tm_row`` (R,) the buffer row (row 0 past
+    the last real one: whatever is gathered by it is a real row) and
+    ``tm_flat`` (R,) its ``position * k + slot`` (``N * k`` past it).
+    What is reordered rides the two sorts as a payload: no gather or
+    scatter of single elements, forward or backward."""
+    n, k = top_e.shape
     local = top_e - first
     held = (local >= 0) & (local < count)
     # absent experts sort last, under the sentinel `count`
-    key = jnp.where(held, local, count).astype(jnp.int32).reshape(-1)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    key, order, w = _by_expert(
+        jnp.where(held, local, count).astype(jnp.int32).reshape(-1),
+        weights.astype(jnp.float32).reshape(-1))
     r = n * min(k, count)
-    rank = jnp.zeros((n * k,), jnp.int32).at[order].set(
-        jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
     group_sizes = jnp.sum(
         key[:, None] == jnp.arange(count, dtype=jnp.int32)[None, :],
         axis=0, dtype=jnp.int32)
     rows = jnp.sum(group_sizes)
-    order = order[:r]
-    return {"weights": weights, "held": held, "pos": rank.reshape(n, k),
-            "row_token": order // k, "row_slot": order % k,
-            "row_expert": jnp.minimum(key[order], count - 1),
-            "group_sizes": group_sizes, "rows": rows}
+    at = jnp.arange(r, dtype=jnp.int32)
+    real = at < rows
+    tm_flat, tm_row = jax.lax.sort(
+        (jnp.where(real, order[:r], n * k), at), num_keys=1)
+    return {"held": held, "order": order, "row_token": order[:r] // k,
+            "row_slot": order[:r] % k, "real": real,
+            "row_w": jnp.where(real, w[:r], 0),
+            "row_expert": jnp.minimum(key[:r], count - 1),
+            "group_sizes": group_sizes, "rows": rows,
+            "tm_row": jnp.where(real, tm_row, 0), "tm_flat": tm_flat}
 
 
-def _picked(y, pos, held):
-    """(N, k, d) float32: each held slot's row of ``y``, 0 elsewhere."""
-    return jnp.where(held[..., None], y[pos].astype(jnp.float32), 0)
+def chunk_rows(r):
+    """Rows one turn of ``_rows_of`` moves into a buffer of ``r`` rows.
+    A turn costs by its rows whatever the chunk (4,096, 8,192 and 16,384
+    read the same 45 ns a 4 KB row alone on the chip, 23 in the step), so
+    the chunk only sets what a movement wastes, half a chunk on average,
+    against the number of turns: at 8,192 two or three turns of 0.2 ms
+    move the cell's 14-24 thousand real rows where one gather over the
+    buffer's 131,072 takes 1.2-2.2 ms (benchmark/moe_bench.py rows;
+    PERF.md section 6, PR 31).  A multiple of the grouped products' tile
+    of rows (512).  A buffer that small is one chunk."""
+    return min(r, 8192)
+
+
+# The share of a buffer's rows below which `_rows_of`'s loop over the real
+# chunks beats one plain gather of all of them, by where the rows come from
+# (benchmark/moe_bench.py rows, alone on the chip at R = 131,072 rows of 2,048
+# bf16; PERF.md section 6, PR 31).  From the POSITIONS (x, dout: 64 MB, which
+# XLA prefetches) a plain gather moves a row in 6.3 ns, a turn of the loop in
+# 45: the loop wins under 18 thousand rows (inside the benchmark's step a
+# plain gather takes 9.5 ns a row and a turn 23, so it goes on winning up to
+# 0.4; between the two the form before PR 31 stays).  From the BUFFER itself
+# (512 MB) a plain gather and the grouped product after it take 6.1-6.6 ms,
+# the loop 0.75 + 0.052 a thousand rows: it wins under 100 thousand.
+_LOOPS_BELOW = {"positions": 0.14, "buffer": 0.75}
+
+
+def loops(top_k, count, experts):
+    """Which movements of a layer that holds ``count`` of ``experts`` go as
+    loops over the real chunks: {"positions": bool, "buffer": bool}, from
+    the share of its buffer a balanced router fills."""
+    fill = top_k * count / (experts * min(top_k, count))
+    return {src: fill < below for src, below in _LOOPS_BELOW.items()}
+
+
+# a buffer nobody has written (the tests make it NaN: off the TPU
+# `lax.empty` gives zeros, which would hide a read of an unwritten row)
+_blank = jax.lax.empty
+
+
+def _rows_of(src, idx, rows):
+    """(R, d) whose row ``r`` is ``src[idx[r]]`` in every chunk that holds
+    one of the first ``rows`` rows; the chunks past them are NOT WRITTEN,
+    and nothing may read them (the grouped products walk the tiles their
+    groups fill; whatever else meets the buffer selects by ``real``).  The trip count follows ``rows``: moving rows costs by
+    the real ones, not by the bound.  Where the chunk does not divide R
+    the last chunk overlaps the one before it and writes the same rows
+    again.  ``rows`` None: one gather moves the buffer's every row (see
+    ``loops``)."""
+    if rows is None:
+        return src[idx]
+    r = idx.shape[0]
+    chunk = chunk_rows(r)
+
+    def turn(i, buf):
+        start = jnp.minimum(i * chunk, r - chunk)
+        return jax.lax.dynamic_update_slice(
+            buf, src[jax.lax.dynamic_slice(idx, (start,), (chunk,))],
+            (start, 0))
+
+    return jax.lax.fori_loop(0, (rows + chunk - 1) // chunk, turn,
+                             _blank((r, src.shape[1]), src.dtype))
+
+
+def _sum_tiles(tm, held, tm_flat):
+    """``out[t] = sum of the rows of tm that belong to position t`` (N, d),
+    accumulated in float32; ``tm`` holds the real rows in position order,
+    where the rows of a tile of ``_TILE`` positions are one stretch: a
+    grouped product with each row's one-hot place in its tile, one group a
+    tile, which walks the real rows only (the rest of ``tm`` may be
+    unwritten)."""
+    n, k = held.shape
+    tiles = -(-n // _TILE)
+    sizes = jnp.sum(jnp.pad(jnp.sum(held, axis=1, dtype=jnp.int32),
+                            (0, tiles * _TILE - n)).reshape(tiles, _TILE),
+                    axis=1)
+    place = ((tm_flat // k) % _TILE)[:, None] \
+        == jnp.arange(_TILE, dtype=jnp.int32)[None, :]
+    out = jax.lax.ragged_dot_general(
+        place.astype(tm.dtype), tm, sizes,
+        jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
+        # float32 rows stay float32 on the MXU (bf16 rows are exact there)
+        precision=jax.lax.Precision.HIGHEST
+        if tm.dtype == jnp.float32 else None,
+        preferred_element_type=jnp.float32)
+    return out.reshape(tiles * _TILE, -1)[:n].astype(tm.dtype)
 
 
 @jax.custom_vjp
-def _dispatch(x, row_token, pos, held):
-    """Rows of ``x`` in expert order: ``xs[r] = x[row_token[r]]``."""
-    return x[row_token]
+def _to_rows(x, rows_p, rows_b, row_token, held, tm_row, tm_flat):
+    """Rows of ``x`` in expert order: ``xs[r] = x[row_token[r]]``.
+    ``rows_p`` / ``rows_b``: ``rows`` where the gathers from the positions
+    / from the buffer go as loops, else None."""
+    return _rows_of(x, row_token, rows_p)
 
 
-def _dispatch_fwd(x, row_token, pos, held):
-    return x[row_token], (pos, held)
+def _to_rows_fwd(x, rows_p, rows_b, row_token, held, tm_row, tm_flat):
+    return _rows_of(x, row_token, rows_p), (rows_b, held, tm_row, tm_flat)
 
 
-def _dispatch_bwd(res, dxs):
-    pos, held = res
+def _to_rows_bwd(res, dxs):
+    rows_b, held, tm_row, tm_flat = res
     with jax.named_scope("mx.moe.route"):
-        return jnp.sum(_picked(dxs, pos, held), axis=1).astype(dxs.dtype), \
-            None, None, None
+        return (_sum_tiles(_rows_of(dxs, tm_row, rows_b), held, tm_flat),) \
+            + (None,) * 6
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
 
 
 @jax.custom_vjp
-def _combine(y, weights, pos, held, row_token, row_slot, rows):
-    """``out[t] = sum_s weights[t, s] * y[pos[t, s]]`` over the held slots."""
-    return jnp.sum(_picked(y, pos, held) * weights[..., None],
-                   axis=1).astype(y.dtype)
+def _to_positions(y, rows_p, rows_b, row_token, held, tm_row, tm_flat):
+    """The transpose of ``_to_rows``: ``out[t] = sum of the real rows of y
+    that came from position t``."""
+    return _sum_tiles(_rows_of(y, tm_row, rows_b), held, tm_flat)
 
 
-def _combine_fwd(*args):
-    return _combine.fun(*args), args
+def _to_positions_fwd(y, rows_p, rows_b, row_token, held, tm_row, tm_flat):
+    return _sum_tiles(_rows_of(y, tm_row, rows_b), held, tm_flat), \
+        (rows_p, row_token)
 
 
-def _combine_bwd(res, dout):
-    y, weights, pos, held, row_token, row_slot, rows = res
+def _to_positions_bwd(res, dout):
+    rows_p, row_token = res
     with jax.named_scope("mx.moe.route"):
-        real = jnp.arange(y.shape[0], dtype=jnp.int32) < rows
-        row_w = weights[row_token, row_slot]
-        dy = jnp.where(real[:, None],
-                       dout[row_token].astype(jnp.float32) * row_w[:, None],
-                       0).astype(y.dtype)
-        dw = jnp.sum(_picked(y, pos, held)
-                    * dout.astype(jnp.float32)[:, None, :], axis=-1)
-        return dy, dw.astype(weights.dtype), None, None, None, None, None
+        return (_rows_of(dout, row_token, rows_p),) + (None,) * 6
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+_to_positions.defvjp(_to_positions_fwd, _to_positions_bwd)
 
 
 def moe_forward(x, gate, w1, w2, wg=None, b1=None, b2=None, *, top_k, first,
@@ -144,20 +308,33 @@ def moe_forward(x, gate, w1, w2, wg=None, b1=None, b2=None, *, top_k, first,
     act = _ACTIVATIONS[activation]
     with jax.named_scope("mx.moe.route"):
         r = route(x, gate, top_k, first, count, norm_topk)
-        xs = _dispatch(x, r["row_token"], r["pos"], r["held"])
+        loop = loops(top_k, count, gate.shape[0])
+        moves = (r["rows"] if loop["positions"] else None,
+                 r["rows"] if loop["buffer"] else None,
+                 r["row_token"], r["held"], r["tm_row"], r["tm_flat"])
+        xs = _to_rows(x, *moves)
     with jax.named_scope("mx.moe.experts"):
-        gs = r["group_sizes"]
+        gs, w = r["group_sizes"], r["row_w"][:, None]
+
+        def bias(b):
+            # by a select, forward and backward: past the last real row a
+            # buffer and its gradient hold anything, NaN too
+            return jnp.where(r["real"][:, None], b[r["row_expert"]], 0)
+
         h = jax.lax.ragged_dot(xs, w1, gs)
         if b1 is not None:
-            h = h + b1[r["row_expert"]]
+            h = h + bias(b1)
         h = act(jax.lax.ragged_dot(xs, wg, gs)) * h if wg is not None \
             else act(h)
+        # the routing weight goes onto the hidden rows, in float32 inside
+        # the fusion that makes them (the second product is linear), not
+        # onto its wider results: no pass over the buffer for it
+        h = (h.astype(jnp.float32) * w).astype(h.dtype)
         y = jax.lax.ragged_dot(h, w2, gs)
         if b2 is not None:
-            y = y + b2[r["row_expert"]]
+            y = y + (w * bias(b2)).astype(y.dtype)
     with jax.named_scope("mx.moe.route"):
-        return _combine(y, r["weights"].astype(jnp.float32), r["pos"],
-                        r["held"], r["row_token"], r["row_slot"], r["rows"])
+        return _to_positions(y, *moves)
 
 
 class MoE(HybridBlock):
@@ -232,10 +409,12 @@ class MoE(HybridBlock):
     def _layout(self, positions):
         if positions not in self._laid_out:
             self._laid_out.add(positions)
+            r = self.buffer_rows(positions)
             _trace.instant("mx.moe.layout", args={
                 "experts": self._E, "held": self._count,
-                "first": self._first, "top_k": self._k,
-                "buffer_rows": self.buffer_rows(positions)})
+                "first": self._first, "top_k": self._k, "buffer_rows": r,
+                "chunk_rows": chunk_rows(r),
+                "chunks": -(-r // chunk_rows(r))})
 
     def forward(self, x):
         from ...ops.registry import apply_op
@@ -263,7 +442,9 @@ class MoE(HybridBlock):
     def load(self, x):
         """Rows each held expert would get from ``x``, eagerly, as a list
         of ints (diagnosis and tests; the step program never calls it).
-        Feeds the gauge ``moe_expert_rows{expert}``."""
+        Feeds the gauges ``moe_expert_rows{expert}`` and
+        ``moe_rows_walked_share``: the share of the sorted buffer that the
+        chunk loops walk for this ``x`` (1.0: every chunk)."""
         from ...ndarray.ndarray import NDArray
 
         xv = x._data if isinstance(x, NDArray) else jnp.asarray(x)
@@ -277,6 +458,12 @@ class MoE(HybridBlock):
                            ("expert",))
             for i, s in enumerate(sizes):
                 g.labels(expert=str(self._first + i)).set(s)
+            r = self.buffer_rows(xv.shape[0])
+            chunk = chunk_rows(r)
+            _tel.gauge("moe_rows_walked_share",
+                       "share of the sorted buffer's rows the chunk loops "
+                       "walk, by MoE.load").set(
+                min(r, -(-sum(sizes) // chunk) * chunk) / r)
         return sizes
 
     def __repr__(self):
