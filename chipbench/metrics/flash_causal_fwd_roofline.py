@@ -1,0 +1,8 @@
+"""``flash_fwd`` under ``mx.attn.causal``: least time by the chip's peaks for
+its calls' ALLOWED pairs (``T (T + 1) / 2`` a head) and bytes (K and V once a
+KV head) over their summed device time."""
+import rule_readers  # chipbench/rule_readers.py
+
+
+def read(ctx):
+    return rule_readers.flash_roofline_pct(ctx, "causal", ["flash_fwd"])

@@ -4,14 +4,20 @@ HERE, as one grouped matrix product.
 The reference has no MoE (SURVEY §2.3 EP row: "Absent") — this is a
 new-capability component designed TPU-first.  The layer is told which
 experts it holds (``first .. first + count`` of ``num_experts``): it routes
-every position over ALL ``num_experts`` (router product and softmax in
-float32, renormalised top-k), sorts the assignments that picked a held
+every position over ALL ``num_experts`` (router product and scores in
+float32: a softmax over the experts, or a sigmoid an expert as DeepSeek-V3
+has it; top-k, renormalised, times a routing scale), sorts the assignments
+that picked a held
 expert into expert order, runs the held experts as ``jax.lax.ragged_dot``
 grouped products, and adds each position's weighted results back.  What the
 absent experts would have added is left out: with ``count == num_experts``
 (the default) that is the whole layer; with fewer it is one chip's share
 under expert parallelism, and the shares of all the chips add up to the
-whole layer (pinned by tests/chipbench/test_sdar_chipbench.py).
+whole layer (pinned by tests/chipbench/test_sdar_chipbench.py).  A SHARED
+expert (``shared_hidden``) is one more gated expert that every position
+passes through, unrouted and unweighted, under the scope ``mx.moe.shared``:
+every share holds it whole, so the shares add up to the whole layer with
+the shared expert counted once.
 
 **No assignment is ever dropped.**  Shapes are static, so the sorted buffer
 has ``positions x min(top_k, count)`` rows: a position picks ``top_k``
@@ -90,18 +96,28 @@ _ACTIVATIONS = {"relu": jax.nn.relu, "gelu": jax.nn.gelu,
 _TILE = 128
 
 
-def route(x, gate, top_k, first, count, norm_topk=True):
+_SCORES = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
+           "sigmoid": jax.nn.sigmoid}
+
+
+def route(x, gate, top_k, first, count, norm_topk=True, score="softmax",
+          scale=1.0):
     """Route ``x`` (N, d) over all the router's experts; lay out the
     assignments that picked a held expert in expert order.
 
-    Returns ``layout``'s dict and ``weights`` (N, k) float32 (renormalised
-    over the top-k when ``norm_topk``)."""
+    ``score``: how the router's float32 logits become scores, ``softmax``
+    over the experts or ``sigmoid`` an expert; the ``top_k`` largest are a
+    position's experts.  Returns ``layout``'s dict and ``weights`` (N, k)
+    float32: the chosen scores, renormalised over the top-k when
+    ``norm_topk``, times ``scale``."""
     logits = jnp.einsum("td,ed->te", x, gate,
                         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    probs = _SCORES[score](logits.astype(jnp.float32))
     top_p, top_e = jax.lax.top_k(probs, top_k)
     weights = top_p / jnp.sum(top_p, -1, keepdims=True) if norm_topk \
         else top_p
+    if scale != 1.0:
+        weights = weights * scale
     return dict(layout(top_e, weights, first, count), weights=weights)
 
 
@@ -173,11 +189,26 @@ def chunk_rows(r):
     read the same 45 ns a 4 KB row alone on the chip, 23 in the step), so
     the chunk only sets what a movement wastes, half a chunk on average,
     against the number of turns: at 8,192 two or three turns of 0.2 ms
-    move the cell's 14-24 thousand real rows where one gather over the
-    buffer's 131,072 takes 1.2-2.2 ms (benchmark/moe_bench.py rows;
-    PERF.md section 6, PR 31).  A multiple of the grouped products' tile
-    of rows (512).  A buffer that small is one chunk."""
-    return min(r, 8192)
+    move the SDAR cell's 14-24 thousand real rows where one gather over
+    the buffer's 131,072 takes 1.2-2.2 ms (benchmark/moe_bench.py rows;
+    PERF.md section 6, PR 31).
+
+    The chunk is 3/32 of the buffer, at most 8,192 rows.  The loops are
+    taken at balanced fills under 0.14 (``loops``), in the benchmark's
+    cells an eighth (16 of 128, 32 of 256 experts held at top-8): 4/32 of
+    the buffer, so the balanced expectation lies in the middle of the
+    second chunk and a routing that sends a quarter fewer rows or half as
+    many more takes the same two turns.  With the expectation ON a chunk's
+    boundary the trip count flips with the routing's noise and a step's
+    time with it: a buffer of 65,536 rows in chunks of 8,192 (8,192 rows
+    expected) spread the Laguna cell's median step by 0.84% across three
+    seeds, in chunks of 6,144 by 0.12% (PERF.md section 6, PR 32).  The
+    cap keeps the chunk that PR 31 measured at 131,072 rows.  A multiple
+    of the grouped products' tile of rows (512).  A buffer of up to 8,192
+    rows is one chunk."""
+    if r <= 8192:
+        return r
+    return min(8192, 3 * r // 32 // 512 * 512)
 
 
 # The share of a buffer's rows below which `_rows_of`'s loop over the real
@@ -299,15 +330,18 @@ def _to_positions_bwd(res, dout):
 _to_positions.defvjp(_to_positions_fwd, _to_positions_bwd)
 
 
-def moe_forward(x, gate, w1, w2, wg=None, b1=None, b2=None, *, top_k, first,
-                activation="relu", norm_topk=True):
+def moe_forward(x, gate, w1, w2, wg=None, b1=None, b2=None, shared_w1=None,
+                shared_wg=None, shared_w2=None, *, top_k, first,
+                activation="relu", norm_topk=True, score="softmax",
+                scale=1.0):
     """The layer as a pure function of (N, d) positions; see the module's
     docstring.  ``w1`` (count, d, hidden), ``wg`` the gate projection of a
-    gated expert (``act(x wg) * (x w1)``), ``w2`` (count, hidden, units)."""
+    gated expert (``act(x wg) * (x w1)``), ``w2`` (count, hidden, units);
+    ``shared_*`` the shared expert's three, without the leading dim."""
     count = w1.shape[0]
     act = _ACTIVATIONS[activation]
     with jax.named_scope("mx.moe.route"):
-        r = route(x, gate, top_k, first, count, norm_topk)
+        r = route(x, gate, top_k, first, count, norm_topk, score, scale)
         loop = loops(top_k, count, gate.shape[0])
         moves = (r["rows"] if loop["positions"] else None,
                  r["rows"] if loop["buffer"] else None,
@@ -334,7 +368,11 @@ def moe_forward(x, gate, w1, w2, wg=None, b1=None, b2=None, *, top_k, first,
         if b2 is not None:
             y = y + (w * bias(b2)).astype(y.dtype)
     with jax.named_scope("mx.moe.route"):
-        return _to_positions(y, *moves)
+        out = _to_positions(y, *moves)
+    if shared_w1 is None:
+        return out
+    with jax.named_scope("mx.moe.shared"):
+        return out + (act(x @ shared_wg) * (x @ shared_w1)) @ shared_w2
 
 
 class MoE(HybridBlock):
@@ -361,12 +399,20 @@ class MoE(HybridBlock):
         (default: all of them).  It still routes over all E and computes
         the part of the result its own experts give.
     norm_topk : bool
-        Renormalise the top-k probabilities to sum to 1.
+        Renormalise the top-k scores to sum to 1.
+    score : str
+        'softmax' over the experts or 'sigmoid' an expert (``route``).
+    scale : float
+        Factor on the routing weights, after the renormalisation.
+    shared_hidden : int
+        Hidden width of a shared gated expert that every position passes
+        through, added unweighted (0: none; needs ``gated``).
     """
 
     def __init__(self, num_experts, hidden_size, units, top_k=2,
                  in_units=0, activation="relu", gated=False, use_bias=True,
-                 first=0, count=None, norm_topk=True, **kwargs):
+                 first=0, count=None, norm_topk=True, score="softmax",
+                 scale=1.0, shared_hidden=0, **kwargs):
         super().__init__()
         if top_k < 1 or top_k > num_experts:
             raise MXNetError("top_k must be in [1, num_experts]")
@@ -376,6 +422,10 @@ class MoE(HybridBlock):
                              % (first, first + count, num_experts))
         if activation not in _ACTIVATIONS:
             raise MXNetError("unknown MoE activation %r" % (activation,))
+        if score not in _SCORES:
+            raise MXNetError("unknown MoE router score %r" % (score,))
+        if shared_hidden and not gated:
+            raise MXNetError("a shared expert is a gated expert")
         self._E = int(num_experts)
         self._hidden = int(hidden_size)
         self._units = int(units)
@@ -383,6 +433,7 @@ class MoE(HybridBlock):
         self._act = activation
         self._first, self._count = int(first), count
         self._gated, self._norm_topk = bool(gated), bool(norm_topk)
+        self._score, self._scale = score, float(scale)
         in_units = int(in_units) or int(units)
         self._in_units = in_units
         self._laid_out = set()
@@ -398,6 +449,13 @@ class MoE(HybridBlock):
         self.b2 = Parameter("b2", shape=(count, units), init="zeros",
                             sharding=("ep", None)) if use_bias else None
         self.gate = Parameter("gate", shape=(self._E, in_units))
+        shared = int(shared_hidden)
+        self.shared_w1 = Parameter("shared_w1", shape=(in_units, shared)) \
+            if shared else None
+        self.shared_wg = Parameter("shared_wg", shape=(in_units, shared)) \
+            if shared else None
+        self.shared_w2 = Parameter("shared_w2", shape=(shared, units)) \
+            if shared else None
 
     def _activation(self, jnp_, h):  # parallel.moe_apply's hook
         return _ACTIVATIONS[self._act](h)
@@ -414,7 +472,10 @@ class MoE(HybridBlock):
                 "experts": self._E, "held": self._count,
                 "first": self._first, "top_k": self._k, "buffer_rows": r,
                 "chunk_rows": chunk_rows(r),
-                "chunks": -(-r // chunk_rows(r))})
+                "chunks": -(-r // chunk_rows(r)), "score": self._score,
+                "scale": self._scale,
+                "shared": 0 if self.shared_w1 is None
+                else self.shared_w1.shape[1]})
 
     def forward(self, x):
         from ...ops.registry import apply_op
@@ -424,11 +485,13 @@ class MoE(HybridBlock):
             x = x.reshape((-1, x.shape[-1]))
         self._layout(x.shape[0])
         # the optional weights this layer has, by moe_forward's keyword
-        extra = {n: getattr(self, n).data() for n in ("wg", "b1", "b2")
-                 if getattr(self, n) is not None}
+        extra = {n: getattr(self, n).data()
+                 for n in ("wg", "b1", "b2", "shared_w1", "shared_wg",
+                           "shared_w2") if getattr(self, n) is not None}
         fn = functools.partial(moe_forward, top_k=self._k, first=self._first,
                                activation=self._act,
-                               norm_topk=self._norm_topk)
+                               norm_topk=self._norm_topk, score=self._score,
+                               scale=self._scale)
 
         def moe(x_, gate_, w1_, w2_, *rest):
             return fn(x_, gate_, w1_, w2_, **dict(zip(extra, rest)))
@@ -450,7 +513,8 @@ class MoE(HybridBlock):
         xv = x._data if isinstance(x, NDArray) else jnp.asarray(x)
         xv = xv.reshape(-1, xv.shape[-1])
         sizes = route(xv, self.gate.data()._data, self._k, self._first,
-                      self._count, self._norm_topk)["group_sizes"]
+                      self._count, self._norm_topk, self._score,
+                      self._scale)["group_sizes"]
         sizes = [int(s) for s in jax.device_get(sizes)]
         if _tel.ENABLED:
             g = _tel.gauge("moe_expert_rows",

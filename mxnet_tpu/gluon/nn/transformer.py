@@ -17,6 +17,7 @@ Here the block layer is first-class and TPU-native:
 """
 from __future__ import annotations
 
+import contextlib
 import math
 
 from ... import ndarray as nd
@@ -25,11 +26,13 @@ from ..block import HybridBlock
 from .basic_layers import Dense, Dropout, Embedding, HybridSequential, \
     LayerNorm, RMSNorm
 from .moe import MoE
+from ...ops.pallas_attention import rule_kind, window_mask
 
 __all__ = ["MultiHeadAttention", "PositionwiseFFN",
            "TransformerEncoderCell", "TransformerEncoder",
            "PositionalEmbedding", "SinusoidalPositionalEmbedding",
-           "GroupedQueryAttention", "MoEDecoderLayer"]
+           "GroupedQueryAttention", "GatedMLP", "DecoderLayer",
+           "MoEDecoderLayer"]
 
 
 class MultiHeadAttention(HybridBlock):
@@ -219,17 +222,30 @@ class SinusoidalPositionalEmbedding(HybridBlock):
 class GroupedQueryAttention(HybridBlock):
     """Self-attention of a modern decoder: ``num_heads`` query heads over
     ``num_kv_heads`` KV heads of ``head_dim`` (each KV head serves a group
-    of query heads, never repeated in memory), per-head RMSNorm of q and k
-    with learned gains (QK-norm), rotary positions (rotate-half form) on q
-    and k, no bias.
+    of query heads, never repeated in memory), rotary positions
+    (rotate-half form) on q and k, no bias.  Everything is the LAYER's own:
+    a model may give its layers different head counts, rotary parameters
+    and rules.
+
+    ``qk_norm``: per-head RMSNorm of q and k with learned gains, before
+    the rotary positions (the Qwen3 family; False: none).  ``gate``: a
+    per-head output gate, ``sigmoid(x W_g)`` (one scalar a head a position,
+    from the layer's input) on that head's attention output before the
+    output projection (the headwise form of Qiu et al. arXiv:2505.06708).
+    ``rotary``: keywords of ``nd.rotary_embedding`` beside ``theta``
+    (``rotary_dim``, ``inv_freq``, ``factor``).  ``causal``: query ``i``
+    sees keys ``j <= i``; ``window``: of those only the last ``window``.
 
     forward(x, positions, mask=None): x (B, T, units), positions (T,) or
     (B, T); ``mask`` an array (dense path) or a static
     ``ops.pallas_attention.AttnMask`` that the flash kernels evaluate tile
-    by tile."""
+    by tile, where the rule depends on the call (block diffusion).  The
+    layer's rotary, attention and gate work carries the scope
+    ``mx.attn.<kind>`` of its rule."""
 
     def __init__(self, units, num_heads, num_kv_heads=None, head_dim=None,
-                 rope_theta=10000.0, epsilon=1e-6):
+                 rope_theta=10000.0, epsilon=1e-6, qk_norm=True, gate=False,
+                 rotary=None, causal=False, window=None):
         super().__init__()
         num_kv_heads = num_kv_heads or num_heads
         head_dim = head_dim or units // num_heads
@@ -238,7 +254,9 @@ class GroupedQueryAttention(HybridBlock):
                              % (num_heads, num_kv_heads))
         self._heads, self._kv_heads, self._dim = num_heads, num_kv_heads, \
             head_dim
-        self._theta = rope_theta
+        self._rotary = dict(rotary or {}, theta=rope_theta)
+        self._causal = bool(causal) or window is not None
+        self._window = None if window is None else window_mask(window)
         self.query_proj = Dense(num_heads * head_dim, use_bias=False,
                                 flatten=False, in_units=units)
         self.key_proj = Dense(num_kv_heads * head_dim, use_bias=False,
@@ -248,55 +266,95 @@ class GroupedQueryAttention(HybridBlock):
         self.out_proj = Dense(units, use_bias=False, flatten=False,
                               in_units=num_heads * head_dim)
         self.out_proj.weight.sharding = (None, "tp")
-        self.query_norm = RMSNorm(epsilon=epsilon, in_channels=head_dim)
-        self.key_norm = RMSNorm(epsilon=epsilon, in_channels=head_dim)
+        self.query_norm = RMSNorm(epsilon=epsilon, in_channels=head_dim) \
+            if qk_norm else None
+        self.key_norm = RMSNorm(epsilon=epsilon, in_channels=head_dim) \
+            if qk_norm else None
+        self.gate_proj = Dense(num_heads, use_bias=False, flatten=False,
+                               in_units=units) if gate else None
 
-    def _heads_of(self, proj, norm, x, positions, heads):
-        b, t = x.shape[0], x.shape[1]
-        h = norm(proj(x).reshape((b, t, heads, self._dim)))
-        return nd.rotary_embedding(h, positions, theta=self._theta) \
+    def _placed(self, h, norm, positions, heads):
+        """(B, T, heads * D) projections with their norm and positions."""
+        b, t = h.shape[0], h.shape[1]
+        h = h.reshape((b, t, heads, self._dim))
+        if norm is not None:
+            h = norm(h)
+        return nd.rotary_embedding(h, positions, **self._rotary) \
             .reshape((b, t, heads * self._dim))
 
     def forward(self, x, positions, mask=None):
-        q = self._heads_of(self.query_proj, self.query_norm, x, positions,
-                           self._heads)
-        k = self._heads_of(self.key_proj, self.key_norm, x, positions,
-                           self._kv_heads)
-        out = nd.multi_head_attention(
-            q, k, self.value_proj(x), num_heads=self._heads,
-            num_kv_heads=self._kv_heads, mask=mask)
+        import jax
+
+        if mask is None:
+            mask = self._window
+        causal = self._causal and mask is None
+        kind = rule_kind(causal, mask)
+        q, k, v = self.query_proj(x), self.key_proj(x), self.value_proj(x)
+        with jax.named_scope("mx.attn.%s" % kind) if kind \
+                else contextlib.nullcontext():
+            out = nd.multi_head_attention(
+                self._placed(q, self.query_norm, positions, self._heads),
+                self._placed(k, self.key_norm, positions, self._kv_heads),
+                v, num_heads=self._heads, num_kv_heads=self._kv_heads,
+                mask=mask, causal=causal)
+            if self.gate_proj is not None:
+                b, t = x.shape[0], x.shape[1]
+                g = nd.sigmoid(self.gate_proj(x)) \
+                    .reshape((b, t, self._heads, 1))
+                out = (out.reshape((b, t, self._heads, self._dim)) * g) \
+                    .reshape((b, t, self._heads * self._dim))
         return self.out_proj(out)
 
 
-class MoEDecoderLayer(HybridBlock):
-    """Pre-norm decoder layer whose feed-forward is a mixture of gated SiLU
-    experts: ``h = x + Attn(RMSNorm(x))``, ``h + MoE(RMSNorm(h))``, no bias
-    anywhere.  ``first``/``count`` say which of the ``num_experts`` experts
-    this chip holds (``gluon.nn.MoE``).
+class GatedMLP(HybridBlock):
+    """The dense feed-forward of a modern decoder:
+    ``down(silu(gate(x)) * up(x))``, no bias."""
+
+    def __init__(self, units, hidden_size):
+        super().__init__()
+        self.gate_proj = Dense(hidden_size, use_bias=False, flatten=False,
+                               in_units=units)
+        self.up_proj = Dense(hidden_size, use_bias=False, flatten=False,
+                             in_units=units)
+        self.down_proj = Dense(units, use_bias=False, flatten=False,
+                               in_units=hidden_size)
+        self.gate_proj.weight.sharding = ("tp", None)
+        self.up_proj.weight.sharding = ("tp", None)
+        self.down_proj.weight.sharding = (None, "tp")
+
+    def forward(self, x):
+        return self.down_proj(
+            nd.silu(self.gate_proj(x)) * self.up_proj(x))
+
+
+class DecoderLayer(HybridBlock):
+    """Pre-norm decoder layer, no bias anywhere:
+    ``h = x + attention(RMSNorm(x), positions, mask)``,
+    ``h + feed_forward(RMSNorm(h))``.  The attention block and the
+    feed-forward are handed in; the feed-forward comes as ONE keyword,
+    whose name its parameters carry (``mlp=GatedMLP(...)``,
+    ``moe=MoE(...)``).
 
     ``recompute=True``: inside a traced program (``FusedTrainer``,
     ``hybridize``) the layer runs under ``jax.checkpoint``: the backward
     keeps the layer's input only and runs the layer's forward again."""
 
-    def __init__(self, units, num_heads, num_kv_heads, head_dim,
-                 num_experts, expert_hidden, top_k, first=0, count=None,
-                 rope_theta=10000.0, epsilon=1e-6, norm_topk=True,
-                 recompute=False):
+    def __init__(self, units, attention, epsilon=1e-6, recompute=False,
+                 **feed_forward):
         super().__init__()
+        if len(feed_forward) != 1:
+            raise MXNetError("DecoderLayer takes one feed-forward block as "
+                             "a keyword, got %s" % sorted(feed_forward))
         self._recompute = bool(recompute)
         self.input_norm = RMSNorm(epsilon=epsilon, in_channels=units)
-        self.attention = GroupedQueryAttention(
-            units, num_heads, num_kv_heads, head_dim, rope_theta=rope_theta,
-            epsilon=epsilon)
+        self.attention = attention
         self.post_norm = RMSNorm(epsilon=epsilon, in_channels=units)
-        self.moe = MoE(num_experts, expert_hidden, units, top_k=top_k,
-                       in_units=units, activation="silu", gated=True,
-                       use_bias=False, first=first, count=count,
-                       norm_topk=norm_topk)
+        (self._ffn, block), = feed_forward.items()
+        setattr(self, self._ffn, block)
 
     def _layer(self, x, positions, mask):
         h = x + self.attention(self.input_norm(x), positions, mask)
-        return h + self.moe(self.post_norm(h))
+        return h + getattr(self, self._ffn)(self.post_norm(h))
 
     def forward(self, x, positions, mask=None):
         import jax
@@ -309,3 +367,24 @@ class MoEDecoderLayer(HybridBlock):
                                mask)._data
 
         return nd.NDArray(jax.checkpoint(pure)(x._data, positions._data))
+
+
+class MoEDecoderLayer(DecoderLayer):
+    """``DecoderLayer`` of the Qwen3-MoE family: grouped-KV attention with
+    QK-norm, and a softmax-routed mixture of gated SiLU experts under the
+    name ``moe``.  ``first``/``count`` say which of the ``num_experts``
+    experts this chip holds (``gluon.nn.MoE``)."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 num_experts, expert_hidden, top_k, first=0, count=None,
+                 rope_theta=10000.0, epsilon=1e-6, norm_topk=True,
+                 recompute=False):
+        super().__init__(
+            units, GroupedQueryAttention(
+                units, num_heads, num_kv_heads, head_dim,
+                rope_theta=rope_theta, epsilon=epsilon),
+            epsilon=epsilon, recompute=recompute,
+            moe=MoE(num_experts, expert_hidden, units, top_k=top_k,
+                    in_units=units, activation="silu", gated=True,
+                    use_bias=False, first=first, count=count,
+                    norm_topk=norm_topk))

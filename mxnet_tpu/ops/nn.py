@@ -542,11 +542,13 @@ def multi_head_attention(q, k, v, num_heads=1, mask=None, scale=None,
 
     args = (q, k, v, num_heads, mask, scale, causal, impl, attn_dropout,
             dropout_key, num_kv_heads)
-    if not isinstance(mask, pa.AttnMask):
+    # a structured rule names its calls (kernels and the layout work around
+    # them) in the program, forward and backward: mx.attn.<kind>, the
+    # built-in causal rule as mx.attn.causal
+    kind = pa.rule_kind(causal, mask)
+    if kind is None:
         return _multi_head_attention(*args)
-    # a structured mask names its calls (kernels and the layout work around
-    # them) in the program, forward and backward: mx.attn.<kind>
-    with jax.named_scope("mx.attn.%s" % mask.kind):
+    with jax.named_scope("mx.attn.%s" % kind):
         return _multi_head_attention(*args)
 
 
@@ -631,17 +633,30 @@ def _multi_head_attention(q, k, v, num_heads, mask, scale, causal, impl,
 
 
 @register("rotary_embedding")
-def rotary_embedding(x, positions, theta=10000.0):
+def rotary_embedding(x, positions, theta=10000.0, rotary_dim=None,
+                     inv_freq=None, factor=1.0):
     """Rotary position embedding, rotate-half form (Su et al.
     arXiv:2104.09864 as GPT-NeoX and the Qwen family write it): x
-    (..., T, H, D), positions (T,) or (B, T); pair (i, i + D/2) of every
-    head is rotated by ``positions * theta**(-2i/D)``.  Angles in float32."""
+    (..., T, H, D), positions (T,) or (B, T); pair (i, i + R/2) of the
+    first ``R = rotary_dim`` dimensions of every head (default: all D) is
+    rotated by ``positions * theta**(-2i/R)``, the other ``D - R`` pass
+    through (a partial rotary factor).  ``inv_freq`` (R/2 numbers) gives
+    the frequencies instead of ``theta`` and ``factor`` multiplies cos and
+    sin: what a scaled rotary (YaRN, arXiv:2309.00071) needs, computed by
+    whoever knows the model's config.  Angles in float32."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = positions.astype(jnp.float32)[..., None] * inv      # (..., T, D/2)
+    r = d if rotary_dim is None else int(rotary_dim)
+    inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r)) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
+    ang = positions.astype(jnp.float32)[..., None] * inv      # (..., T, R/2)
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[..., None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[..., None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
-    rot = jnp.concatenate([-x2, x1], -1)
-    return (xf * cos + rot * sin).astype(x.dtype)
+    head = xf if r == d else xf[..., :r]
+    rot = jnp.concatenate([-head[..., r // 2:], head[..., :r // 2]], -1)
+    out = head * cos + rot * sin
+    if r < d:
+        out = jnp.concatenate([out, xf[..., r:]], -1)
+    return out.astype(x.dtype)

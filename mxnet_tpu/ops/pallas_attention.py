@@ -47,7 +47,13 @@ class AttnMask(NamedTuple):
     noisy copy first; position ``i`` has block ``(i mod seq) // block``.
     A noisy query sees the noisy keys of its own block and the clean keys
     of earlier blocks; a clean query sees the clean keys of its own and
-    earlier blocks.  ``seq**2 + seq*block`` of the ``4 seq**2`` pairs."""
+    earlier blocks.  ``seq**2 + seq*block`` of the ``4 seq**2`` pairs.
+
+    ``kind="window"`` (causal sliding window, as Mistral and the Laguna
+    family's ``sliding_attention`` layers have it): self-attention in
+    which query ``i`` sees key ``j`` iff ``0 <= i - j < block``; ``block``
+    is the window, ``seq`` is not used (0).  ``block * T - block *
+    (block - 1) / 2`` of the ``T**2`` pairs at ``T >= block``."""
     kind: str
     seq: int
     block: int
@@ -60,6 +66,22 @@ def block_diffusion_mask(seq, block):
     return AttnMask("block_diffusion", int(seq), int(block))
 
 
+def window_mask(window):
+    if window < 1:
+        raise MXNetError("window_mask: a window of %d keys" % window)
+    return AttnMask("window", 0, int(window))
+
+
+def rule_kind(causal, mask):
+    """The name of a call's structured rule, as its scope (``mx.attn.<kind>``)
+    and ``mx.attn.tiles`` carry it: an ``AttnMask``'s kind, ``causal`` for
+    the built-in rule, None where the call has neither (no mask, or an
+    array)."""
+    if isinstance(mask, AttnMask):
+        return mask.kind
+    return "causal" if causal and mask is None else None
+
+
 def mask_allowed(mask, q_idx, k_idx):
     """The rule as a boolean expression of index arrays (broadcast
     against each other): what the kernels evaluate on a cut tile, from a
@@ -68,6 +90,8 @@ def mask_allowed(mask, q_idx, k_idx):
 
     Divisions, selects and subtractions stay on the operands' own shapes;
     only two compares and their join are as large as the broadcast."""
+    if mask.kind == "window":
+        return (k_idx <= q_idx) & (k_idx > q_idx - mask.block)
     if mask.kind != "block_diffusion":
         raise MXNetError("unknown attention mask kind %r" % (mask.kind,))
     L, b = mask.seq, mask.block
@@ -127,6 +151,15 @@ def _split(lo, hi, whole_lo, whole_hi, head=True, tail=True):
         + ([(b, hi, True)] if tail else [])
 
 
+def _window_has_whole(window, block_q, block_k):
+    """Can a window of ``window`` keys allow any tile whole?  The keys that
+    EVERY row of a query tile sees are ``window - block_q + 1``, and a whole
+    tile needs ``block_k`` of them.  Static: where it is False the kernels
+    trace the cut body only (a window of one tile: both visited tiles are
+    cut, the diagonal's and the far edge's)."""
+    return window + 1 >= block_q + block_k
+
+
 def _k_tiles(qi, block_q, block_k, seq_q, seq_k, causal=False, mask=None):
     """The key tiles that query tile ``qi`` has to visit (the forward and
     dq kernels' loop), as ``(lo, hi, cut)`` ranges of tile indices in
@@ -147,6 +180,17 @@ def _k_tiles(qi, block_q, block_k, seq_q, seq_k, causal=False, mask=None):
         hi = _min(nk, _cdiv(q0 + block_q, block_k))
         return _split(0, hi, 0, _min(_div(q0 + 1, block_k), last),
                       head=False)
+    if mask.kind == "window":
+        # from the first row's oldest key to the diagonal; a tile is whole
+        # when its last key is not past the first row and its first key is
+        # inside the last row's window
+        w = mask.block
+        lo = _div(_max(q0 - (w - 1), 0), block_k)
+        hi = _min(nk, _cdiv(q0 + block_q, block_k))
+        if not _window_has_whole(w, block_q, block_k):
+            return [(lo, hi, True)]
+        return _split(lo, hi, _cdiv(_max(q0 + block_q - w, 0), block_k),
+                      _min(_div(q0 + 1, block_k), last))
     # block diffusion: noisy keys of the rows' own blocks (all cut), then
     # clean keys up to the last row's block
     L, b = mask.seq, mask.block
@@ -191,6 +235,17 @@ def _q_tiles(j, block_q, block_k, seq_q, seq_k, causal=False, mask=None):
         return _split(_div(k0, block_q), nq,
                       _cdiv(k0 + block_k - 1, block_q), last,
                       tail=last < nq)
+    if mask.kind == "window":
+        # from the diagonal to the last row that sees the tile's last key;
+        # a tile is whole when its first row is not before the last key and
+        # its last row still sees the first key
+        w = mask.block
+        lo = _div(k0, block_q)
+        hi = _min(nq, _cdiv(_min(k0 + block_k, seq_k) + (w - 1), block_q))
+        if not _window_has_whole(w, block_q, block_k):
+            return [(lo, hi, True)]
+        return _split(lo, hi, _cdiv(k0 + block_k - 1, block_q),
+                      _min(_div(k0 + w, block_q), last))
     # block diffusion: noisy queries (of the noisy keys' own blocks, and of
     # later blocks than the first clean key's), then clean queries from the
     # first clean key's block on
@@ -846,8 +901,7 @@ def _note_tiles(cfg, q, k):
 
     visited, whole, cut = tile_counts(*what)
     _trace.instant("mx.attn.tiles", args={
-        "kind": "causal" if cfg.causal else
-        cfg.mask.kind if cfg.mask is not None else "none",
+        "kind": rule_kind(cfg.causal, cfg.mask) or "none",
         "visited": visited, "whole": whole, "cut": cut,
         "operand_dtype": dtype})
 
@@ -863,11 +917,12 @@ def _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
     if mask is not None:
         if causal:
             raise MXNetError("flash_attention: causal= and mask= together")
-        if q.shape[2] != 2 * mask.seq or k.shape[2] != 2 * mask.seq:
+        want = q.shape[2] if mask.kind == "window" else 2 * mask.seq
+        if q.shape[2] != want or k.shape[2] != want:
             raise MXNetError(
-                "flash_attention: a block_diffusion mask of seq %d needs "
-                "%d query and key positions, got %d and %d"
-                % (mask.seq, 2 * mask.seq, q.shape[2], k.shape[2]))
+                "flash_attention: a %s mask needs %d query and key "
+                "positions, got %d and %d"
+                % (mask.kind, want, q.shape[2], k.shape[2]))
     if q.shape[1] % k.shape[1]:
         raise MXNetError("flash_attention: %d query heads over %d KV heads"
                          % (q.shape[1], k.shape[1]))

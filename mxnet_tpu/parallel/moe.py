@@ -75,9 +75,11 @@ def moe_apply(moe, x, mesh=None, axis_name="ep", capacity_factor=2.0,
                          % (axis_name,))
     ep = int(mesh.shape[axis_name])
     E, k = moe._E, moe._k
-    if moe._gated or moe.b1 is None or moe._count != E:
+    if moe._gated or moe.b1 is None or moe._count != E \
+            or moe._score != "softmax" or moe._scale != 1.0:
         raise MXNetError("moe_apply takes the ungated, biased MoE with "
-                         "every expert held")
+                         "every expert held, a softmax router and no "
+                         "routing scale")
     if E % ep:
         raise MXNetError("num_experts %d not divisible by ep=%d" % (E, ep))
     xv = x._data if isinstance(x, NDArray) else jnp.asarray(x)

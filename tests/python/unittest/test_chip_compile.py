@@ -166,6 +166,44 @@ def test_block_diffusion_kernels_compile_for_v5e_with_grouped_kv(topo):
     assert "bf16[32,8192,128]" in text and "bf16[4,8192,128]" in text
 
 
+@pytest.mark.parametrize("rule", ["window", "causal"])
+def test_laguna_calls_compile_for_v5e_with_grouped_kv(topo, mosaic_modules,
+                                                      rule):
+    """laguna_xs2_t8k's two calls: 8,192 positions, 8 KV heads of 128 under
+    64 query heads and a window of 512 keys, or under 48 and the causal
+    rule.  The window of one tile traces the cut body alone (2, 3 and 4
+    products); the causal call traces it twice (whole and cut tiles).  bf16
+    into every product, no score matrix in the program."""
+    heads, kw, bodies = (64, {"mask": pa.window_mask(512)}, 1) \
+        if rule == "window" else (48, {"causal": True}, 2)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def f(q, k, v):
+        def loss(q, k, v):
+            return pa.flash_attention(q, k, v, interpret=False, **kw) \
+                .astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    q = jax.ShapeDtypeStruct((1, heads, 8192, 128), jnp.bfloat16,
+                             sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16, sharding=one)
+    text = jax.jit(f).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "8192,8192" not in text                  # no score matrix
+    assert "bf16[%d,8192,128]" % heads in text and "bf16[8,8192,128]" in text
+    assert len(mosaic_modules) == 3             # forward, dq, dkv
+    for module, n in zip(mosaic_modules, (2, 3, 4)):
+        matmuls = re.findall(
+            r"tpu\.matmul.*?: (vector<[^>]*>), (vector<[^>]*>), "
+            r"(vector<[^>]*>)", module)
+        assert len(matmuls) == n * bodies, (len(matmuls), n, bodies)
+        for lhs, rhs, acc in matmuls:
+            assert lhs.endswith("xbf16>") and rhs.endswith("xbf16>") \
+                and acc.endswith("xf32>"), (lhs, rhs, acc)
+        assert "arith.extf" not in module
+
+
 @pytest.mark.parametrize("dropout_p", [0.0, 0.1])
 def test_flash_lowers_on_a_dp_mesh_without_gathering_the_batch(topo,
                                                                dropout_p):

@@ -18,11 +18,16 @@ def _dense_moe(moe, x):
     """Every held expert on every position, weighted by its routing weight
     or 0: what the grouped path must equal."""
     p = {n: v.data().asnumpy() for n, v in moe.collect_params().items()}
-    probs = jax.nn.softmax(x @ p["gate"].T, axis=-1)
+    logits = x @ p["gate"].T
+    probs = jax.nn.sigmoid(logits) if moe._score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(probs, moe._k)
-    top_p = top_p / top_p.sum(-1, keepdims=True)
+    top_p = moe._scale * top_p / top_p.sum(-1, keepdims=True)
     weight = (jax.nn.one_hot(top_e, moe._E) * top_p[..., None]).sum(1)
     out = 0
+    if "shared_w1" in p:    # every position, unrouted and unweighted
+        out = (jax.nn.silu(x @ p["shared_wg"]) * (x @ p["shared_w1"])) \
+            @ p["shared_w2"]
     for e in range(moe._count):
         h = x @ p["w1"][e]
         if "b1" in p:
@@ -39,6 +44,11 @@ def _dense_moe(moe, x):
     dict(gated=True, use_bias=False, activation="silu"),
     dict(gated=True, use_bias=False, activation="silu", first=2, count=3),
     dict(first=6, count=2, top_k=3),         # top_k over the experts held
+    dict(gated=True, use_bias=False, activation="silu", score="sigmoid",
+         scale=2.5),                         # sigmoid scores, scaled weights
+    dict(gated=True, use_bias=False, activation="silu", score="sigmoid",
+         scale=2.5, shared_hidden=12, first=2, count=3),   # a shared expert
+    dict(gated=True, use_bias=False, activation="silu", shared_hidden=4),
 ])
 def test_grouped_forward_equals_every_expert_on_every_position(kw):
     mx.random.seed(5)
@@ -342,11 +352,27 @@ def test_layout_instant_is_written_when_the_layer_meets_a_shape(monkeypatch):
     assert len(got) == 1
     assert got[0]["args"] == {"experts": 8, "held": 4, "first": 2,
                               "top_k": 2, "buffer_rows": 12,
-                              "chunk_rows": 12, "chunks": 1}
+                              "chunk_rows": 12, "chunks": 1,
+                              "score": "softmax", "scale": 1.0, "shared": 0}
     monkeypatch.setattr(moe_mod, "chunk_rows", lambda r: min(r, 8))
     moe(nd.array(np.ones((10, 8), "float32")))
     assert [e for e in trace.events() if e.get("name") == "mx.moe.layout"][
         -1]["args"]["chunks"] == 3                    # 20 rows by eights
+
+
+def test_chunk_puts_the_balanced_expectation_inside_a_chunk():
+    """3/32 of the buffer, at most 8,192, in whole tiles of 512; a buffer
+    of up to 8,192 rows is one chunk.  An eighth of the buffer (the fill of
+    the benchmark's MoE cells) is 1.33 chunks where the cap does not bind:
+    not on a boundary, where the trip count would flip with the routing."""
+    assert [moe_mod.chunk_rows(r) for r in (12, 100, 8192)] == [12, 100, 8192]
+    assert moe_mod.chunk_rows(65536) == 6144        # laguna_xs2_t8k
+    assert moe_mod.chunk_rows(131072) == 8192       # sdar_30b_a3b_bd4k
+    assert moe_mod.chunk_rows(16384) == 1536
+    assert moe_mod.chunk_rows(10 ** 7) == 8192
+    for r in (16384, 32768, 65536, 81920):
+        chunk = moe_mod.chunk_rows(r)
+        assert chunk % 512 == 0 and 1.3 < (r / 8) / chunk < 1.4
 
 
 def test_constructor_refuses_experts_outside_the_router():
@@ -354,6 +380,54 @@ def test_constructor_refuses_experts_outside_the_router():
         nn.MoE(8, 16, 8, first=6, count=4)
     with pytest.raises(MXNetError):
         nn.MoE(8, 16, 8, activation="tanh")
+    with pytest.raises(MXNetError, match="score"):
+        nn.MoE(8, 16, 8, score="tanh")
+    with pytest.raises(MXNetError, match="shared"):
+        nn.MoE(8, 16, 8, shared_hidden=4)       # ungated
+
+
+def test_sigmoid_routing_weights_are_the_scaled_share_of_the_chosen_scores():
+    """DeepSeek-V3's router: a sigmoid an expert (the scores do not compete),
+    the k largest, normalised over the chosen ones, times the scale."""
+    rs = np.random.RandomState(2)
+    x, gate = rs.randn(20, 6).astype("float32"), \
+        rs.randn(8, 6).astype("float32")
+    r = moe_mod.route(jnp.asarray(x), jnp.asarray(gate), 3, 0, 8,
+                      score="sigmoid", scale=2.5)
+    s = 1.0 / (1.0 + np.exp(-(x @ gate.T)))
+    chosen = np.sort(s, axis=-1)[:, ::-1][:, :3]
+    np.testing.assert_allclose(r["weights"],
+                               2.5 * chosen / chosen.sum(-1, keepdims=True),
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(r["weights"]).sum(-1), 2.5,
+                               rtol=1e-5)
+    # softmax scores pick the same experts (both are monotone in the
+    # logit) and weigh them differently
+    soft = moe_mod.route(jnp.asarray(x), jnp.asarray(gate), 3, 0, 8)
+    assert (np.asarray(soft["held"]) == np.asarray(r["held"])).all()
+    assert int(soft["rows"]) == int(r["rows"]) == 60
+    assert np.abs(np.asarray(soft["weights"]) * 2.5
+                  - np.asarray(r["weights"])).max() > 1e-3
+
+
+def test_shared_expert_takes_gradients_and_load_follows_the_sigmoid_router():
+    mx.random.seed(8)
+    moe = nn.MoE(8, 16, 8, top_k=2, gated=True, use_bias=False,
+                 activation="silu", first=2, count=4, score="sigmoid",
+                 scale=2.5, shared_hidden=6)
+    moe.initialize()
+    x = nd.array(np.random.RandomState(4).randn(25, 8))
+    for p in moe.collect_params().values():
+        p.grad_req = "write"
+    with mx.autograd.record():
+        loss = (moe(x) ** 2).sum()
+    loss.backward()
+    for name in ("shared_w1", "shared_wg", "shared_w2", "gate", "w1"):
+        assert float(np.abs(getattr(moe, name).grad().asnumpy()).max()) > 0
+    rows = moe.load(x)
+    r = moe_mod.route(x._data, moe.gate.data()._data, 2, 2, 4,
+                      score="sigmoid", scale=2.5)
+    assert rows == [int(n) for n in r["group_sizes"]] and sum(rows) > 0
 
 
 def test_moe_apply_refuses_a_share_or_gated_experts():
