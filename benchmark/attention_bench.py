@@ -1,21 +1,33 @@
 """Attention microbenchmark: the Pallas flash kernels, alone on the chip.
 
     python benchmark/attention_bench.py cells [--impl FILE] [--dtype D]
-        the two shapes the benchmark's cells send to the kernels, forward
-        and backward in one program, each KERNEL's device time read from a
-        profiler trace by the kernel's name:
-          bert_t512   B 64 x H 12 x T 512 x D 64, no mask
-                      (bert_base_t512: 12 such calls a step)
-          sdar_bd4k   B 2 x 32 query heads over 4 KV heads x 8,192 x D 128
-                      under block_diffusion_mask(4096, 4)
-                      (sdar_30b_a3b_bd4k: 6 layers, forward twice)
+        the shapes the benchmark's cells send to the kernels, forward and
+        backward in one program that starts from the projections' rows,
+        ``(B, T, H*D)`` and ``(B, T, Hkv*D)``, and goes through
+        ``multi_head_attention(impl="pallas")``; each KERNEL's device time
+        is read from a profiler trace by the kernel's name, and the whole
+        program's beside it, so the layout work around the kernels is in
+        the comparison:
+          bert_t512      B 64 x H 12 x T 512 x D 64, no mask
+                         (bert_base_t512: 12 such calls a step)
+          sdar_bd4k      B 2 x 32 query heads over 4 KV heads x 8,192 x D
+                         128 under block_diffusion_mask(4096, 4)
+                         (sdar_30b_a3b_bd4k: 6 layers, forward twice)
+          laguna_causal  B 1 x 48 over 8 KV heads x 8,192 x 128, causal
+          laguna_win     B 1 x 64 over 8 KV heads x 8,192 x 128, window 512
+                         (laguna_xs2_t8k: 2 and 3 layers, forward twice)
         One JSON line a kernel: ms a call, the products' TFLOP/s and their
-        share of the chip's bf16 peak.  ``--impl FILE`` times another
-        version of ``mxnet_tpu/ops/pallas_attention.py`` (say the parent
-        commit's, ``git show HEAD~1:mxnet_tpu/ops/pallas_attention.py``) in
-        the same process tree: the kernel-level before/after of a change to
-        the kernels' bodies.  ``--dtype float32`` feeds the kernels float32
-        (their products then run at float32).
+        share of the chip's bf16 peak; then ``all three`` and ``through
+        multi_head_attention`` (every device operation of the program, and
+        ``around_ms``, what of it is not the kernels).  ``--impl FILE``
+        times another version of ``mxnet_tpu/ops/pallas_attention.py`` (say
+        the parent commit's, ``git show HEAD~1:mxnet_tpu/ops/
+        pallas_attention.py``) in the same process tree: the kernel-level
+        before/after of a change to the kernels.  A file from before the
+        rows layout (no ``heads_per_step``: its ``flash_attention`` takes
+        ``(B, H, T, D)``) is called as its ``multi_head_attention`` called
+        it, with the head-major transposes around it.  ``--dtype float32``
+        feeds the kernels float32 (their products then run at float32).
 
     python benchmark/attention_bench.py [T ...]     # default 2048 8192
         causal forward + backward, Pallas kernels against the blockwise-JAX
@@ -74,27 +86,59 @@ def _kernels(impl=None):
 
 
 def cell_shapes(pa):
-    """name -> (q shape, kv shape, flash_attention keywords, allowed pairs
-    a query head)."""
-    seq, block = 4096, 4
-    return {
-        "bert_t512": ((64, 12, 512, 64), (64, 12, 512, 64), {}, 512 * 512),
-        "sdar_bd4k": ((2, 32, 2 * seq, 128), (2, 4, 2 * seq, 128),
+    """name -> (B, T, query heads, KV heads, D, the call's rule as
+    keywords, allowed pairs a query head)."""
+    seq, block, T, w = 4096, 4, 8192, 512
+    shapes = {
+        "bert_t512": (64, 512, 12, 12, 64, {}, 512 * 512),
+        "sdar_bd4k": (2, 2 * seq, 32, 4, 128,
                       {"mask": pa.block_diffusion_mask(seq, block)},
                       seq * seq + seq * block),
+        "laguna_causal": (1, T, 48, 8, 128, {"causal": True},
+                          T * (T + 1) // 2),
     }
+    if hasattr(pa, "window_mask"):
+        shapes["laguna_win"] = (1, T, 64, 8, 128,
+                                {"mask": pa.window_mask(w)},
+                                w * T - w * (w - 1) // 2)
+    return shapes
 
 
-def kernel_ms(trace_dir):
-    """{kernel: (calls, summed device ms)} from the profiler's trace: the
-    ``XLA Ops`` events of the first TPU plane, by the kernels' names."""
+def attend(pa, H, Hkv, kw):
+    """``(q, k, v)`` rows -> rows through the kernels of ``pa``, the way
+    ``multi_head_attention(impl="pallas")`` reaches them."""
+    if hasattr(pa, "heads_per_step"):
+        # the tree's own caller, on this file's kernels
+        import mxnet_tpu.ops as ops
+        from mxnet_tpu.ops.nn import multi_head_attention
+
+        ops.pallas_attention = pa
+        return lambda q, k, v: multi_head_attention.fn(
+            q, k, v, num_heads=H, num_kv_heads=Hkv, impl="pallas", **kw)
+
+    def heads(x, n):
+        B, T, _ = x.shape
+        return x.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
+
+    def before_rows(q, k, v):
+        out = pa.flash_attention(heads(q, H), heads(k, Hkv), heads(v, Hkv),
+                                 **kw)
+        return out.transpose(0, 2, 1, 3).reshape(q.shape)
+
+    return before_rows
+
+
+def device_ms(trace_dir):
+    """``({kernel: (calls, summed device ms)}, all operations' summed
+    device ms)`` from the profiler's trace: the ``XLA Ops`` events of the
+    first TPU plane, the kernels by their names."""
     import glob
 
     from jax.profiler import ProfileData
 
     path = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
-    found = {}
+    found, every = {}, 0.0
     for plane in ProfileData.from_file(path).planes:
         if not re.match(r"^/device:TPU:\d+$", plane.name):
             continue
@@ -102,6 +146,7 @@ def kernel_ms(trace_dir):
             if line.name != "XLA Ops":
                 continue
             for e in line.events:
+                every += e.duration_ns / 1e6
                 head = e.name.split(" = ", 1)[0]
                 for kernel in KERNEL_PRODUCTS:  # no name holds another
                     if kernel in head:
@@ -109,20 +154,19 @@ def kernel_ms(trace_dir):
                         found[kernel] = (n + 1, ms + e.duration_ns / 1e6)
                         break
         break
-    return found
+    return found, every
 
 
 def bench_cell(name, pa, dtype, iters, impl_label):
-    qs, kvs, kw, pairs = cell_shapes(pa)[name]
+    B, T, H, Hkv, D, kw, pairs = cell_shapes(pa)[name]
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(keys[0], qs, jnp.float32).astype(dtype)
-    k = jax.random.normal(keys[1], kvs, jnp.float32).astype(dtype)
-    v = jax.random.normal(keys[2], kvs, jnp.float32).astype(dtype)
-    w = jax.random.normal(keys[3], qs, jnp.float32).astype(dtype)
+    q, k, v, w = (jax.random.normal(key, (B, T, n * D), jnp.float32)
+                  .astype(dtype) for key, n in zip(keys, (H, Hkv, Hkv, H)))
+    call = attend(pa, H, Hkv, kw)
 
     def loss(q, k, v):
-        out = pa.flash_attention(q, k, v, **kw)
-        return (out.astype(jnp.float32) * w.astype(jnp.float32)).sum()
+        return (call(q, k, v).astype(jnp.float32)
+                * w.astype(jnp.float32)).sum()
 
     step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
     t0 = time.perf_counter()
@@ -135,8 +179,10 @@ def bench_cell(name, pa, dtype, iters, impl_label):
             out = step(q, k, v)
         jax.block_until_ready(out)
         jax.profiler.stop_trace()
-        found = kernel_ms(d)
+        found, every = device_ms(d)
     peak = _peak_bf16_tflops()
+    label = {"shape": name, "impl": impl_label,
+             "dtype": jnp.dtype(dtype).name}
     rows, total = [], 0.0
     for kernel, products in KERNEL_PRODUCTS.items():
         calls, ms = found.get(kernel, (0, 0.0))
@@ -145,16 +191,15 @@ def bench_cell(name, pa, dtype, iters, impl_label):
                              "(found %s)" % (kernel, sorted(found)))
         ms /= calls
         total += ms
-        tflops = 2.0 * products * qs[0] * qs[1] * pairs * qs[3] \
-            / (ms * 1e-3) / 1e12
-        rows.append({"shape": name, "impl": impl_label,
-                     "dtype": jnp.dtype(dtype).name, "kernel": kernel,
-                     "calls": calls, "ms": round(ms, 4),
-                     "tflops": round(tflops, 2),
-                     "peak_share_pct": round(100.0 * tflops / peak, 2)})
-    rows.append({"shape": name, "impl": impl_label,
-                 "dtype": jnp.dtype(dtype).name, "kernel": "all three",
-                 "ms": round(total, 4), "compile_s": round(compile_s, 1)})
+        tflops = 2.0 * products * B * H * pairs * D / (ms * 1e-3) / 1e12
+        rows.append(dict(label, kernel=kernel, calls=calls,
+                         ms=round(ms, 4), tflops=round(tflops, 2),
+                         peak_share_pct=round(100.0 * tflops / peak, 2)))
+    rows.append(dict(label, kernel="all three", ms=round(total, 4),
+                     compile_s=round(compile_s, 1)))
+    rows.append(dict(label, kernel="through multi_head_attention",
+                     ms=round(every / iters, 4),
+                     around_ms=round(every / iters - total, 4)))
     return rows
 
 
@@ -163,18 +208,18 @@ def bench_one(T, impl, B=4, H=12, D=64, dtype=jnp.bfloat16, iters=10,
     from mxnet_tpu.ops import pallas_attention as pa
 
     rs = np.random.RandomState(0)
-    q = jax.device_put(rs.randn(B, H, T, D).astype(np.float32)).astype(dtype)
-    k = jax.device_put(rs.randn(B, H, T, D).astype(np.float32)).astype(dtype)
-    v = jax.device_put(rs.randn(B, H, T, D).astype(np.float32)).astype(dtype)
+    q, k, v = (jax.device_put(rs.randn(B, T, H, D).astype(np.float32))
+               .astype(dtype) for _ in range(3))
 
     if impl == "pallas":
         def fwd(q, k, v):
             return pa.flash_attention(q, k, v, causal=True, block_q=block,
                                       block_k=block)
     else:
-        def fwd(q, k, v):
-            return pa.blockwise_attention(q, k, v, causal=True,
-                                          block_k=block)
+        def fwd(q, k, v):       # the scan runs head-major
+            return pa.blockwise_attention(
+                *(x.transpose(0, 2, 1, 3) for x in (q, k, v)), causal=True,
+                block_k=block)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()

@@ -244,6 +244,13 @@ def _rel_err(got, want):
     return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
 
 
+def _rows_call(pa, q, k, v, **kw):
+    """``flash_attention`` on this check's head-major (B, H, T, D) arrays:
+    the kernels take (B, T, H, D), so the transposes stand here."""
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    return pa.flash_attention(q, k, v, **kw).transpose(0, 2, 1, 3)
+
+
 def _flash_case(shape, causal, rehearse, dropout_p=0.0, key=None,
                 block=None):
     """Compile fwd+bwd, assert it is the Mosaic kernel, run it."""
@@ -259,8 +266,8 @@ def _flash_case(shape, causal, rehearse, dropout_p=0.0, key=None,
 
     def fwd_bwd(q, k, v, do):
         out, vjp = jax.vjp(
-            lambda q, k, v: pa.flash_attention(
-                q, k, v, causal=causal, dropout_p=dropout_p,
+            lambda q, k, v: _rows_call(
+                pa, q, k, v, causal=causal, dropout_p=dropout_p,
                 dropout_key=key, block_q=block, block_k=block), q, k, v)
         return (out,) + vjp(do)
 
@@ -290,9 +297,9 @@ def _recovered_mask(qkv, key, dropout_p, block):
     def cols(q, k, k0):
         v = (jnp.arange(T)[:, None] == k0 + jnp.arange(D)[None, :])
         v = jnp.broadcast_to(v.astype(q.dtype), (B, H, T, D))
-        return pa.flash_attention(q, k, v, dropout_p=dropout_p,
-                                  dropout_key=key, block_q=block,
-                                  block_k=block) > 0
+        return _rows_call(pa, q, k, v, dropout_p=dropout_p,
+                          dropout_key=key, block_q=block,
+                          block_k=block) > 0
 
     return jnp.concatenate([cols(q, k, k0) for k0 in range(0, T, D)],
                            axis=-1)

@@ -527,6 +527,14 @@ def multi_head_attention(q, k, v, num_heads=1, mask=None, scale=None,
     long sequences.
 
     impl: 'auto' | 'dense' | 'flash' (blockwise scan) | 'pallas'.
+    'pallas' hands the kernels these rows as they are, viewed as
+    (B, T, H, D), and gets rows back: no transpose stands in this function
+    on either side of the call.  At heads of 64 or 32 the kernels' blocks
+    index the heads where the projections wrote them, forward and backward;
+    every other shape is transposed inside ``flash_attention``
+    (``pallas_attention.heads_per_step``: at heads of 128 XLA makes that
+    transpose the layout of the projection's product).  The dense and
+    blockwise paths run head-major and transpose here.
     attn_dropout (+ dropout_key) drops attention probabilities; every
     impl supports it — the Pallas kernel applies a per-tile PRNG mask
     inside fwd AND both backward kernels (regenerated, never stored), so
@@ -566,9 +574,10 @@ def _multi_head_attention(q, k, v, num_heads, mask, scale, causal, impl,
                          % (num_heads, kv_heads))
     group = num_heads // kv_heads
     rule = mask if isinstance(mask, pa.AttnMask) else None
-    qh = q.reshape(B, Tq, num_heads, D).transpose(0, 2, 1, 3)
-    kh = k.reshape(B, Tk, kv_heads, D).transpose(0, 2, 1, 3)
-    vh = v.reshape(B, Tk, kv_heads, D).transpose(0, 2, 1, 3)
+    # the projections' rows with their heads named: a free reshape
+    q4 = q.reshape(B, Tq, num_heads, D)
+    k4 = k.reshape(B, Tk, kv_heads, D)
+    v4 = v.reshape(B, Tk, kv_heads, D)
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     if attn_dropout > 0.0 and dropout_key is None:
         raise MXNetError("attn_dropout > 0 requires dropout_key (draw one "
@@ -596,18 +605,19 @@ def _multi_head_attention(q, k, v, num_heads, mask, scale, causal, impl,
                 "and, for 'pallas', a static AttnMask); use impl='dense' or "
                 "drop the mask" % impl)
         if impl == "pallas":
-            out = pa.flash_attention(qh, kh, vh, causal, scale,
-                                     dropout_p=attn_dropout,
-                                     dropout_key=dropout_key, mask=rule)
-        else:
-            if group > 1:
-                raise MXNetError("impl='flash' (blockwise) has no grouped "
-                                 "KV heads; use 'pallas' or 'dense'")
-            out = pa.blockwise_attention(qh, kh, vh, causal=causal,
-                                         sm_scale=scale,
-                                         dropout_p=attn_dropout,
-                                         dropout_key=dropout_key)
+            # the kernels index the rows where they lie: no transpose here
+            return pa.flash_attention(
+                q4, k4, v4, causal, scale, dropout_p=attn_dropout,
+                dropout_key=dropout_key, mask=rule).reshape(B, Tq, HD)
+        if group > 1:
+            raise MXNetError("impl='flash' (blockwise) has no grouped "
+                             "KV heads; use 'pallas' or 'dense'")
+        out = pa.blockwise_attention(
+            *(x.transpose(0, 2, 1, 3) for x in (q4, k4, v4)), causal=causal,
+            sm_scale=scale, dropout_p=attn_dropout, dropout_key=dropout_key)
         return out.transpose(0, 2, 1, 3).reshape(B, Tq, HD)
+    # the dense products run head-major
+    qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q4, k4, v4))
     if rule is not None:
         mask = pa.mask_allowed(rule, jnp.arange(Tq, dtype=jnp.int32)[:, None],
                                jnp.arange(Tk, dtype=jnp.int32)[None, :])

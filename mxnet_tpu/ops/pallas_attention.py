@@ -15,7 +15,10 @@ Three tiers:
                             backward recomputes per-block probabilities
                             from the saved logsumexp in dedicated dq and
                             dk/dv kernels, with in-kernel probability
-                            dropout.
+                            dropout.  Takes and returns (B, T, H, D): at
+                            heads of 64 or 32 the kernels' blocks index
+                            the projections' rows, ``heads_per_step``
+                            heads a grid step.
 - ``blockwise_attention`` — pure-JAX lax.scan online softmax;
                             differentiable end-to-end; the fallback path.
 - dense                   — plain einsum chain (ops/nn.py), best for short T.
@@ -450,94 +453,163 @@ def _scores(a, b, scale, valid):
     return s if valid is None else jnp.where(valid, s, _NEG_INF)
 
 
-def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
-                  causal, block_q, block_k, seq_k, dropout_p, interpret,
-                  mask=None, seq_q=None):
-    bh = pl.program_id(0)
+# Heads a grid step (``heads_per_step``).  A step that serves several heads
+# holds them side by side in blocks ``heads * D`` = 128 lanes wide and takes
+# them one after the other, every product at the block's full width: the
+# LEFT operand of a product contracted over the lanes (q or dO in ``A @
+# B.T``, k or v in the transposed tile) has the other heads' lanes zeroed,
+# which costs the MXU the passes a product over D lanes costs (a pass
+# contracts 128 either way), and a product that MAKES lanes (``p @ v``,
+# ``ds @ k``, ...) is right in the head's own lanes, which are selected
+# into the step's result.  Measured against static lane slices of the refs
+# (``q_ref[0, :, 64:128]``: a lane rotate a block) at BERT's shape: 1.7%
+# less time over the three kernels (PERF.md section 6, PR 33).
+def _own_lanes(a, heads, width):
+    """The lanes of head ``a`` in a block of ``heads`` heads, as a (1,
+    width) mask; None where the step serves one head (nothing to mask:
+    the bodies are then the single-head bodies, operation for
+    operation)."""
+    if heads == 1:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    d = width // heads
+    return (lane >= a * d) & (lane < (a + 1) * d)
+
+
+def _own(x, own):
+    """``x`` with the other heads' lanes zeroed."""
+    return x if own is None else jnp.where(own, x, jnp.zeros_like(x))
+
+
+def _into(own, mine, step):
+    """The step's result with head ``own``'s lanes taken from ``mine``
+    (``step`` None: the first head)."""
+    if own is None:
+        return mine
+    return jnp.where(own, mine, 0.0 if step is None else step)
+
+
+def _flash_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, heads,
+                  scale, causal, block_q, block_k, seq_k, dropout_p,
+                  interpret, mask=None, seq_q=None):
+    """Forward for one (step of ``heads`` heads, q-block): the heads one
+    after the other, each on its own lanes of the blocks (``_own_lanes``)
+    and with its own statistics and dropout seed."""
+    n = pl.program_id(0)
     qi = pl.program_id(1)
-    q = q_ref[0]                                      # (block_q, D)
-    D = q.shape[-1]
+    width = q_ref.shape[-1]
     nk_all = pl.cdiv(seq_k, block_k)
     pad_k = seq_k if seq_k % block_k else None
 
-    def body(j, carry, cut):
-        m, l, acc = carry
-        kblk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        vblk = v_ref[0, pl.ds(j * block_k, block_k), :]
-        valid = _cut_valid(qi, j, block_q, block_k, causal, mask,
-                           seq_k=pad_k) if cut else None
-        s = _scores(q, kblk, scale, valid)            # (block_q, block_k)
-        m_new = jnp.maximum(m, s.max(-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m - m_new)
-        # denominator accumulates UNdropped mass (the BERT recipe:
-        # dropout(softmax(s)) @ v — normalization sees the full softmax)
-        l_new = l * corr + p.sum(-1)
-        if dropout_p > 0.0:
-            keep = _tile_keep_mask(seed_ref[bh], qi * nk_all + j, p.shape,
-                                   dropout_p, interpret)
-            p = p * keep.astype(p.dtype) / (1.0 - dropout_p)
-        acc_new = acc * corr[:, None] + jax.lax.dot_general(
-            p.astype(vblk.dtype), vblk, _NN,
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+    out = None
+    for a in range(heads):
+        own = _own_lanes(a, heads, width)
+        q = _own(q_ref[0], own)                       # (block_q, width)
 
-    m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    a0 = jnp.zeros((block_q, D), jnp.float32)
-    m, l, acc = _loop_tiles(
-        _k_tiles(qi, block_q, block_k, seq_q, seq_k, causal, mask), body,
-        (m0, l0, a0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-    # lse rides a (8, block_q) tile — Mosaic requires the last two block
-    # dims be (8k, 128k)-aligned, so a flat (1, block_q) row is illegal on
-    # real TPU; sublane-broadcast and let the caller slice row 0
-    lse = m + jnp.log(jnp.maximum(l, 1e-30))
-    lse_ref[0, 0] = jax.lax.broadcast_in_dim(lse, (8, block_q), (1,))
+        def body(j, carry, cut):
+            m, l, acc = carry
+            kblk = k_ref[0, pl.ds(j * block_k, block_k), :]
+            vblk = v_ref[0, pl.ds(j * block_k, block_k), :]
+            valid = _cut_valid(qi, j, block_q, block_k, causal, mask,
+                               seq_k=pad_k) if cut else None
+            s = _scores(q, kblk, scale, valid)        # (block_q, block_k)
+            m_new = jnp.maximum(m, s.max(-1))
+            p = jnp.exp(s - m_new[:, None])
+            corr = jnp.exp(m - m_new)
+            # denominator accumulates UNdropped mass (the BERT recipe:
+            # dropout(softmax(s)) @ v — normalization sees the full softmax)
+            l_new = l * corr + p.sum(-1)
+            if dropout_p > 0.0:
+                keep = _tile_keep_mask(seed_ref[n * heads + a],
+                                       qi * nk_all + j, p.shape, dropout_p,
+                                       interpret)
+                p = p * keep.astype(p.dtype) / (1.0 - dropout_p)
+            acc_new = acc * corr[:, None] + jax.lax.dot_general(
+                p.astype(vblk.dtype), vblk, _NN,
+                preferred_element_type=jnp.float32)
+            return m_new, l_new, acc_new
+
+        m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((block_q,), jnp.float32)
+        a0 = jnp.zeros((block_q, width), jnp.float32)
+        m, l, acc = _loop_tiles(
+            _k_tiles(qi, block_q, block_k, seq_q, seq_k, causal, mask), body,
+            (m0, l0, a0))
+        out = _into(own, acc / jnp.maximum(l, 1e-30)[:, None], out)
+        # lse rides a (8, block_q) tile — Mosaic requires the last two
+        # block dims be (8k, 128k)-aligned, so a flat (1, block_q) row is
+        # illegal on real TPU; sublane-broadcast and let the caller slice
+        # row 0
+        lse = m + jnp.log(jnp.maximum(l, 1e-30))
+        lse_ref[a, 0] = jax.lax.broadcast_in_dim(lse, (8, block_q), (1,))
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, *, scale, causal, block_q, block_k,
-                   seq_k, dropout_p, interpret, mask=None, seq_q=None):
-    """dq for one (bh, q-block): ds = p∘(msc∘(dO·Vᵀ) − Δ); dq = scale·ds·K."""
-    bh = pl.program_id(0)
+def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref,
+                   *rest, heads, scale, causal, block_q, block_k, seq_k,
+                   dropout_p, interpret, mask=None, seq_q=None):
+    """dq for one (step of heads, q-block): ds = p∘(msc∘(dO·Vᵀ) − Δ);
+    dq = scale·ds·K.
+
+    Δ = rowsum(dO ∘ O) is made HERE, from the two blocks as they lie, and
+    written out in lse's (8, block_q) rows for the dkv kernel: a reduction
+    over D of the ``(B, T, H * D)`` rows is no program XLA writes well (it
+    copies the float32 product into another layout first).  ``rest`` is
+    ``(dq_ref, delta_ref)``, after a ``dlse_ref`` where the caller has a
+    cotangent for lse: that folds into Δ (the softmax backward is ds =
+    p·(dp − Δ) and ∂lse/∂s = p, so ds = p·(dp − (Δ − dlse))), and neither
+    kernel needs to know."""
+    *dlse_ref, dq_ref, delta_ref = rest
+    n = pl.program_id(0)
     qi = pl.program_id(1)
-    q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0, 0, 0, :][:, None]  # row 0 of the (8, block_q) tile
-    delta = delta_ref[0, 0, 0, :][:, None]
-    D = q.shape[-1]
+    width = q_ref.shape[-1]
     nk_all = pl.cdiv(seq_k, block_k)
     pad_k = seq_k if seq_k % block_k else None
 
-    def body(j, dq, cut):
-        kblk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        vblk = v_ref[0, pl.ds(j * block_k, block_k), :]
-        valid = _cut_valid(qi, j, block_q, block_k, causal, mask,
-                           seq_k=pad_k) if cut else None
-        s = _scores(q, kblk, scale, valid)
-        p = jnp.exp(s - lse)                           # rows sum to 1
-        dp = jax.lax.dot_general(do, vblk, _NT,
-                                 preferred_element_type=jnp.float32)
-        if dropout_p > 0.0:
-            keep = _tile_keep_mask(seed_ref[bh], qi * nk_all + j, p.shape,
-                                   dropout_p, interpret)
-            dp = dp * keep.astype(dp.dtype) / (1.0 - dropout_p)
-        ds = p * (dp - delta)
-        return dq + jax.lax.dot_general(
-            ds.astype(kblk.dtype), kblk, _NN,
-            preferred_element_type=jnp.float32)
+    dq_step = None
+    for a in range(heads):
+        own = _own_lanes(a, heads, width)
+        q = _own(q_ref[0], own)
+        do = _own(do_ref[0], own)
+        lse = lse_ref[a, 0, 0, :][:, None]  # row 0 of the (8, block_q) tile
+        delta = jnp.sum(do.astype(jnp.float32)
+                        * out_ref[0].astype(jnp.float32), axis=-1)
+        if dlse_ref:
+            delta = delta - dlse_ref[0][a, 0, 0, :]
+        delta_ref[a, 0] = jax.lax.broadcast_in_dim(delta, (8, block_q), (1,))
+        delta = delta[:, None]
 
-    dq = _loop_tiles(
-        _k_tiles(qi, block_q, block_k, seq_q, seq_k, causal, mask), body,
-        jnp.zeros((block_q, D), jnp.float32))
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+        def body(j, dq, cut):
+            kblk = k_ref[0, pl.ds(j * block_k, block_k), :]
+            vblk = v_ref[0, pl.ds(j * block_k, block_k), :]
+            valid = _cut_valid(qi, j, block_q, block_k, causal, mask,
+                               seq_k=pad_k) if cut else None
+            s = _scores(q, kblk, scale, valid)
+            p = jnp.exp(s - lse)                       # rows sum to 1
+            dp = jax.lax.dot_general(do, vblk, _NT,
+                                     preferred_element_type=jnp.float32)
+            if dropout_p > 0.0:
+                keep = _tile_keep_mask(seed_ref[n * heads + a],
+                                       qi * nk_all + j, p.shape, dropout_p,
+                                       interpret)
+                dp = dp * keep.astype(dp.dtype) / (1.0 - dropout_p)
+            ds = p * (dp - delta)
+            return dq + jax.lax.dot_general(
+                ds.astype(kblk.dtype), kblk, _NN,
+                preferred_element_type=jnp.float32)
+
+        dq = _loop_tiles(
+            _k_tiles(qi, block_q, block_k, seq_q, seq_k, causal, mask), body,
+            jnp.zeros((block_q, width), jnp.float32))
+        dq_step = _into(own, dq, dq_step)
+    dq_ref[0] = (dq_step * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, *, scale, causal, block_q,
-                    block_k, seq_q, seq_k, dropout_p, interpret, mask=None):
-    """dk/dv for one (bh, k-block), looping q blocks.
+                    delta_ref, dk_ref, dv_ref, *, heads, scale, causal,
+                    block_q, block_k, seq_q, seq_k, dropout_p, interpret,
+                    mask=None):
+    """dk/dv for one (step of heads, k-block), looping q blocks.
 
     dv = (p∘msc)ᵀ·dO;  dk = scale·dsᵀ·Q  with the SAME per-tile dropout
     mask as the forward (regenerated, not stored).  The tile is formed
@@ -545,55 +617,60 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     products as plain left operands (Mosaic takes no bf16 product
     contracted over dimension 0 of both operands), and lse and Δ broadcast
     along the rows as they lie, without a relayout a tile."""
-    bh = pl.program_id(0)
+    n = pl.program_id(0)
     j = pl.program_id(1)
-    kblk = k_ref[0]                                   # (block_k, D)
-    vblk = v_ref[0]
-    D = kblk.shape[-1]
+    width = k_ref.shape[-1]
     nk_all = pl.cdiv(seq_k, block_k)
     pad_q = seq_q if seq_q % block_q else None
 
-    def body(qi, carry, cut):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :]
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[0, qi, 0, :][None, :]     # (nq, 8, block_q), row 0
-        delta = delta_ref[0, qi, 0, :][None, :]
-        # padded KEY rows need no mask here: they only make rows of dK and
-        # dV that the caller cuts off
-        valid = _cut_valid(qi, j, block_q, block_k, causal, mask,
-                           seq_q=pad_q, transposed=True) if cut else None
-        s = _scores(kblk, q, scale, valid)            # (block_k, block_q)
-        p = jnp.exp(s - lse)
-        if cut and pad_q:
-            p = jnp.where(valid, p, 0.0)              # padded q rows -> 0
-        if dropout_p > 0.0:
-            keep = _tile_keep_mask(
-                seed_ref[bh], qi * nk_all + j, (block_q, block_k),
-                dropout_p, interpret, transposed=True).astype(p.dtype) \
-                / (1.0 - dropout_p)
-        else:
-            keep = None
-        pm = p * keep if keep is not None else p
-        dv = dv + jax.lax.dot_general(
-            pm.astype(do.dtype), do, _NN,
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(vblk, do, _NT,
-                                 preferred_element_type=jnp.float32)
-        if keep is not None:
-            dp = dp * keep
-        ds = p * (dp - delta)
-        dk = dk + jax.lax.dot_general(
-            ds.astype(q.dtype), q, _NN,
-            preferred_element_type=jnp.float32)
-        return dk, dv
+    dk_step = dv_step = None
+    for a in range(heads):
+        own = _own_lanes(a, heads, width)
+        kblk = _own(k_ref[0], own)                    # (block_k, width)
+        vblk = _own(v_ref[0], own)
 
-    dk, dv = _loop_tiles(
-        _q_tiles(j, block_q, block_k, seq_q, seq_k, causal, mask), body,
-        (jnp.zeros((block_k, D), jnp.float32),
-         jnp.zeros((block_k, D), jnp.float32)))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        def body(qi, carry, cut):
+            dk, dv = carry
+            q = q_ref[0, pl.ds(qi * block_q, block_q), :]
+            do = do_ref[0, pl.ds(qi * block_q, block_q), :]
+            lse = lse_ref[a, qi, 0, :][None, :]  # (nq, 8, block_q), row 0
+            delta = delta_ref[a, qi, 0, :][None, :]
+            # padded KEY rows need no mask here: they only make rows of dK
+            # and dV that the caller cuts off
+            valid = _cut_valid(qi, j, block_q, block_k, causal, mask,
+                               seq_q=pad_q, transposed=True) if cut else None
+            s = _scores(kblk, q, scale, valid)        # (block_k, block_q)
+            p = jnp.exp(s - lse)
+            if cut and pad_q:
+                p = jnp.where(valid, p, 0.0)          # padded q rows -> 0
+            if dropout_p > 0.0:
+                keep = _tile_keep_mask(
+                    seed_ref[n * heads + a], qi * nk_all + j,
+                    (block_q, block_k), dropout_p, interpret,
+                    transposed=True).astype(p.dtype) / (1.0 - dropout_p)
+            else:
+                keep = None
+            pm = p * keep if keep is not None else p
+            dv = dv + jax.lax.dot_general(
+                pm.astype(do.dtype), do, _NN,
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(vblk, do, _NT,
+                                     preferred_element_type=jnp.float32)
+            if keep is not None:
+                dp = dp * keep
+            ds = p * (dp - delta)
+            dk = dk + jax.lax.dot_general(
+                ds.astype(q.dtype), q, _NN,
+                preferred_element_type=jnp.float32)
+            return dk, dv
+
+        dk, dv = _loop_tiles(
+            _q_tiles(j, block_q, block_k, seq_q, seq_k, causal, mask), body,
+            (jnp.zeros((block_k, width), jnp.float32),
+             jnp.zeros((block_k, width), jnp.float32)))
+        dk_step, dv_step = _into(own, dk, dk_step), _into(own, dv, dv_step)
+    dk_ref[0] = (dk_step * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_step.astype(dv_ref.dtype)
 
 
 def _smem_spec():
@@ -603,34 +680,96 @@ def _smem_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
+def heads_per_step(head_dim, heads, kv_heads):
+    """How many heads ONE grid step of the kernels serves when they read
+    ``(B, T, heads * head_dim)`` rows as the projections wrote them, or 0
+    where the kernels take a head-major view instead.
+
+    Rows pay where a head-major copy is a copy: heads NARROWER than the
+    128 lanes (BERT's 64).  There XLA cannot write a projection's product
+    head-major for nothing (a ``(T, 64)`` tile half-fills its registers),
+    so every transpose around a kernel call was a ``copy`` of its own; a
+    block's last dimension has to be a multiple of 128 lanes or the whole
+    array's, so such heads go ``128 // head_dim`` a block, if the head
+    count divides by that and K/V are not grouped (a block of K/V then
+    holds the same heads).  A single head spans its array either way.
+
+    Everything else gets 0, and ``flash_attention`` hands the kernels a
+    head-major view in which every head is a batch row of ONE head: heads
+    of 80 or 96, an odd head count of 64, 64 with grouped KV heads, AND
+    heads of a multiple of 128 lanes (SDAR, Laguna).  For those the view
+    is no copy: XLA gives the transpose to the projection's product as
+    its layout (``(T, 128)`` tiles are whole registers) and QK-norm and
+    rotary run on it, while rows would need every 4-D elementwise
+    operation between projection and kernel to keep rows' tiles (16
+    positions of one head's lanes), which XLA's layouts for ``(B, T, H,
+    D)`` do not: ``laguna_xs2_t8k`` lost 3.7% that way (PERF.md section
+    6, PR 33)."""
+    if heads == kv_heads == 1:
+        return 1
+    g = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 0
+    return g if g and heads == kv_heads and heads % g == 0 else 0
+
+
+def _steps(q, k):
+    """``(heads a grid step, head blocks, group)`` of a call on local
+    ``(B, T, H, D)`` arrays: blocks of ``heads * D`` lanes along the rows
+    (as many in k and v as in q: heads that share a step are not grouped,
+    and a grouped call comes head-major, one head a row), and the query
+    heads a KV head serves."""
+    B, _, H, D = q.shape
+    g = heads_per_step(D, H, k.shape[2])
+    return g, H // g, (B * H) // (k.shape[0] * k.shape[2])
+
+
 def _pad_pack(q, k, v, block_q, block_k):
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    nq = -(-Tq // block_q)
-    nk = -(-Tk // block_k)
-    pad_q = nq * block_q - Tq
-    pad_k = nk * block_k - Tk
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
-    if pad_k:
-        # k/v must be padded to a block multiple: pl.ds clamps its start at
-        # the array edge, which would misalign rows against the k_idx mask
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-    qf = q.reshape(B * H, nq * block_q, D)
-    kf = k.reshape(B * k.shape[1], Tk + pad_k, D)    # H, or fewer KV heads
-    vf = v.reshape(B * v.shape[1], Tk + pad_k, D)
-    return qf, kf, vf, nq, nk, pad_q, pad_k
+    """``(B, T, H, D)`` arrays as the rows the kernels index, ``(B, T
+    padded to the block, H * D)``: a free reshape, and a pad on axis 1
+    where T is not a multiple of the block.  Also the rows q was padded
+    by."""
+    pad_q = -q.shape[1] % block_q
+    # k/v must be padded to a block multiple: pl.ds clamps its start at the
+    # array edge, which would misalign rows against the k_idx mask
+    pad_k = -k.shape[1] % block_k
+    return _rows(q, pad_q), _rows(k, pad_k), _rows(v, pad_k), pad_q
 
 
-def _kv_index(group):
-    """Index map of a whole-K/V block for grid point (b*H + h, tile): with
-    grouped KV heads, query head ``h`` reads KV head ``h // group`` where it
-    lies; K and V are never repeated in memory."""
-    if group == 1:
-        return lambda b, i: (b, 0, 0)
-    return lambda b, i: (b // group, 0, 0)
+def _rows(x, pad):
+    x = x.reshape(x.shape[0], x.shape[1], -1)
+    return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
 
+
+def _head_block(n, blocks):
+    """Grid point ``n`` = batch row * ``blocks`` + head block, taken
+    apart."""
+    if blocks == 1:
+        return n, 0
+    return jax.lax.div(n, blocks), jax.lax.rem(n, blocks)
+
+
+def _q_index(blocks, whole=False):
+    """Index map of a block of q, dO, out, dq for grid point ``(b * blocks
+    + h, tile)``: rows ``tile`` (or all of them) of batch row ``b``, lanes
+    of head block ``h``."""
+    def at(n, i):
+        b, h = _head_block(n, blocks)
+        return (b, 0 if whole else i, h)
+    return at
+
+
+def _kv_index(group, blocks, whole=True):
+    """Index map of a K/V block for grid point ``(n, tile)``, ``n`` the
+    query head block counted over the batch: with grouped KV heads, query
+    head ``n`` reads KV head ``n // group`` where it lies in the
+    ``(B, Tk, Hkv * D)`` rows, lanes ``[h D, (h + 1) D)`` of batch row
+    ``b`` for KV head ``b * Hkv + h``.  K and V are never repeated in
+    memory, and the consecutive grid points of one group keep the same
+    block index, so a whole-K/V block is fetched once a group."""
+    def at(n, i):
+        b, h = _head_block(n if group == 1 else jax.lax.div(n, group),
+                           blocks)
+        return (b, 0 if whole else i, h)
+    return at
 
 
 class _Cfg(NamedTuple):
@@ -669,7 +808,7 @@ def mesh_rows(mesh, batch_axes):
 
 def _over_rows(local_fn, out_ndims, cfg, *arrays):
     """``local_fn(cfg, *arrays)`` — Pallas calls gridded over the leading
-    (B, H) dims of every array — directly, or per device under the
+    batch rows of every array — directly, or per device under the
     ``mesh_rows`` layout in force."""
     layout = getattr(_MESH_ROWS, "layout", None)
     # inside a caller's shard_map (ring attention, a pipeline stage, an
@@ -677,9 +816,9 @@ def _over_rows(local_fn, out_ndims, cfg, *arrays):
     if layout is None or jax.sharding.get_abstract_mesh().manual_axes:
         return local_fn(cfg, *arrays)
     mesh, batch_axes = layout
-    B, keep, size = arrays[1].shape[0], [], 1
+    keep, size = [], 1
     for a in batch_axes:
-        if B % (size * mesh.shape[a]) == 0:
+        if all(x.shape[0] % (size * mesh.shape[a]) == 0 for x in arrays):
             keep.append(a)
             size *= mesh.shape[a]
     spec = lambda ndim: P(tuple(keep) or None,  # noqa: E731
@@ -693,35 +832,34 @@ def _over_rows(local_fn, out_ndims, cfg, *arrays):
 
 
 def _forward_local(cfg, seeds, q, k, v):
-    """Forward kernel over local arrays -> (out (B,H,Tq,D), lse
-    (B,H,Tq padded to the q block))."""
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    """Forward kernel over local arrays, q ``(B, Tq, H, D)`` and k, v
+    ``(B, Tk, Hkv, D)`` -> (out like q, lse ``(B, H, Tq padded to the q
+    block)``)."""
+    B, Tq, H, D = q.shape
+    Tk = k.shape[1]
     block_q, block_k = min(cfg.block_q, Tq), min(cfg.block_k, Tk)
-    qf, kf, vf, nq, _nk, pad_q, _pad_k = _pad_pack(q, k, v, block_q,
-                                                   block_k)
-    Tk_pad = kf.shape[1]
-    kv_of = _kv_index(H // k.shape[1])
+    qf, kf, vf, _ = _pad_pack(q, k, v, block_q, block_k)
+    nq, Tk_pad = qf.shape[1] // block_q, kf.shape[1]
+    g, blocks, group = _steps(q, k)
+    width = g * D
+    q_tile = pl.BlockSpec((1, block_q, width), _q_index(blocks))
+    kv_all = pl.BlockSpec((1, Tk_pad, width), _kv_index(group, blocks))
 
     kernel = functools.partial(
-        _flash_kernel, scale=cfg.scale, causal=cfg.causal, block_q=block_q,
-        block_k=block_k, seq_k=Tk, dropout_p=cfg.dropout_p,
-        interpret=cfg.interpret, mask=cfg.mask, seq_q=Tq)
+        _flash_kernel, heads=g, scale=cfg.scale, causal=cfg.causal,
+        block_q=block_q, block_k=block_k, seq_k=Tk,
+        dropout_p=cfg.dropout_p, interpret=cfg.interpret, mask=cfg.mask,
+        seq_q=Tq)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * H, nq),
-        in_specs=[
-            _smem_spec(),
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk_pad, D), kv_of),
-            pl.BlockSpec((1, Tk_pad, D), kv_of),
-        ],
+        grid=(B * H // g, nq),
+        in_specs=[_smem_spec(), q_tile, kv_all, kv_all],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, 8, block_q), lambda b, i: (b, i, 0, 0)),
+            q_tile,
+            pl.BlockSpec((g, 1, 8, block_q), lambda n, i: (n, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, nq * block_q, D), q.dtype),
+            jax.ShapeDtypeStruct(qf.shape, q.dtype),
             jax.ShapeDtypeStruct((B * H, nq, 8, block_q), jnp.float32),
         ],
         interpret=cfg.interpret,
@@ -729,102 +867,94 @@ def _forward_local(cfg, seeds, q, k, v):
         name="flash_fwd",
     )(seeds.reshape(B * H), qf, kf, vf)
     lse = lse[:, :, 0, :].reshape(B, H, nq * block_q)
-    out = out.reshape(B, H, nq * block_q, D)
-    return (out[:, :, :Tq] if pad_q else out), lse
+    return out[:, :Tq].reshape(q.shape), lse
 
 
-def _backward_local(cfg, seeds, q, k, v, do, lse, delta):
-    """dq and dk/dv kernels over local arrays; ``lse``/``delta`` are
+def _backward_local(cfg, seeds, q, k, v, out, do, lse, *dlse):
+    """dq and dk/dv kernels over local arrays laid out as the forward's;
+    ``lse`` (and ``dlse``, where the caller has a cotangent for it) are
     (B, H, Tq padded to the q block)."""
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    B, Tq, H, D = q.shape
+    Tk = k.shape[1]
     block_q, block_k = min(cfg.block_q, Tq), min(cfg.block_k, Tk)
-    qf, kf, vf, nq, nk, pad_q, _pad_k = _pad_pack(q, k, v, block_q, block_k)
+    qf, kf, vf, pad_q = _pad_pack(q, k, v, block_q, block_k)
     Tq_pad, Tk_pad = qf.shape[1], kf.shape[1]
-    dof = jnp.pad(do, ((0, 0), (0, 0), (0, pad_q), (0, 0))) if pad_q else do
-    dof = dof.reshape(B * H, Tq_pad, D)
+    nq, nk = Tq_pad // block_q, Tk_pad // block_k
+    # padded rows contribute zeros: their dO is padding too
+    dof, outf = _rows(do, pad_q), _rows(out, pad_q)
 
-    # widen lse/delta rows to the (nq, 8, block_q) tile layout the kernels
-    # read (see _flash_kernel's lse note)
+    # widen lse rows to the (nq, 8, block_q) tile layout the kernels read
+    # (see _flash_kernel's lse note)
     def _widen(x):
         x = x.reshape(B * H, nq, 1, block_q)
         return jnp.broadcast_to(x, (B * H, nq, 8, block_q))
 
     lse4 = _widen(lse)
-    delta4 = _widen(delta)
     seedf = seeds.reshape(B * H)
-    group = H // k.shape[1]
-    kv_of = _kv_index(group)
+    g, blocks, group = _steps(q, k)
+    width = g * D
+    q_tile = pl.BlockSpec((1, block_q, width), _q_index(blocks))
+    q_all = pl.BlockSpec((1, Tq_pad, width), _q_index(blocks, whole=True))
+    kv_all = pl.BlockSpec((1, Tk_pad, width), _kv_index(group, blocks))
+    kv_at = _kv_index(group, blocks, whole=False)
+    kv_tile = pl.BlockSpec((1, block_k, width), kv_at)
+    row_tile = pl.BlockSpec((g, 1, 8, block_q), lambda n, i: (n, i, 0, 0))
+    row_all = pl.BlockSpec((g, nq, 8, block_q), lambda n, j: (n, 0, 0, 0))
 
     smem_spec = _smem_spec()
     params = _vmem_params(cfg, Tq, Tk, q)
     dq_kernel = functools.partial(
-        _bwd_dq_kernel, scale=cfg.scale, causal=cfg.causal, block_q=block_q,
-        block_k=block_k, seq_k=Tk, dropout_p=cfg.dropout_p,
-        interpret=cfg.interpret, mask=cfg.mask, seq_q=Tq)
-    dq = pl.pallas_call(
+        _bwd_dq_kernel, heads=g, scale=cfg.scale, causal=cfg.causal,
+        block_q=block_q, block_k=block_k, seq_k=Tk,
+        dropout_p=cfg.dropout_p, interpret=cfg.interpret, mask=cfg.mask,
+        seq_q=Tq)
+    dq, delta4 = pl.pallas_call(
         dq_kernel,
-        grid=(B * H, nq),
-        in_specs=[
-            smem_spec,
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk_pad, D), kv_of),
-            pl.BlockSpec((1, Tk_pad, D), kv_of),
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, 8, block_q), lambda b, i: (b, i, 0, 0)),
-            pl.BlockSpec((1, 1, 8, block_q), lambda b, i: (b, i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Tq_pad, D), q.dtype),
+        grid=(B * H // g, nq),
+        in_specs=[smem_spec, q_tile, kv_all, kv_all, q_tile, q_tile,
+                  row_tile] + [row_tile] * len(dlse),
+        out_specs=[q_tile, row_tile],
+        out_shape=[jax.ShapeDtypeStruct(qf.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse4.shape, jnp.float32)],
         interpret=cfg.interpret,
         compiler_params=params,
         name="flash_bwd_dq",
-    )(seedf, qf, kf, vf, dof, lse4, delta4)
+    )(seedf, qf, kf, vf, dof, outf, lse4, *map(_widen, dlse))
 
     dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=cfg.scale, causal=cfg.causal,
+        _bwd_dkv_kernel, heads=g, scale=cfg.scale, causal=cfg.causal,
         block_q=block_q, block_k=block_k, seq_q=Tq, seq_k=Tk,
         dropout_p=cfg.dropout_p, interpret=cfg.interpret, mask=cfg.mask)
     if group == 1:
-        kv_tile = lambda b, j: (b, j, 0)  # noqa: E731
+        dkv_tile, dkv_shape = kv_tile, kf.shape
         dkv_dtypes = (k.dtype, v.dtype)
     else:
-        # one (dk, dv) a QUERY head, in float32, summed over the group
-        # below: the kernel keeps one head's Q and dO resident, not eight
-        kv_tile = lambda b, j: (b // group, j, 0)  # noqa: E731
+        # one (dk, dv) a QUERY head, in float32, a slab a member of the
+        # group, summed below (a sum of slabs, no relayout): the kernel
+        # keeps one head's Q and dO resident, not eight
+        dkv_tile = pl.BlockSpec(
+            (None, 1, block_k, width),
+            lambda n, j: (jax.lax.rem(n, group),) + kv_at(n, j))
+        dkv_shape = (group,) + kf.shape
         dkv_dtypes = (jnp.float32, jnp.float32)
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(B * H, nk),
-        in_specs=[
-            smem_spec,
-            pl.BlockSpec((1, Tq_pad, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, D), kv_tile),
-            pl.BlockSpec((1, block_k, D), kv_tile),
-            pl.BlockSpec((1, Tq_pad, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, nq, 8, block_q), lambda b, j: (b, 0, 0, 0)),
-            pl.BlockSpec((1, nq, 8, block_q), lambda b, j: (b, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tk_pad, D), dkv_dtypes[0]),
-            jax.ShapeDtypeStruct((B * H, Tk_pad, D), dkv_dtypes[1]),
-        ],
+        grid=(B * H // g, nk),
+        in_specs=[smem_spec, q_all, kv_tile, kv_tile, q_all, row_all,
+                  row_all],
+        out_specs=[dkv_tile, dkv_tile],
+        out_shape=[jax.ShapeDtypeStruct(dkv_shape, dkv_dtypes[0]),
+                   jax.ShapeDtypeStruct(dkv_shape, dkv_dtypes[1])],
         interpret=cfg.interpret,
         compiler_params=params,
         name="flash_bwd_dkv",
     )(seedf, qf, kf, vf, dof, lse4, delta4)
 
-    dq = dq.reshape(B, H, Tq_pad, D)[:, :, :Tq]
-    dk = dk.reshape(B, H, Tk_pad, D)[:, :, :Tk]
-    dv = dv.reshape(B, H, Tk_pad, D)[:, :, :Tk]
     if group > 1:
-        dk, dv = (a.reshape(B, H // group, group, Tk, D).sum(2).astype(
-            like.dtype) for a, like in ((dk, k), (dv, v)))
-    return dq, dk, dv
+        dk, dv = (a.sum(0).astype(like.dtype)
+                  for a, like in ((dk, k), (dv, v)))
+    return dq[:, :Tq].reshape(q.shape), dk[:, :Tk].reshape(k.shape), \
+        dv[:, :Tk].reshape(v.shape)
 
 
 def _forward_call(cfg, seeds, q, k, v):
@@ -832,20 +962,13 @@ def _forward_call(cfg, seeds, q, k, v):
 
 
 def _flash_backward(cfg, seeds, q, k, v, out, lse, do, dlse=None):
-    # Δ = rowsum(dO ∘ O) — one cheap fused XLA reduction, fed to both
-    # kernels
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                           # (B, H, Tq)
-    if dlse is not None:
-        # lse cotangent folds into the delta term: the softmax backward is
-        # ds = p·(dp − Δ) and ∂lse/∂s = p, so ds = p·(dp − (Δ − dlse)) —
-        # the kernels need no change to support flash_attention_lse
-        delta = delta - dlse.astype(jnp.float32)
-    pad_q = lse.shape[2] - delta.shape[2]
-    if pad_q:       # padded rows contribute zeros (their dO is padding too)
-        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q)))
-    return _over_rows(_backward_local, (4, 4, 4), cfg, seeds, q, k, v, do,
-                      lse, delta)
+    more = ()
+    if dlse is not None:        # lse's cotangent, padded like lse
+        pad_q = lse.shape[2] - dlse.shape[2]
+        more = (jnp.pad(dlse.astype(jnp.float32),
+                        ((0, 0), (0, 0), (0, pad_q))),)
+    return _over_rows(_backward_local, (4, 4, 4), cfg, seeds, q, k, v, out,
+                      do, lse, *more)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -889,88 +1012,119 @@ _TILES_NOTED = set()
 
 def _note_tiles(cfg, q, k):
     """One ``mx.attn.tiles`` instant a distinct call, written where the
-    call is traced: what the kernels will visit, and at which dtype their
-    products run."""
-    what = (q.shape[2], k.shape[2], cfg.block_q, cfg.block_k, cfg.causal,
+    call is traced: what the kernels will visit, at which dtype their
+    products run, and how they read the call's arrays (``layout`` ``rows``
+    with ``heads_per_step`` heads a grid step, or ``heads`` where the
+    shape fell back to a head-major copy)."""
+    heads = heads_per_step(q.shape[3], q.shape[2], k.shape[2])
+    what = (q.shape[1], k.shape[1], cfg.block_q, cfg.block_k, cfg.causal,
             cfg.mask)
-    dtype = str(q.dtype)
-    if what + (dtype,) in _TILES_NOTED:
+    how = (str(q.dtype), heads)
+    if what + how in _TILES_NOTED:
         return
-    _TILES_NOTED.add(what + (dtype,))
+    _TILES_NOTED.add(what + how)
     from .. import trace as _trace
 
     visited, whole, cut = tile_counts(*what)
     _trace.instant("mx.attn.tiles", args={
         "kind": rule_kind(cfg.causal, cfg.mask) or "none",
         "visited": visited, "whole": whole, "cut": cut,
-        "operand_dtype": dtype})
+        "operand_dtype": how[0], "heads_per_step": heads or 1,
+        "layout": "rows" if heads else "heads"})
 
 
 def _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
              dropout_p=0.0, mask=None):
-    """The static kernel configuration of one call: interpret-or-compile
-    resolved from the backend, the default softmax scale, and the
-    fast-memory check made before anything is traced."""
+    """The static kernel configuration of one call on ``(B, T, H, D)``
+    arrays: interpret-or-compile resolved from the backend, the default
+    softmax scale, and the fast-memory check made before anything is
+    traced."""
     interpret = _default_interpret() if interpret is None else interpret
     scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    _check_vmem(q, k.shape[2], block_q, block_k, interpret)
+    _check_vmem(q, k.shape[1], block_q, block_k, interpret)
     if mask is not None:
         if causal:
             raise MXNetError("flash_attention: causal= and mask= together")
-        want = q.shape[2] if mask.kind == "window" else 2 * mask.seq
-        if q.shape[2] != want or k.shape[2] != want:
+        want = q.shape[1] if mask.kind == "window" else 2 * mask.seq
+        if q.shape[1] != want or k.shape[1] != want:
             raise MXNetError(
                 "flash_attention: a %s mask needs %d query and key "
                 "positions, got %d and %d"
-                % (mask.kind, want, q.shape[2], k.shape[2]))
-    if q.shape[1] % k.shape[1]:
+                % (mask.kind, want, q.shape[1], k.shape[1]))
+    if q.shape[2] % k.shape[2]:
         raise MXNetError("flash_attention: %d query heads over %d KV heads"
-                         % (q.shape[1], k.shape[1]))
+                         % (q.shape[2], k.shape[2]))
     return _Cfg(bool(causal), float(scale), int(block_q), int(block_k),
                 bool(interpret), float(dropout_p), mask)
 
 
-def _flash_lse_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                    interpret):
-    cfg = _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret)
-    _note_tiles(cfg, q, k)
-    seeds = jnp.zeros(q.shape[:2], jnp.int32)
-    out, lse = _forward_call(cfg, seeds, q, k, v)
-    return out, lse[:, :, :q.shape[2]]
+def _kernel_views(*arrays):
+    """The call's ``(B, T, H, D)`` arrays as the kernels take them: as
+    they are where ``heads_per_step`` says rows, else head-major with
+    every head a batch row of one head, ``(B * H, T, 1, D)`` (the
+    transposes are plain JAX: autodiff returns the gradients the same
+    way)."""
+    q, k = arrays[:2]
+    if heads_per_step(q.shape[3], q.shape[2], k.shape[2]):
+        return arrays
+    return tuple(x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], 1,
+                                                 x.shape[3])
+                 for x in arrays)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _caller_view(out, B):
+    """A kernel view's result as the caller's ``(B, T, H, D)``."""
+    if out.shape[0] == B:
+        return out
+    return out.reshape((B, -1) + out.shape[1:2] + out.shape[3:]).transpose(
+        0, 2, 1, 3)
+
+
+def _no_seeds(q):
+    return jnp.zeros((q.shape[0], q.shape[2]), jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash_lse_core(cfg, q, k, v):
+    out, lse = _forward_call(cfg, _no_seeds(q), q, k, v)
+    return out, lse[:, :, :q.shape[1]]
+
+
+def _flash_lse_fwd(cfg, q, k, v):
+    outs = _flash_lse_core.fun(cfg, q, k, v)
+    return outs, (q, k, v) + outs
+
+
+def _flash_lse_bwd(cfg, res, cts):
+    q, k, v, out, lse = res
+    do, dlse = cts
+    Tq = q.shape[1]
+    bq = min(cfg.block_q, Tq)
+    pad_q = -(-Tq // bq) * bq - Tq
+    if pad_q:
+        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q)))
+    return _flash_backward(cfg, _no_seeds(q), q, k, v, out, lse, do,
+                           dlse=dlse)
+
+
+_flash_lse_core.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
 def flash_attention_lse(q, k, v, causal=False, sm_scale=None, block_q=512,
                         block_k=512, interpret=None):
     """Flash attention returning (out, logsumexp) — the building block for
     ring/context-parallel composition (parallel/ring.py): partial results
     from different K/V shards merge exactly via their lse.  The lse
-    cotangent is honored (it folds into the backward's delta term)."""
-    return _flash_lse_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                           interpret)
+    cotangent is honored (it folds into the backward's delta term).
 
-
-def _flash_lse_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    outs = _flash_lse_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                           interpret)
-    return outs, (q, k, v) + outs
-
-
-def _flash_lse_bwd(causal, sm_scale, block_q, block_k, interpret, res,
-                   cts):
-    q, k, v, out, lse = res
-    do, dlse = cts
+    ``q`` is ``(B, Tq, H, D)`` and ``k``, ``v`` ``(B, Tk, Hkv, D)``, the
+    layout ``flash_attention`` takes; ``out`` comes back like ``q`` and the
+    logsumexp head-major, ``(B, H, Tq)``, as the kernels write it."""
     cfg = _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret)
-    Tq = q.shape[2]
-    bq = min(block_q, Tq)
-    pad_q = -(-Tq // bq) * bq - Tq
-    if pad_q:
-        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q)))
-    seeds = jnp.zeros(q.shape[:2], jnp.int32)
-    return _flash_backward(cfg, seeds, q, k, v, out, lse, do, dlse=dlse)
-
-
-flash_attention_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+    _note_tiles(cfg, q, k)
+    B, Tq, H, _ = q.shape
+    out, lse = _flash_lse_core(cfg, *_kernel_views(q, k, v))
+    return _caller_view(out, B), lse.reshape(B, H, Tq)
 
 
 def _bh_seeds(dropout_key, B, H):
@@ -995,7 +1149,18 @@ def _bh_seeds(dropout_key, B, H):
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
                     block_k=None, interpret=None, dropout_p=0.0,
                     dropout_key=None, mask=None):
-    """Flash attention, (B, H, T, D) layout.
+    """Flash attention on the projections' own layout: ``q`` is ``(B, Tq,
+    H, D)``, ``k`` and ``v`` ``(B, Tk, Hkv, D)`` (each a free reshape of a
+    projection's ``(B, T, heads * D)`` rows), and the result comes back
+    like ``q``.  Where heads are narrower than the 128 lanes (``D`` = 64 or
+    32) the kernels' blocks index those rows where they lie, ``128 // D``
+    heads a grid step (``heads_per_step``), so no head-major copy is made
+    on the way in or out, forward or backward.  Every other shape (heads
+    of a multiple of 128, where XLA makes the transpose the layout of the
+    projection's product; ``D`` = 80, an odd head count of 64, 64 with
+    grouped KV heads, where it is a copy) is transposed head-major HERE
+    and runs through the same kernels with every head a batch row of one
+    head; ``mx.attn.tiles`` says which (``layout``, ``heads_per_step``).
 
     ``k``/``v`` may hold fewer heads than ``q`` (``H`` a multiple of their
     count): each KV head then serves a group of consecutive query heads,
@@ -1018,7 +1183,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
     its interleaved_matmul kernels are fwd-only, transformer.cc:650-826).
     Attention-probability dropout runs IN-kernel from the TPU PRNG: the
     per-tile mask is regenerated — never stored — in fwd, dq and dkv
-    passes, seeded by (key ⊕ batch·head, q-block·k-block).
+    passes, seeded by (key ⊕ batch·head, q-block·k-block), a head's mask
+    the same however many heads share its grid step.
 
     Traced inside a program jitted over a mesh, the kernels need the
     engine's ``mesh_rows`` declaration (Mosaic kernels cannot be
@@ -1032,7 +1198,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
     cfg = _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
                    dropout_p, mask)
     _note_tiles(cfg, q, k)
-    B, H = q.shape[:2]
+    B, H = q.shape[0], q.shape[2]
     if dropout_p > 0.0:
         if dropout_key is None:
             raise ValueError("flash_attention: dropout_p > 0 requires "
@@ -1040,7 +1206,9 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
         seeds = _bh_seeds(dropout_key, B, H)
     else:
         seeds = jnp.zeros((B, H), jnp.int32)
-    return _flash_core(cfg, seeds, q, k, v)
+    q, k, v = _kernel_views(q, k, v)
+    out = _flash_core(cfg, seeds.reshape(q.shape[0], q.shape[2]), q, k, v)
+    return _caller_view(out, B)
 
 
 def _default_interpret():
@@ -1071,22 +1239,25 @@ def flash_vmem_bytes(seq_q, seq_k, head_dim, itemsize,
     an upper estimate, checked against the v5e compiler in
     tests/python/unittest/test_chip_compile.py.
 
-    The forward and dq kernels keep one head's whole K and V resident
-    (``(Tk, D)`` blocks), the dkv kernel one head's whole Q and dO plus
-    the lse/Δ rows in their 8-sublane layout; Pallas double-buffers every
-    operand block, and a row of D < 128 still fills a 128-lane tile.  On
-    top of the operands comes the working set of one (block_q, block_k)
-    tile: scores, probabilities, mask and their products in float32, and
-    the copies of p and ds at the operands' dtype that enter the second
-    products (the compiler lets them share room: the count of six float32
-    tiles still covers its need, which the chip-compile tests check with
-    dropout on)."""
+    The forward and dq kernels keep a grid step's whole K and V resident
+    (``(Tk, heads * D)`` blocks: one head, or the ``128 // D`` heads that
+    share 128 lanes, ``heads_per_step``), the dkv kernel its whole Q and
+    dO plus the lse/Δ rows of each of its heads in their 8-sublane layout;
+    Pallas double-buffers every operand block, and a row of D < 128 fills
+    a 128-lane tile whether one head lies in it (the head-major view) or
+    several.  On top of the operands comes the working set of one
+    (block_q, block_k) tile, one head's at a time: scores, probabilities,
+    mask and their products in float32, and the copies of p and ds at the
+    operands' dtype that enter the second products (the compiler lets them
+    share room: the count of six float32 tiles still covers its need,
+    which the chip-compile tests check with dropout on)."""
     lanes = -(-head_dim // 128) * 128
+    heads = lanes // head_dim if lanes % head_dim == 0 else 1
     block_q, block_k = min(block_q, seq_q), min(block_k, seq_k)
     pad = lambda t, b: -(-t // b) * b  # noqa: E731
     row = lanes * itemsize
     resident_kv = 2 * 2 * pad(seq_k, block_k) * row
-    resident_q = 2 * 2 * pad(seq_q, block_q) * (row + 8 * 4)
+    resident_q = 2 * 2 * pad(seq_q, block_q) * (row + heads * 8 * 4)
     blocks = 2 * 4 * max(block_q, block_k) * row
     tile = 6 * block_q * block_k * 4 + 4 * (block_q + block_k) * lanes * 4
     return max(resident_kv, resident_q) + blocks + tile
@@ -1094,7 +1265,7 @@ def flash_vmem_bytes(seq_q, seq_k, head_dim, itemsize,
 
 def _vmem_params(cfg, seq_q, seq_k, q):
     """Ask the compiler for the scoped VMEM the shape needs where the
-    default would not do (long T: a head's whole K/V is resident)."""
+    default would not do (long T: a grid step's whole K/V is resident)."""
     from jax.experimental.pallas import tpu as pltpu
 
     need = flash_vmem_bytes(seq_q, seq_k, q.shape[-1], q.dtype.itemsize,
@@ -1109,7 +1280,7 @@ def _check_vmem(q, seq_k, block_q, block_k, interpret):
     limit to exceed; the interpreter has no VMEM."""
     if interpret:
         return
-    need = flash_vmem_bytes(q.shape[2], seq_k, q.shape[3],
+    need = flash_vmem_bytes(q.shape[1], seq_k, q.shape[3],
                             q.dtype.itemsize, block_q, block_k)
     if need > VMEM_BUDGET_BYTES:
         raise MXNetError(
@@ -1117,7 +1288,7 @@ def _check_vmem(q, seq_k, block_q, block_k, interpret):
             "(the kernels keep a head's whole K/V resident), over the "
             "%d MiB the kernels may take; use impl='flash' (blockwise) or "
             "shorter sequences per call (ring_attention shards T)"
-            % (q.shape[2], seq_k, q.shape[3], q.dtype, need >> 20,
+            % (q.shape[1], seq_k, q.shape[3], q.dtype, need >> 20,
                VMEM_BUDGET_BYTES >> 20))
 
 

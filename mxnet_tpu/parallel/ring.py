@@ -53,7 +53,15 @@ def _ring_attn_sharded(q, k, v, axis_name, causal, scale, impl="dense",
     impl='flash' runs the Pallas flash kernel per hop and merges the
     normalized partials via their logsumexp (exact: softmax is associative
     under lse reweighting) — O(T_loc·D) memory per hop, MXU matmuls
-    throughout, the ring-of-flash-blocks design for long context."""
+    throughout, the ring-of-flash-blocks design for long context.  The
+    kernels take ``(B, T, H, D)``; this function's shards are head-major,
+    so its transposes stand at ITS boundary, once a call and not once a
+    hop: q, k and v go to rows before the scan, K/V travel the ring as
+    rows (``ppermute`` does not care), the accumulator is kept as rows and
+    the result goes back head-major after the last hop.  No benchmark
+    cell runs the ring, so no cell pays those four copies; a caller that
+    holds rows already should get a rows entry point before it gets a
+    cell."""
     axis_size = lax.psum(1, axis_name)
     rank = lax.axis_index(axis_name)
     B, H, T, D = q.shape
@@ -72,6 +80,7 @@ def _ring_attn_sharded(q, k, v, axis_name, causal, scale, impl="dense",
         from ..ops.pallas_attention import flash_attention_lse
 
         bq = min(block, T)
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))  # rows
 
         def flash_hop(k_cur, v_cur, kv_rank):
             def hop(causal_flag):
@@ -83,7 +92,7 @@ def _ring_attn_sharded(q, k, v, axis_name, causal, scale, impl="dense",
                 return hop(False)
 
             def skip(_):
-                return (jnp.zeros((B, H, T, D), jnp.float32),
+                return (jnp.zeros((B, T, H, D), jnp.float32),
                         jnp.full((B, H, T), -jnp.inf, jnp.float32))
 
             # diagonal hop: in-block causal; earlier ranks: fully visible;
@@ -98,9 +107,10 @@ def _ring_attn_sharded(q, k, v, axis_name, causal, scale, impl="dense",
             kv_rank = (rank - i) % axis_size
             o_blk, lse_blk = flash_hop(k_cur, v_cur, kv_rank)
             lse_new = jnp.logaddexp(lse_acc, lse_blk)
-            w_a = jnp.exp(lse_acc - lse_new)
-            w_b = jnp.exp(lse_blk - lse_new)
-            o_acc = o_acc * w_a[..., None] + o_blk * w_b[..., None]
+            # lse is head-major (B, H, T), the partials rows (B, T, H, D)
+            w_a, w_b = (jnp.exp(x - lse_new).transpose(0, 2, 1)[..., None]
+                        for x in (lse_acc, lse_blk))
+            o_acc = o_acc * w_a + o_blk * w_b
             perm = [(j, (j + 1) % axis_size) for j in range(axis_size)]
             k_nxt = lax.ppermute(k_cur, axis_name, perm)
             v_nxt = lax.ppermute(v_cur, axis_name, perm)
@@ -108,10 +118,10 @@ def _ring_attn_sharded(q, k, v, axis_name, causal, scale, impl="dense",
 
         zero_q = (q * 0).astype(jnp.float32)
         o0 = zero_q
-        lse0 = zero_q[..., 0] - jnp.inf
+        lse0 = zero_q[..., 0].transpose(0, 2, 1) - jnp.inf
         (o, _lse, _, _), _ = lax.scan(step_flash, (o0, lse0, k, v),
                                       jnp.arange(axis_size))
-        return o.astype(q.dtype)
+        return o.astype(q.dtype).transpose(0, 2, 1, 3)
 
     def step(carry, i):
         o_acc, m_acc, l_acc, k_cur, v_cur = carry

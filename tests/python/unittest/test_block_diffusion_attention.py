@@ -29,20 +29,21 @@ def _rule(seq, block):
 
 
 def _dense(q, k, v, allowed):
-    group = q.shape[1] // k.shape[1]
-    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    """The oracle on the kernels' own layout, (B, T, H, D)."""
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
     s = jnp.where(allowed, s, -jnp.inf)
-    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
 
 
 def _qkv(seq, heads, kv_heads, d=16, batch=2, seed=0):
     k = jax.random.split(jax.random.PRNGKey(seed), 4)
     t = 2 * seq
-    return (jax.random.normal(k[0], (batch, heads, t, d)),
-            jax.random.normal(k[1], (batch, kv_heads, t, d)),
-            jax.random.normal(k[2], (batch, kv_heads, t, d)),
-            jax.random.normal(k[3], (batch, heads, t, d)))
+    return (jax.random.normal(k[0], (batch, t, heads, d)),
+            jax.random.normal(k[1], (batch, t, kv_heads, d)),
+            jax.random.normal(k[2], (batch, t, kv_heads, d)),
+            jax.random.normal(k[3], (batch, t, heads, d)))
 
 
 @pytest.mark.parametrize("seq,block", [(16, 4), (12, 2), (20, 4)])
@@ -145,8 +146,8 @@ def test_the_cells_call_visits_80_tiles_a_head_56_whole_and_24_cut(
     assert pa.tile_counts(8192, 8192, 512, 512, False, mask) == (80, 56, 24)
     assert pa.tile_counts(512, 512, 512, 512) == (1, 1, 0)      # BERT's
     monkeypatch.setattr(pa, "_TILES_NOTED", set())
-    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16)
 
     def noted():
         return [e["args"] for e in trace.events()
@@ -159,7 +160,7 @@ def test_the_cells_call_visits_80_tiles_a_head_56_whole_and_24_cut(
                        q, kv, kv)
     assert noted()[before:] == [{
         "kind": "block_diffusion", "visited": 80, "whole": 56, "cut": 24,
-        "operand_dtype": "bfloat16"}]
+        "operand_dtype": "bfloat16", "heads_per_step": 1, "layout": "heads"}]
 
 
 @pytest.mark.parametrize("seq,block,bq,bk,heads,kv_heads", [
@@ -192,8 +193,8 @@ def test_grouped_kv_heads_equal_repeated_k_and_v():
             return pa.flash_attention(q, k, v, block_q=16, block_k=16, **kw)
 
         def repeated(q, k, v):
-            return pa.flash_attention(q, jnp.repeat(k, 4, 1),
-                                      jnp.repeat(v, 4, 1), block_q=16,
+            return pa.flash_attention(q, jnp.repeat(k, 4, 2),
+                                      jnp.repeat(v, 4, 2), block_q=16,
                                       block_k=16, **kw)
 
         np.testing.assert_allclose(grouped(q, k, v), repeated(q, k, v),
@@ -228,9 +229,9 @@ def test_multi_head_attention_takes_the_rule_to_the_kernels_or_dense():
                                 num_kv_heads=2,
                                 mask=nd.NDArray(jnp.ones((256, 256), bool)))
     with pytest.raises(MXNetError, match="positions"):
-        pa.flash_attention(q._data.reshape(1, 4, 256, 16)[:, :, :128],
-                           kk._data.reshape(1, 2, 256, 16),
-                           v._data.reshape(1, 2, 256, 16), mask=mask)
+        pa.flash_attention(q._data.reshape(1, 256, 4, 16)[:, :128],
+                           kk._data.reshape(1, 256, 2, 16),
+                           v._data.reshape(1, 256, 2, 16), mask=mask)
 
 
 def test_rotary_and_qk_norm_against_a_hand_written_form():

@@ -69,10 +69,12 @@ def _compile(fn, shape, dtype, sharding, key_sharding=None):
 
 
 @pytest.mark.parametrize("shape,causal,dropout_p", [
-    ((16, 12, 512, 64), False, 0.0),     # BERT-base, batch 16
-    ((16, 12, 512, 64), False, 0.1),     # ... with in-kernel dropout
-    ((4, 12, 2048, 64), True, 0.0),
-    ((1, 12, 8192, 64), True, 0.0),      # asks for more than default VMEM
+    ((16, 512, 12, 64), False, 0.0),     # BERT-base, batch 16
+    ((16, 512, 12, 64), False, 0.1),     # ... with in-kernel dropout
+    ((4, 2048, 12, 64), True, 0.0),
+    ((1, 8192, 12, 64), True, 0.0),      # asks for more than default VMEM
+    ((4, 1024, 8, 32), True, 0.1),       # four heads a grid step
+    ((2, 1024, 5, 80), True, 0.0),       # no rows layout: head-major view
 ])
 def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, causal, dropout_p):
     text = _compile(_fwd_bwd(causal, dropout_p), shape, jnp.bfloat16,
@@ -99,24 +101,28 @@ def mosaic_modules(monkeypatch):
 
 @pytest.mark.parametrize("cell", ["bert_base_t512", "sdar_30b_a3b_bd4k"])
 def test_the_cells_kernels_feed_the_mxu_bf16(topo, mosaic_modules, cell):
-    """Both cells' shapes (``bf16[768,512,64]``; 32 query heads of 128 over
-    4 KV heads, 8,192 positions under the block-diffusion mask): every
-    ``tpu.matmul`` of the three kernels takes bf16 operands and accumulates
-    in float32, and no block of Q, K, V or dO is widened (no ``extf`` at
-    all: the only casts are p's and ds's ``truncf``)."""
+    """Both cells' shapes (``bf16[64,512,768]`` rows, two heads of 64 a
+    grid step; 32 query heads of 128 over 4 KV heads head-major, 8,192
+    positions under the block-diffusion mask): every ``tpu.matmul`` of the
+    three kernels
+    takes bf16 operands and accumulates in float32, and no block of Q, K or
+    V is widened: the forward and dkv kernels hold no ``extf`` at all (their
+    only casts are p's and ds's ``truncf``), the dq kernel two a head, dO's
+    and O's for the float32 products of Δ = rowsum(dO ∘ O)."""
     one = SingleDeviceSharding(topo.devices[0])
     if cell == "bert_base_t512":
-        q = kv = jax.ShapeDtypeStruct((64, 12, 512, 64), jnp.bfloat16,
+        q = kv = jax.ShapeDtypeStruct((64, 512, 12, 64), jnp.bfloat16,
                                       sharding=one)
-        kw, products = {}, (2, 3, 4)
+        # the body is traced once a head of the grid step's two
+        kw, products, heads = {}, (2 * 2, 3 * 2, 4 * 2), 2
     else:
-        q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+        q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
                                  sharding=one)
-        kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+        kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16,
                                   sharding=one)
         # the body is traced once a class of tiles: 3, 3 and 4 ranges
-        kw, products = {"mask": pa.block_diffusion_mask(4096, 4)}, \
-            (2 * 3, 3 * 3, 4 * 4)
+        kw, products, heads = {"mask": pa.block_diffusion_mask(4096, 4)}, \
+            (2 * 3, 3 * 3, 4 * 4), 1
 
     def f(q, k, v):
         def loss(q, k, v):
@@ -127,10 +133,11 @@ def test_the_cells_kernels_feed_the_mxu_bf16(topo, mosaic_modules, cell):
 
     text = jax.jit(f).lower(q, kv, kv).compile().as_text()
     assert text.count("tpu_custom_call") == 3
-    if cell == "bert_base_t512":
-        assert "bf16[768,512,64]" in text
+    if cell == "bert_base_t512":    # the rows as they lie
+        assert "bf16[64,512,768]" in text and " transpose(" not in text
     assert len(mosaic_modules) == 3             # forward, dq, dkv
-    for module, n in zip(mosaic_modules, products):
+    for module, n, widened in zip(mosaic_modules, products,
+                                  (0, 2 * heads, 0)):
         matmuls = re.findall(
             r"tpu\.matmul.*?: (vector<[^>]*>), (vector<[^>]*>), "
             r"(vector<[^>]*>)", module)
@@ -138,10 +145,13 @@ def test_the_cells_kernels_feed_the_mxu_bf16(topo, mosaic_modules, cell):
         for lhs, rhs, acc in matmuls:
             assert lhs.endswith("xbf16>") and rhs.endswith("xbf16>") \
                 and acc.endswith("xf32>"), (lhs, rhs, acc)
-        assert "arith.extf" not in module
-    if cell == "bert_base_t512":    # no mask, no padding: no index, no select
-        for module in mosaic_modules:
-            assert "tpu.iota" not in module and "arith.select" not in module
+        assert module.count("arith.extf") == widened
+    if cell == "sdar_30b_a3b_bd4k":     # one head a step: no lane is masked
+        return
+    # no mask, no padding: the only index is the lane's, the only selects
+    # the heads' lanes (a left operand's, a result's), none on a tile
+    for module in mosaic_modules:
+        assert "512x512xi1" not in module
 
 
 def test_block_diffusion_kernels_compile_for_v5e_with_grouped_kv(topo):
@@ -158,11 +168,12 @@ def test_block_diffusion_kernels_compile_for_v5e_with_grouped_kv(topo):
 
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=one)
-    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16, sharding=one)
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16, sharding=one)
     text = jax.jit(f).lower(q, kv, kv).compile().as_text()
     assert text.count("tpu_custom_call") == 3
     assert "8192,8192" not in text                  # no score matrix
+    # the head-major view: every head a batch row
     assert "bf16[32,8192,128]" in text and "bf16[4,8192,128]" in text
 
 
@@ -185,12 +196,12 @@ def test_laguna_calls_compile_for_v5e_with_grouped_kv(topo, mosaic_modules,
 
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    q = jax.ShapeDtypeStruct((1, heads, 8192, 128), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
                              sharding=one)
-    kv = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=one)
     text = jax.jit(f).lower(q, kv, kv).compile().as_text()
     assert text.count("tpu_custom_call") == 3
-    assert "8192,8192" not in text                  # no score matrix
+    assert "%d,8192,8192" % heads not in text       # no score matrix
     assert "bf16[%d,8192,128]" % heads in text and "bf16[8,8192,128]" in text
     assert len(mosaic_modules) == 3             # forward, dq, dkv
     for module, n in zip(mosaic_modules, (2, 3, 4)):
@@ -201,7 +212,7 @@ def test_laguna_calls_compile_for_v5e_with_grouped_kv(topo, mosaic_modules,
         for lhs, rhs, acc in matmuls:
             assert lhs.endswith("xbf16>") and rhs.endswith("xbf16>") \
                 and acc.endswith("xf32>"), (lhs, rhs, acc)
-        assert "arith.extf" not in module
+        assert module.count("arith.extf") == (2 if n == 3 else 0)  # Δ's
 
 
 @pytest.mark.parametrize("dropout_p", [0.0, 0.1])
@@ -209,13 +220,13 @@ def test_flash_lowers_on_a_dp_mesh_without_gathering_the_batch(topo,
                                                                dropout_p):
     mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
     with pa.mesh_rows(mesh, ("dp",)):
-        text = _compile(_fwd_bwd(False, dropout_p), (16, 12, 512, 64),
+        text = _compile(_fwd_bwd(False, dropout_p), (16, 512, 12, 64),
                         jnp.bfloat16, NamedSharding(mesh, P("dp")),
                         NamedSharding(mesh, P()))
     assert text.count("tpu_custom_call") == 3
     assert "all-gather" not in text
-    # every kernel works on a quarter of the batch: 4 * 12 rows of (T, D)
-    assert "bf16[48,512,64]" in text and "bf16[192,512,64]" not in text
+    # every kernel works on a quarter of the batch: 4 rows of (T, H * D)
+    assert "bf16[4,512,768]" in text and "bf16[16,512,768]" not in text
 
 
 def test_flash_without_mesh_rows_cannot_lower_sharded(topo):
@@ -223,23 +234,23 @@ def test_flash_without_mesh_rows_cannot_lower_sharded(topo):
     partitioning rule of their own."""
     mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
     with pytest.raises(Exception, match="shard_map"):
-        _compile(_fwd_bwd(False), (16, 12, 512, 64), jnp.bfloat16,
+        _compile(_fwd_bwd(False), (16, 512, 12, 64), jnp.bfloat16,
                  NamedSharding(mesh, P("dp")), NamedSharding(mesh, P()))
 
 
 @pytest.mark.parametrize("shape,dtype,block,dropout_p", [
-    ((1, 2, 24576, 128), jnp.float32, 1024, 0.0),  # 83 MiB by the estimate
-    ((1, 2, 65536, 64), jnp.bfloat16, 512, 0.0),   # 81 MiB
-    ((1, 2, 6144, 256), jnp.float32, 256, 0.0),    # just over the default
+    ((1, 24576, 2, 128), jnp.float32, 1024, 0.0),  # 83 MiB by the estimate
+    ((1, 65536, 2, 64), jnp.bfloat16, 512, 0.0),   # 89 MiB, two heads a step
+    ((1, 6144, 2, 256), jnp.float32, 256, 0.0),    # just over the default
     # bf16 copies of p and ds beside the float32 tile, and the keep mask
-    ((1, 2, 65536, 64), jnp.bfloat16, 512, 0.1),
-    ((1, 2, 16384, 128), jnp.bfloat16, 1024, 0.1),
+    ((1, 65536, 2, 64), jnp.bfloat16, 512, 0.1),
+    ((1, 16384, 2, 128), jnp.bfloat16, 1024, 0.1),
 ])
 def test_envelope_estimate_covers_the_compilers_need(topo, shape, dtype,
                                                      block, dropout_p):
     """Inside the envelope the kernels ask for ``flash_vmem_bytes`` of
     VMEM, and that is enough for the compiler at the far end of it."""
-    need = pa.flash_vmem_bytes(shape[2], shape[2], shape[3],
+    need = pa.flash_vmem_bytes(shape[1], shape[1], shape[3],
                                jnp.dtype(dtype).itemsize, block, block)
     assert pa.VMEM_DEFAULT_BYTES < need <= pa.VMEM_BUDGET_BYTES
     text = _compile(_fwd_bwd(True, dropout_p, block_q=block, block_k=block),
@@ -248,7 +259,7 @@ def test_envelope_estimate_covers_the_compilers_need(topo, shape, dtype,
 
 
 def test_outside_the_envelope_is_the_repos_own_error(topo):
-    shape = (1, 2, 65536, 128)
+    shape = (1, 65536, 2, 128)
     assert pa.flash_vmem_bytes(65536, 65536, 128, 4) > pa.VMEM_BUDGET_BYTES
     with pytest.raises(MXNetError, match="VMEM"):
         _compile(_fwd_bwd(True), shape, jnp.float32,
@@ -256,3 +267,50 @@ def test_outside_the_envelope_is_the_repos_own_error(topo):
     # impl="auto" never sends such a shape to the kernels
     assert not pa.use_flash(65536, 65536, 128, False, 4)
     assert pa.use_flash(8192, 8192, 128, False, 4)
+
+
+@pytest.mark.parametrize("cell", ["bert_base_t512", "laguna_xs2_t8k"])
+def test_no_copy_between_a_projection_and_the_kernels(topo, cell):
+    """Projections, ``multi_head_attention``, output projection, forward and
+    gradient, as one program.  Heads of 64 reach the kernels as the rows
+    the products wrote: nothing as large as Q is copied or transposed on
+    the way.  Heads of 128 reach them head-major, which XLA makes the
+    LAYOUT of the three products (``{3,1,2,0}`` of ``(B, T, H, D)``): of
+    the eight transposes in the jaxpr two are left as copies, the
+    attention output's on its way into the output projection and its
+    cotangent's on the way back (as before the rows layout; PERF.md
+    section 6, PR 33)."""
+    from mxnet_tpu.ops.nn import multi_head_attention
+
+    B, T, H, Hkv, D, kw = (64, 512, 12, 12, 64, {}) \
+        if cell == "bert_base_t512" \
+        else (1, 8192, 64, 8, 128, {"mask": pa.window_mask(512)})
+    units = 768 if cell == "bert_base_t512" else 2048
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def f(x, wq, wk, wv, wo):
+        def loss(x, wq, wk, wv, wo):
+            out = multi_head_attention.fn(
+                x @ wq, x @ wk, x @ wv, num_heads=H, num_kv_heads=Hkv,
+                impl="pallas", **kw)
+            return ((out @ wo).astype(jnp.float32) ** 2).sum()
+
+        return jax.value_and_grad(loss, (0, 1, 2, 3, 4))(x, wq, wk, wv, wo)
+
+    arg = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.bfloat16, sharding=one)
+    interpret = pa._default_interpret
+    pa._default_interpret = lambda: False       # compile, on this CPU host
+    try:
+        text = jax.jit(f).lower(
+            arg(B, T, units), arg(units, H * D), arg(units, Hkv * D),
+            arg(units, Hkv * D), arg(H * D, units)).compile().as_text()
+    finally:
+        pa._default_interpret = interpret
+    assert text.count("tpu_custom_call") == 3
+    moved = re.findall(
+        r"= bf16\[(?:%d,%d,%d|%d,%d,%d,%d)\]\S* (?:copy|transpose)\("
+        % (B, T, H * D, B, T, H, D), text)
+    assert len(moved) <= (0 if D == 64 else 2), moved
+    if D == 128:
+        assert "[1,8192,64,128]{3,1,2,0" in text    # a product, head-major

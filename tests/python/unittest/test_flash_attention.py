@@ -15,20 +15,21 @@ B, H, D = 2, 2, 32
 
 
 def _dense(q, k, v, causal, scale=None):
-    T, Tk = q.shape[2], k.shape[2]
+    """The oracle on the kernels' own layout, (B, T, H, D)."""
+    T, Tk = q.shape[1], k.shape[1]
     scale = scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
         m = jnp.tril(jnp.ones((T, Tk), bool))
         s = jnp.where(m, s, -1e30)
     w = jax.nn.softmax(s, -1)
-    return jnp.einsum("bhqk,bhkd->bhqd", w, v.astype(jnp.float32)).astype(
+    return jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32)).astype(
         q.dtype)
 
 
 def _rand(T, seed=0):
     rs = np.random.RandomState(seed)
-    mk = lambda: jnp.asarray(rs.randn(B, H, T, D).astype(np.float32))  # noqa
+    mk = lambda: jnp.asarray(rs.randn(B, T, H, D).astype(np.float32))  # noqa
     return mk(), mk(), mk()
 
 
@@ -37,7 +38,7 @@ def _rand(T, seed=0):
 def test_flash_backward_matches_dense(causal, T):
     q, k, v = _rand(T)
     g = jnp.asarray(np.random.RandomState(1)
-                    .randn(B, H, T, D).astype(np.float32))
+                    .randn(B, T, H, D).astype(np.float32))
 
     def loss_flash(q, k, v):
         return (pa.flash_attention(q, k, v, causal=causal, block_q=64,
@@ -116,7 +117,9 @@ def test_flash_dropout_gradient_finite_difference():
 def test_flash_vs_blockwise_same_math_no_dropout():
     q, k, v = _rand(160, seed=7)
     a = pa.flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
-    b = pa.blockwise_attention(q, k, v, causal=True, block_k=64)
+    b = pa.blockwise_attention(          # the scan runs head-major
+        *(x.transpose(0, 2, 1, 3) for x in (q, k, v)), causal=True,
+        block_k=64).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)
 
@@ -158,32 +161,35 @@ def test_flash_attention_lse_matches_dense_oracle():
     merge exactly (the ring-of-flash-blocks invariant)."""
     q, k, v = _rand(96, seed=9)
     out, lse = pa.flash_attention_lse(q, k, v, block_q=32, block_k=32)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
     np.testing.assert_allclose(
         np.asarray(lse), np.asarray(jax.scipy.special.logsumexp(s, -1)),
         rtol=1e-5, atol=1e-6)
     # split-KV merge identity
-    o1, l1 = pa.flash_attention_lse(q, k[:, :, :48], v[:, :, :48],
+    o1, l1 = pa.flash_attention_lse(q, k[:, :48], v[:, :48],
                                     block_q=32, block_k=16)
-    o2, l2 = pa.flash_attention_lse(q, k[:, :, 48:], v[:, :, 48:],
+    o2, l2 = pa.flash_attention_lse(q, k[:, 48:], v[:, 48:],
                                     block_q=32, block_k=16)
     lm = jnp.logaddexp(l1, l2)
-    om = o1 * jnp.exp(l1 - lm)[..., None] + o2 * jnp.exp(l2 - lm)[..., None]
+    # out is (B, T, H, D), lse head-major (B, H, T)
+    w1, w2 = (jnp.exp(l - lm).transpose(0, 2, 1)[..., None]
+              for l in (l1, l2))
+    om = o1 * w1 + o2 * w2
     np.testing.assert_allclose(np.asarray(om), np.asarray(out),
                                rtol=1e-5, atol=1e-6)
     # full grads incl. the lse cotangent, vs a dense oracle
     g = jnp.asarray(np.random.RandomState(1)
                     .randn(*q.shape).astype(np.float32))
     h = jnp.asarray(np.random.RandomState(2)
-                    .randn(*q.shape[:3]).astype(np.float32))
+                    .randn(B, H, q.shape[1]).astype(np.float32))
 
     def loss(q_, k_, v_):
         o, l = pa.flash_attention_lse(q_, k_, v_, block_q=32, block_k=32)
         return (o * g).sum() + (l * h).sum()
 
     def loss_ref(q_, k_, v_):
-        s_ = jnp.einsum("bhqd,bhkd->bhqk", q_, k_) / np.sqrt(D)
-        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s_, -1), v_)
+        s_ = jnp.einsum("bqhd,bkhd->bhqk", q_, k_) / np.sqrt(D)
+        o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s_, -1), v_)
         return (o * g).sum() + (jax.scipy.special.logsumexp(s_, -1)
                                 * h).sum()
 
@@ -204,7 +210,7 @@ def test_flash_under_mesh_rows_matches_unsharded(dropout_p):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     rs = np.random.RandomState(3)
-    q, k, v, g = (jnp.asarray(rs.randn(4, 2, 128, D).astype(np.float32))
+    q, k, v, g = (jnp.asarray(rs.randn(4, 128, 2, D).astype(np.float32))
                   for _ in range(4))
     key = jax.random.PRNGKey(5)
 
@@ -295,10 +301,10 @@ _CALLS = {      # (Tq, Tk, query heads, KV heads, keywords)
 
 def _all_four(tq, tk, heads, kv_heads, kw, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(ks[0], (2, heads, tq, 16)).astype(dtype)
-    k = jax.random.normal(ks[1], (2, kv_heads, tk, 16)).astype(dtype)
-    v = jax.random.normal(ks[2], (2, kv_heads, tk, 16)).astype(dtype)
-    w = jax.random.normal(ks[3], (2, heads, tq, 16))
+    q = jax.random.normal(ks[0], (2, tq, heads, 16)).astype(dtype)
+    k = jax.random.normal(ks[1], (2, tk, kv_heads, 16)).astype(dtype)
+    v = jax.random.normal(ks[2], (2, tk, kv_heads, 16)).astype(dtype)
+    w = jax.random.normal(ks[3], (2, tq, heads, 16))
     kw = dict({"block_q": 32, "block_k": 32}, **kw)
     if kw.get("dropout_p"):
         kw["dropout_key"] = jax.random.PRNGKey(5)
@@ -351,7 +357,7 @@ def test_a_float32_call_keeps_float32_operands_in_every_product(kw):
     products, and no cast of p or ds (no float is converted at all)."""
     kw = dict(kw, **({"dropout_key": jax.random.PRNGKey(0)}
                      if "dropout_p" in kw else {}))
-    x = jnp.ones((1, 2, 128, 16), jnp.float32)
+    x = jnp.ones((1, 128, 2, 16), jnp.float32)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: pa.flash_attention(q, k, v, block_q=32, block_k=32,
                                            **kw).sum(), (0, 1, 2)))(x, x, x)
@@ -368,7 +374,7 @@ def test_a_float32_call_keeps_float32_operands_in_every_product(kw):
 
 
 def test_a_bf16_call_feeds_the_products_bf16_and_accumulates_in_float32():
-    x = jnp.ones((1, 2, 128, 16), jnp.bfloat16)
+    x = jnp.ones((1, 128, 2, 16), jnp.bfloat16)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: pa.flash_attention(
             q, k, v, block_q=32, block_k=32).astype(jnp.float32).sum(),
@@ -456,7 +462,9 @@ def test_blocks_left_out_are_the_module_constants(case):
     blocks = {"block_k": 256} if call is pa.blockwise_attention else \
         {"block_q": 512, "block_k": 512}
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    q, k, v = (jax.random.normal(key, (1, 1, T, 16)) for key in ks)
+    shape = (1, 1, T, 16) if call is pa.blockwise_attention \
+        else (1, T, 1, 16)          # the scan runs head-major
+    q, k, v = (jax.random.normal(key, shape) for key in ks)
     got = jax.tree_util.tree_leaves(call(q, k, v, **kw))
     want = jax.tree_util.tree_leaves(call(q, k, v, **kw, **blocks))
     other = jax.tree_util.tree_leaves(call(
@@ -481,3 +489,234 @@ def test_an_explicit_block_wins_each_on_its_own(kw, want, monkeypatch):
         kw = dict(kw, dropout_key=jax.random.PRNGKey(1))
     pa.flash_attention(q, k, v, **kw)
     assert seen == [want]
+
+
+# ---------------------------------------------------------------------------
+# the rows layout: the kernels index the projections' (B, T, H * D) rows
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("d,heads,kv_heads,want", [
+    (64, 12, 12, 2), (32, 8, 8, 4), (64, 2, 2, 2),      # heads share lanes
+    (64, 1, 1, 1), (80, 1, 1, 1), (128, 1, 1, 1),   # one head IS its array
+    (128, 32, 4, 0), (128, 64, 8, 0), (256, 2, 2, 0),   # head-major is free
+    (80, 4, 4, 0), (96, 2, 1, 0),               # no legal block of rows
+    (64, 3, 3, 0), (32, 6, 6, 0),               # an odd count under pairing
+    (64, 4, 2, 0),                              # 64 with grouped KV heads
+])
+def test_heads_per_step_is_a_rule_on_the_shape(d, heads, kv_heads, want):
+    assert pa.heads_per_step(d, heads, kv_heads) == want
+
+
+def _mha(q, k, v, **kw):
+    from mxnet_tpu.ops.nn import multi_head_attention
+
+    return multi_head_attention.fn(q, k, v, **kw)
+
+
+def _outer_eqns(jaxpr):
+    """Every equation AROUND the kernels: the jaxprs inside the custom_vjp
+    and its branches, not the kernels' own bodies."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _outer_eqns(sub)
+
+
+_CELL_CALLS = {     # (B, T, query heads, KV heads, D, the call's rule)
+    "bert_base_t512": (64, 512, 12, 12, 64, {}),
+    "sdar_30b_a3b_bd4k": (2, 8192, 32, 4, 128,
+                          {"mask": pa.block_diffusion_mask(4096, 4)}),
+    "laguna_xs2_t8k_causal": (1, 8192, 48, 8, 128, {"causal": True}),
+    "laguna_xs2_t8k_window": (1, 8192, 64, 8, 128,
+                              {"mask": pa.window_mask(512)}),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_CALLS))
+def test_no_copy_stands_between_the_projections_and_the_kernels(cell):
+    """``multi_head_attention`` forward and gradient at the cells' attention
+    shapes (traced, nothing runs): three kernel calls.  Heads of 64 (BERT):
+    every call on the ``(B, T, H * D)`` rows as the projections wrote them,
+    and no ``transpose`` of anything as large as K around them.  Heads of
+    128 (SDAR, Laguna): every call on the head-major view, one head a batch
+    row, whose transposes XLA gives to the projections' products as their
+    layout (``test_chip_compile.py`` compiles that)."""
+    B, T, H, Hkv, D, kw = _CELL_CALLS[cell]
+    q = jax.ShapeDtypeStruct((B, T, H * D), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, T, Hkv * D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return _mha(q, k, v, num_heads=H, num_kv_heads=Hkv, impl="pallas",
+                    **kw).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2)))(q, kv, kv)
+    eqns = list(_outer_eqns(jaxpr.jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert sorted(e.params["name"] for e in calls) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    rows = pa.heads_per_step(D, H, Hkv) > 0
+    assert rows == (D == 64)
+    want = {(B, T, H * D), (B, T, Hkv * D)} if rows \
+        else {(B * H, T, D), (B * Hkv, T, D)}
+    for e in calls:
+        big = {v.aval.shape for v in e.invars
+               if v.aval.ndim == 3 and v.aval.dtype == jnp.bfloat16}
+        assert big == want, big
+    moved = [e.invars[0].aval.shape for e in eqns
+             if e.primitive.name == "transpose"
+             and e.invars[0].aval.size >= B * T * Hkv * D]
+    assert bool(moved) != rows, moved
+
+
+def test_a_shape_the_rule_does_not_serve_is_transposed_in_flash_attention():
+    """D = 80: the same three calls, on a head-major view in which every
+    head is a batch row of one head."""
+    B, T, H, D = 2, 256, 4, 80
+    x = jax.ShapeDtypeStruct((B, T, H * D), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: _mha(
+        q, k, v, num_heads=H, impl="pallas").sum(), (0, 1, 2)))(x, x, x)
+    eqns = list(_outer_eqns(jaxpr.jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 3
+    for e in calls:
+        assert (B * H, T, D) in [v.aval.shape for v in e.invars]
+    assert any(e.primitive.name == "transpose" for e in eqns)
+
+
+@pytest.mark.parametrize("name,t,heads,kv_heads,d,kw", [
+    # one head a batch row of the head-major view, grouped KV heads,
+    # under each rule
+    ("d128_grouped_plain", 64, 4, 2, 128, {}),
+    ("d128_grouped_causal", 64, 4, 2, 128, {"causal": True}),
+    ("d128_grouped_window", 64, 4, 2, 128, {"mask": pa.window_mask(24)}),
+    ("d128_grouped_block_diffusion", 64, 4, 2, 128,
+     {"mask": pa.block_diffusion_mask(32, 4)}),
+    # heads that share the 128 lanes of a block
+    ("d64_pairs", 64, 4, 4, 64, {}),
+    ("d64_pairs_causal", 64, 4, 4, 64, {"causal": True}),
+    ("d32_fours", 64, 8, 8, 32, {}),
+    ("d32_fours_causal", 64, 8, 8, 32, {"causal": True}),
+    # T no multiple of the blocks: padded on axis 1 of the rows
+    ("d64_pairs_padded", 50, 4, 4, 64, {"causal": True}),
+    ("d128_grouped_padded", 50, 4, 2, 128, {"causal": True}),
+    # shapes that fall back to the head-major view
+    ("d80", 40, 2, 2, 80, {}),
+    ("d80_grouped_causal", 40, 4, 2, 80, {"causal": True}),
+    ("d64_odd_heads", 40, 3, 3, 64, {}),
+    ("d64_grouped", 40, 4, 2, 64, {"causal": True}),
+])
+def test_every_layout_rule_matches_the_dense_path(name, t, heads, kv_heads,
+                                                  d, kw, monkeypatch):
+    """Output and the three gradients through ``multi_head_attention``,
+    kernels against dense, from ``(B, T, H * D)`` rows; tiles of 16 so that
+    every call has several, whole and cut."""
+    monkeypatch.setattr(pa, "DEFAULT_BLOCK_Q", 16)
+    monkeypatch.setattr(pa, "DEFAULT_BLOCK_K", 16)
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    q, k, v, w = (jax.random.normal(key, (2, t, n * d))
+                  for key, n in zip(ks, (heads, kv_heads, kv_heads, heads)))
+
+    def run(impl):
+        def loss(q, k, v):
+            out = _mha(q, k, v, num_heads=heads, num_kv_heads=kv_heads,
+                       impl=impl, **kw)
+            return (out * w).sum(), out
+
+        (_, out), grads = jax.value_and_grad(loss, (0, 1, 2),
+                                             has_aux=True)(q, k, v)
+        return (out,) + grads
+
+    for what, a, b in zip(("out", "dq", "dk", "dv"), run("pallas"),
+                          run("dense")):
+        np.testing.assert_allclose(a, b, atol=1e-5, err_msg=what)
+
+
+@pytest.mark.parametrize("d,heads", [(64, 2), (32, 4)])
+def test_a_shared_grid_step_draws_each_heads_own_dropout_mask(d, heads):
+    """The heads of one grid step against single-head calls handed the
+    same ``seeds[b, h]``: outputs and gradients equal to the order of the
+    products' sums (another mask would be an error of the values' own
+    size), so no head's mask depends on how many heads share its step."""
+    B, T = 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(6), 4)
+    q, k, v, do = (jax.random.normal(key, (B, T, heads, d)) for key in ks)
+    cfg = pa._cfg_for(q, k, False, None, 32, 32, None, dropout_p=0.3)
+    seeds = pa._bh_seeds(jax.random.PRNGKey(9), B, heads)
+    assert pa.heads_per_step(d, heads, heads) == heads
+    assert pa.heads_per_step(d, 1, 1) == 1
+
+    def call(seeds, q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: pa._flash_core(cfg, seeds, q, k,
+                                                          v), q, k, v)
+        return (out,) + vjp(do)
+
+    together = call(seeds, q, k, v, do)
+    assert not np.allclose(together[0], pa.flash_attention(
+        q, k, v, block_q=32, block_k=32))         # the masks are at work
+    for h in range(heads):
+        alone = call(seeds[:, h:h + 1],
+                     *(x[:, :, h:h + 1] for x in (q, k, v, do)))
+        for what, a, b in zip(("out", "dq", "dk", "dv"), together, alone):
+            np.testing.assert_allclose(
+                np.asarray(a[:, :, h:h + 1]), np.asarray(b), atol=2e-6,
+                err_msg="%s of head %d" % (what, h))
+
+
+@pytest.mark.parametrize("layout,heads,kv_heads,d", [
+    ("pairs", 2, 2, 64), ("fours", 4, 4, 32),
+    ("head_major_view_grouped", 4, 2, 128), ("head_major_view", 3, 3, 64)])
+def test_every_layout_under_mesh_rows_matches_one_device(layout, heads,
+                                                         kv_heads, d):
+    """``dp`` = 2 under ``mesh_rows`` on the virtual devices against one
+    device, bit for bit, dropout on: each rule of ``heads_per_step``, and
+    the head-major view (whose batch rows are B * H)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    B, T = 4, 64
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    q, k, v, g = (jax.random.normal(key, (B, T, n, d)) for key, n in
+                  zip(ks, (heads, kv_heads, kv_heads, heads)))
+    key = jax.random.PRNGKey(5)
+
+    def fwd_bwd(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: pa.flash_attention(
+            q, k, v, causal=True, block_q=32, block_k=32, dropout_p=0.2,
+            dropout_key=key), q, k, v)
+        return (out,) + vjp(g)
+
+    want = jax.jit(fwd_bwd)(q, k, v)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+    rows = NamedSharding(mesh, P("dp"))
+    with pa.mesh_rows(mesh, ("dp",)):
+        sharded = jax.jit(lambda *qkv: fwd_bwd(*qkv))
+        args = [jax.device_put(x, rows) for x in (q, k, v)]
+        assert "shard_map" in str(jax.make_jaxpr(sharded)(*args))
+        got = sharded(*args)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+
+
+def test_the_tiles_instant_says_how_the_kernels_read_the_call(monkeypatch):
+    """``mx.attn.tiles`` at BERT's shape (two heads a grid step, rows) and
+    at a shape the rule does not serve (D = 80: head-major, one head);
+    SDAR's and Laguna's calls are pinned beside their tile counts."""
+    from mxnet_tpu import trace
+
+    monkeypatch.setattr(pa, "_TILES_NOTED", set())
+
+    def noted():
+        return [e["args"] for e in trace.events()
+                if e["name"] == "mx.attn.tiles"
+                and e["args"]["operand_dtype"] == "bfloat16"
+                and e["args"]["kind"] == "none"]
+
+    before = len(noted())
+    for shape in ((64, 512, 12, 64), (2, 512, 4, 80), (64, 512, 12, 64)):
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        jax.eval_shape(pa.flash_attention, x, x, x)
+    tiles = {"kind": "none", "visited": 1, "whole": 1, "cut": 0,
+             "operand_dtype": "bfloat16"}
+    assert noted()[before:] == [
+        dict(tiles, heads_per_step=2, layout="rows"),
+        dict(tiles, heads_per_step=1, layout="heads")]

@@ -38,12 +38,12 @@ def test_pallas_flash_matches_dense(causal):
     from mxnet_tpu.ops import pallas_attention as pa
 
     B, H, T, D = 1, 2, 128, 8
-    q, k, v = (jnp.asarray(_rand(B, H, T, D)) for _ in range(3))
+    q, k, v = (jnp.asarray(_rand(B, T, H, D)) for _ in range(3))
     out = pa.flash_attention(q, k, v, causal, None, 32, 32, True)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
     if causal:
         s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
-    ref = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+    ref = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
     assert_almost_equal(np.asarray(out), np.asarray(ref), rtol=1e-4,
                         atol=1e-5)
 
@@ -52,16 +52,16 @@ def test_flash_attention_grad():
     from mxnet_tpu.ops import pallas_attention as pa
 
     B, H, T, D = 1, 1, 32, 8
-    q, k, v = (jnp.asarray(_rand(B, H, T, D)) for _ in range(3))
+    q, k, v = (jnp.asarray(_rand(B, T, H, D)) for _ in range(3))
 
     def loss_flash(q_, k_, v_):
         return pa.flash_attention(q_, k_, v_, True, None, 16, 16,
                                   True).sum()
 
     def loss_dense(q_, k_, v_):
-        s = jnp.einsum("bhqd,bhkd->bhqk", q_, k_) / np.sqrt(D)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q_, k_) / np.sqrt(D)
         s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
-        return jnp.einsum("bhqk,bhkd->bhqd",
+        return jnp.einsum("bhqk,bkhd->bqhd",
                           jax.nn.softmax(s, -1), v_).sum()
 
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)

@@ -25,7 +25,7 @@ def _rule(t, window):
 
 def _qkv(t, heads, kv_heads, d=16, batch=2, seed=0, dtype=jnp.float32):
     k = jax.random.split(jax.random.PRNGKey(seed), 4)
-    return tuple(jax.random.normal(key, (batch, n, t, d)).astype(dtype)
+    return tuple(jax.random.normal(key, (batch, t, n, d)).astype(dtype)
                  for key, n in zip(k, (heads, kv_heads, kv_heads, heads)))
 
 
@@ -101,7 +101,7 @@ def test_the_cells_calls_visit_31_and_136_tiles_a_head(monkeypatch):
     assert 512 * 8192 - 512 * 511 // 2 == 4_063_488
     assert 31 * 512 * 512 == 8_126_464
     monkeypatch.setattr(pa, "_TILES_NOTED", set())
-    kv = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
 
     def noted():
         return [e["args"] for e in trace.events()
@@ -110,15 +110,15 @@ def test_the_cells_calls_visit_31_and_136_tiles_a_head(monkeypatch):
 
     before = len(noted())
     for heads, kw in ((64, {"mask": mask}), (48, {"causal": True})):
-        q = jax.ShapeDtypeStruct((1, heads, 8192, 128), jnp.bfloat16)
+        q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16)
         for _ in range(2):      # one instant a distinct call
             jax.eval_shape(lambda q, k, v: pa.flash_attention(q, k, v, **kw),
                            q, kv, kv)
     assert noted()[before:] == [
         {"kind": "window", "visited": 31, "whole": 0, "cut": 31,
-         "operand_dtype": "bfloat16"},
+         "operand_dtype": "bfloat16", "heads_per_step": 1, "layout": "heads"},
         {"kind": "causal", "visited": 136, "whole": 120, "cut": 16,
-         "operand_dtype": "bfloat16"}]
+         "operand_dtype": "bfloat16", "heads_per_step": 1, "layout": "heads"}]
 
 
 @pytest.mark.parametrize("t,window,bq,bk,heads,kv_heads", [
@@ -217,12 +217,12 @@ def test_multi_head_attention_takes_both_rules_to_the_kernels_or_dense():
         assert "4x256x256" not in lowered.as_text()
         assert scope in lowered.as_text(debug_info=True)
     with pytest.raises(MXNetError, match="positions"):
-        pa.flash_attention(q._data.reshape(1, heads, t, d)[:, :, :128],
-                           kk._data.reshape(1, kv_heads, t, d),
-                           v._data.reshape(1, kv_heads, t, d),
+        pa.flash_attention(q._data.reshape(1, t, heads, d)[:, :128],
+                           kk._data.reshape(1, t, kv_heads, d),
+                           v._data.reshape(1, t, kv_heads, d),
                            mask=pa.window_mask(48))
     with pytest.raises(MXNetError, match="together"):
-        pa.flash_attention(q._data.reshape(1, heads, t, d),
-                           kk._data.reshape(1, kv_heads, t, d),
-                           v._data.reshape(1, kv_heads, t, d), causal=True,
+        pa.flash_attention(q._data.reshape(1, t, heads, d),
+                           kk._data.reshape(1, t, kv_heads, d),
+                           v._data.reshape(1, t, kv_heads, d), causal=True,
                            mask=pa.window_mask(48))
