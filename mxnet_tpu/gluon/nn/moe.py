@@ -6,7 +6,8 @@ new-capability component designed TPU-first.  The layer is told which
 experts it holds (``first .. first + count`` of ``num_experts``): it routes
 every position over ALL ``num_experts`` (router product and scores in
 float32: a softmax over the experts, or a sigmoid an expert as DeepSeek-V3
-has it; top-k, renormalised, times a routing scale), sorts the assignments
+has it; top-k, optionally chosen under a SELECTION bias that never enters
+a weight, renormalised, times a routing scale), sorts the assignments
 that picked a held
 expert into expert order, runs the held experts as ``jax.lax.ragged_dot``
 grouped products, and adds each position's weighted results back.  What the
@@ -101,19 +102,30 @@ _SCORES = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
 
 
 def route(x, gate, top_k, first, count, norm_topk=True, score="softmax",
-          scale=1.0):
+          scale=1.0, bias=None):
     """Route ``x`` (N, d) over all the router's experts; lay out the
     assignments that picked a held expert in expert order.
 
     ``score``: how the router's float32 logits become scores, ``softmax``
     over the experts or ``sigmoid`` an expert; the ``top_k`` largest are a
-    position's experts.  Returns ``layout``'s dict and ``weights`` (N, k)
-    float32: the chosen scores, renormalised over the top-k when
-    ``norm_topk``, times ``scale``."""
+    position's experts.  ``bias`` (experts,): a SELECTION bias
+    (DeepSeek-V3's ``noaux_tc``): the experts are the ``top_k`` largest of
+    ``score + bias``, and a chosen expert's weight is made from its score
+    alone.  Returns ``layout``'s dict and ``weights`` (N, k) float32: the
+    chosen scores, renormalised over the top-k when ``norm_topk``, times
+    ``scale``."""
     logits = jnp.einsum("td,ed->te", x, gate,
                         preferred_element_type=jnp.float32)
     probs = _SCORES[score](logits.astype(jnp.float32))
-    top_p, top_e = jax.lax.top_k(probs, top_k)
+    if bias is None:
+        top_p, top_e = jax.lax.top_k(probs, top_k)
+    else:
+        _, top_e = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        # the chosen experts' own scores, by a select over the experts: no
+        # gather of single elements, forward or backward
+        chosen = top_e[..., None] == jnp.arange(probs.shape[-1],
+                                                dtype=top_e.dtype)
+        top_p = jnp.sum(jnp.where(chosen, probs[:, None, :], 0), axis=-1)
     weights = top_p / jnp.sum(top_p, -1, keepdims=True) if norm_topk \
         else top_p
     if scale != 1.0:
@@ -332,16 +344,18 @@ _to_positions.defvjp(_to_positions_fwd, _to_positions_bwd)
 
 def moe_forward(x, gate, w1, w2, wg=None, b1=None, b2=None, shared_w1=None,
                 shared_wg=None, shared_w2=None, *, top_k, first,
-                activation="relu", norm_topk=True, score="softmax",
-                scale=1.0):
+                select_bias=None, activation="relu", norm_topk=True,
+                score="softmax", scale=1.0):
     """The layer as a pure function of (N, d) positions; see the module's
     docstring.  ``w1`` (count, d, hidden), ``wg`` the gate projection of a
     gated expert (``act(x wg) * (x w1)``), ``w2`` (count, hidden, units);
-    ``shared_*`` the shared expert's three, without the leading dim."""
+    ``shared_*`` the shared expert's three, without the leading dim;
+    ``select_bias`` the router's selection bias (``route``)."""
     count = w1.shape[0]
     act = _ACTIVATIONS[activation]
     with jax.named_scope("mx.moe.route"):
-        r = route(x, gate, top_k, first, count, norm_topk, score, scale)
+        r = route(x, gate, top_k, first, count, norm_topk, score, scale,
+                  select_bias)
         loop = loops(top_k, count, gate.shape[0])
         moves = (r["rows"] if loop["positions"] else None,
                  r["rows"] if loop["buffer"] else None,
@@ -407,12 +421,17 @@ class MoE(HybridBlock):
     shared_hidden : int
         Hidden width of a shared gated expert that every position passes
         through, added unweighted (0: none; needs ``gated``).
+    select_bias : bool
+        Hold a selection bias ``select_bias`` (num_experts,): the experts
+        are chosen by ``score + bias``, the weights made from the scores
+        alone (``route``).  No gradient reaches it (``grad_req="null"``):
+        whoever balances the load sets it between steps.
     """
 
     def __init__(self, num_experts, hidden_size, units, top_k=2,
                  in_units=0, activation="relu", gated=False, use_bias=True,
                  first=0, count=None, norm_topk=True, score="softmax",
-                 scale=1.0, shared_hidden=0, **kwargs):
+                 scale=1.0, shared_hidden=0, select_bias=False, **kwargs):
         super().__init__()
         if top_k < 1 or top_k > num_experts:
             raise MXNetError("top_k must be in [1, num_experts]")
@@ -456,6 +475,9 @@ class MoE(HybridBlock):
             if shared else None
         self.shared_w2 = Parameter("shared_w2", shape=(shared, units)) \
             if shared else None
+        self.select_bias = Parameter(
+            "select_bias", shape=(self._E,), init="zeros",
+            grad_req="null") if select_bias else None
 
     def _activation(self, jnp_, h):  # parallel.moe_apply's hook
         return _ACTIVATIONS[self._act](h)
@@ -475,7 +497,8 @@ class MoE(HybridBlock):
                 "chunks": -(-r // chunk_rows(r)), "score": self._score,
                 "scale": self._scale,
                 "shared": 0 if self.shared_w1 is None
-                else self.shared_w1.shape[1]})
+                else self.shared_w1.shape[1],
+                "bias": self.select_bias is not None})
 
     def forward(self, x):
         from ...ops.registry import apply_op
@@ -487,7 +510,8 @@ class MoE(HybridBlock):
         # the optional weights this layer has, by moe_forward's keyword
         extra = {n: getattr(self, n).data()
                  for n in ("wg", "b1", "b2", "shared_w1", "shared_wg",
-                           "shared_w2") if getattr(self, n) is not None}
+                           "shared_w2", "select_bias")
+                 if getattr(self, n) is not None}
         fn = functools.partial(moe_forward, top_k=self._k, first=self._first,
                                activation=self._act,
                                norm_topk=self._norm_topk, score=self._score,
@@ -514,7 +538,9 @@ class MoE(HybridBlock):
         xv = xv.reshape(-1, xv.shape[-1])
         sizes = route(xv, self.gate.data()._data, self._k, self._first,
                       self._count, self._norm_topk, self._score,
-                      self._scale)["group_sizes"]
+                      self._scale,
+                      None if self.select_bias is None
+                      else self.select_bias.data()._data)["group_sizes"]
         sizes = [int(s) for s in jax.device_get(sizes)]
         if _tel.ENABLED:
             g = _tel.gauge("moe_expert_rows",

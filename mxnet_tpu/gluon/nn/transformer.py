@@ -20,7 +20,7 @@ from __future__ import annotations
 import contextlib
 import math
 
-from ... import ndarray as nd
+from ... import ndarray as nd, trace as _trace
 from ...base import MXNetError
 from ..block import HybridBlock
 from .basic_layers import Dense, Dropout, Embedding, HybridSequential, \
@@ -31,8 +31,8 @@ from ...ops.pallas_attention import rule_kind, window_mask
 __all__ = ["MultiHeadAttention", "PositionwiseFFN",
            "TransformerEncoderCell", "TransformerEncoder",
            "PositionalEmbedding", "SinusoidalPositionalEmbedding",
-           "GroupedQueryAttention", "GatedMLP", "DecoderLayer",
-           "MoEDecoderLayer"]
+           "GroupedQueryAttention", "LatentAttention", "GatedMLP",
+           "DecoderLayer", "MoEDecoderLayer", "recomputed"]
 
 
 class MultiHeadAttention(HybridBlock):
@@ -219,6 +219,42 @@ class SinusoidalPositionalEmbedding(HybridBlock):
         return apply_op(add_pe, x)
 
 
+def recomputed(fn, recompute, *arrays):
+    """``fn(*arrays)``, NDArrays in and one out.  With ``recompute``,
+    inside a traced program (``FusedTrainer``, ``hybridize``), under
+    ``jax.checkpoint``: the backward keeps ``arrays`` only and runs ``fn``
+    again."""
+    import jax
+
+    if not (recompute and isinstance(arrays[0]._data, jax.core.Tracer)):
+        return fn(*arrays)
+
+    def pure(*datas):
+        return fn(*map(nd.NDArray, datas))._data
+
+    return nd.NDArray(jax.checkpoint(pure)(*(a._data for a in arrays)))
+
+
+def _attn_scope(kind):
+    """The scope ``mx.attn.<kind>`` an attention block's own work carries
+    in a traced program (``kind`` None: no scope)."""
+    import jax
+
+    return jax.named_scope("mx.attn.%s" % kind) if kind \
+        else contextlib.nullcontext()
+
+
+def _placed(h, heads, positions, rotary, norm=None):
+    """A projection's ``(B, T, heads * D)`` rows as ``(B, T, heads, D)``
+    with their per-head norm and rotary positions (``rotary``: keywords of
+    ``nd.rotary_embedding``)."""
+    b, t = h.shape[0], h.shape[1]
+    h = h.reshape((b, t, heads, -1))
+    if norm is not None:
+        h = norm(h)
+    return nd.rotary_embedding(h, positions, **rotary)
+
+
 class GroupedQueryAttention(HybridBlock):
     """Self-attention of a modern decoder: ``num_heads`` query heads over
     ``num_kv_heads`` KV heads of ``head_dim`` (each KV head serves a group
@@ -273,28 +309,17 @@ class GroupedQueryAttention(HybridBlock):
         self.gate_proj = Dense(num_heads, use_bias=False, flatten=False,
                                in_units=units) if gate else None
 
-    def _placed(self, h, norm, positions, heads):
-        """(B, T, heads * D) projections with their norm and positions."""
-        b, t = h.shape[0], h.shape[1]
-        h = h.reshape((b, t, heads, self._dim))
-        if norm is not None:
-            h = norm(h)
-        return nd.rotary_embedding(h, positions, **self._rotary) \
-            .reshape((b, t, heads * self._dim))
-
     def forward(self, x, positions, mask=None):
-        import jax
-
         if mask is None:
             mask = self._window
         causal = self._causal and mask is None
-        kind = rule_kind(causal, mask)
         q, k, v = self.query_proj(x), self.key_proj(x), self.value_proj(x)
-        with jax.named_scope("mx.attn.%s" % kind) if kind \
-                else contextlib.nullcontext():
+        with _attn_scope(rule_kind(causal, mask)):
             out = nd.multi_head_attention(
-                self._placed(q, self.query_norm, positions, self._heads),
-                self._placed(k, self.key_norm, positions, self._kv_heads),
+                _placed(q, self._heads, positions, self._rotary,
+                        self.query_norm).reshape(q.shape),
+                _placed(k, self._kv_heads, positions, self._rotary,
+                        self.key_norm).reshape(k.shape),
                 v, num_heads=self._heads, num_kv_heads=self._kv_heads,
                 mask=mask, causal=causal)
             if self.gate_proj is not None:
@@ -303,6 +328,95 @@ class GroupedQueryAttention(HybridBlock):
                     .reshape((b, t, self._heads, 1))
                 out = (out.reshape((b, t, self._heads, self._dim)) * g) \
                     .reshape((b, t, self._heads * self._dim))
+        return self.out_proj(out)
+
+
+class LatentAttention(HybridBlock):
+    """Multi-head latent attention (MLA; DeepSeek-V2 arXiv:2405.04434
+    section 2.1, as DeepSeek-V3 and ``glm4_moe_lite`` carry it) in its
+    EXPANDED form, the form of training and prefill: queries, keys and
+    values go through low-rank latents,
+
+    - ``c_q = RMSNorm(x W_qa)`` (``q_rank``), ``q = c_q W_qb``: a head's
+      ``nope + rope`` dimensions;
+    - ``[c_kv ; k_r] = x W_kva`` (``kv_rank + rope``), ``c_kv =
+      RMSNorm(c_kv)``; ``k_r`` is ONE rotary key for all the heads;
+    - ``[k_nope ; v] = c_kv W_kvb``: a head's ``nope + v_dim`` dimensions;
+    - ``q_h = [q_nope_h ; rotary(q_rope_h)]``, ``k_h = [k_nope_h ;
+      rotary(k_r)]``: rotary positions (rotate-half form) on the LAST
+      ``rope`` dimensions of a head only, the rotary key repeated over the
+      heads; scores scaled by ``(nope + rope) ** -0.5``.
+
+    The heads' keys and values are made whole (``(B, T, heads, D)``) and
+    handed to ``nd.multi_head_attention`` like any other block's, so the
+    flash kernels or the dense path are chosen as for every block; the
+    kernels want ``nope + rope == v_dim`` (GLM-4.7-Flash: 192 + 64 = 256;
+    unequal sizes raise: nothing is padded).  The absorbed form, in which
+    decoding attends over the latents themselves, is not here.
+
+    forward(x, positions, mask=None) as ``GroupedQueryAttention``; causal
+    unless a ``mask`` says otherwise.
+    Everything between the layer's input and the output projection's (the
+    low-rank path IS the mechanism) carries the scope ``mx.attn.mla``;
+    the rule's own ``mx.attn.<kind>`` appears within it."""
+
+    def __init__(self, units, num_heads, q_rank, kv_rank, nope_dim,
+                 rope_dim, v_dim, rope_theta=10000.0, epsilon=1e-6):
+        super().__init__()
+        if nope_dim + rope_dim != v_dim:
+            raise MXNetError(
+                "LatentAttention: a head's query/key size %d + %d and its "
+                "value size %d differ; the attention kernels take one head "
+                "size" % (nope_dim, rope_dim, v_dim))
+        self._sizes = {"heads": num_heads, "q_rank": q_rank,
+                       "kv_rank": kv_rank, "nope": nope_dim,
+                       "rope": rope_dim, "v": v_dim}
+        self._rotary = {"theta": rope_theta}
+        self._laid_out = set()
+        self.q_a_proj = Dense(q_rank, use_bias=False, flatten=False,
+                              in_units=units)
+        self.q_a_norm = RMSNorm(epsilon=epsilon, in_channels=q_rank)
+        self.q_b_proj = Dense(num_heads * (nope_dim + rope_dim),
+                              use_bias=False, flatten=False,
+                              in_units=q_rank)
+        self.kv_a_proj = Dense(kv_rank + rope_dim, use_bias=False,
+                               flatten=False, in_units=units)
+        self.kv_a_norm = RMSNorm(epsilon=epsilon, in_channels=kv_rank)
+        self.kv_b_proj = Dense(num_heads * (nope_dim + v_dim),
+                               use_bias=False, flatten=False,
+                               in_units=kv_rank)
+        self.out_proj = Dense(units, use_bias=False, flatten=False,
+                              in_units=num_heads * v_dim)
+        self.out_proj.weight.sharding = (None, "tp")
+
+    def _layout(self, shape):
+        if shape not in self._laid_out:
+            self._laid_out.add(shape)
+            _trace.instant("mx.mla.layout",
+                           args=dict(self._sizes, form="expanded"))
+
+    def forward(self, x, positions, mask=None):
+        self._layout(tuple(x.shape[:2]))
+        b, t = x.shape[0], x.shape[1]
+        heads, kv_rank, nope, rope = (self._sizes[n] for n in (
+            "heads", "kv_rank", "nope", "rope"))
+        with _attn_scope("mla"):
+            q = self.q_b_proj(self.q_a_norm(self.q_a_proj(x))) \
+                .reshape((b, t, heads, -1))
+            kv_a = self.kv_a_proj(x)
+            kv = self.kv_b_proj(self.kv_a_norm(kv_a[..., :kv_rank])) \
+                .reshape((b, t, heads, -1))
+            # one rotary key, repeated over the heads
+            k_r = _placed(kv_a[..., kv_rank:], 1, positions, self._rotary)
+            q = nd.concat(q[..., :nope],
+                          _placed(q[..., nope:], heads, positions,
+                                  self._rotary), dim=-1)
+            k = nd.concat(kv[..., :nope],
+                          nd.broadcast_to(k_r, (b, t, heads, rope)), dim=-1)
+            out = nd.multi_head_attention(
+                q.reshape((b, t, -1)), k.reshape((b, t, -1)),
+                kv[..., nope:].reshape((b, t, -1)), num_heads=heads,
+                mask=mask, causal=mask is None)
         return self.out_proj(out)
 
 
@@ -357,16 +471,8 @@ class DecoderLayer(HybridBlock):
         return h + getattr(self, self._ffn)(self.post_norm(h))
 
     def forward(self, x, positions, mask=None):
-        import jax
-
-        if not (self._recompute and isinstance(x._data, jax.core.Tracer)):
-            return self._layer(x, positions, mask)
-
-        def pure(x_, positions_):
-            return self._layer(nd.NDArray(x_), nd.NDArray(positions_),
-                               mask)._data
-
-        return nd.NDArray(jax.checkpoint(pure)(x._data, positions._data))
+        return recomputed(lambda x_, positions_: self._layer(
+            x_, positions_, mask), self._recompute, x, positions)
 
 
 class MoEDecoderLayer(DecoderLayer):

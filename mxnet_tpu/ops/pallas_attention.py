@@ -1015,11 +1015,11 @@ def _note_tiles(cfg, q, k):
     call is traced: what the kernels will visit, at which dtype their
     products run, and how they read the call's arrays (``layout`` ``rows``
     with ``heads_per_step`` heads a grid step, or ``heads`` where the
-    shape fell back to a head-major copy)."""
+    shape fell back to a head-major copy) at which ``head_dim``."""
     heads = heads_per_step(q.shape[3], q.shape[2], k.shape[2])
     what = (q.shape[1], k.shape[1], cfg.block_q, cfg.block_k, cfg.causal,
             cfg.mask)
-    how = (str(q.dtype), heads)
+    how = (str(q.dtype), heads, q.shape[3])
     if what + how in _TILES_NOTED:
         return
     _TILES_NOTED.add(what + how)
@@ -1030,7 +1030,7 @@ def _note_tiles(cfg, q, k):
         "kind": rule_kind(cfg.causal, cfg.mask) or "none",
         "visited": visited, "whole": whole, "cut": cut,
         "operand_dtype": how[0], "heads_per_step": heads or 1,
-        "layout": "rows" if heads else "heads"})
+        "layout": "rows" if heads else "heads", "head_dim": q.shape[3]})
 
 
 def _cfg_for(q, k, causal, sm_scale, block_q, block_k, interpret,
@@ -1296,7 +1296,11 @@ def use_flash(seq_q, seq_k, head_dim, has_mask, itemsize=4):
     """Dispatch heuristic for impl='auto': flash pays off once the score
     matrix no longer fits the fusion footprint; dense einsum wins short-T.
     A shape outside the kernels' fast-memory envelope is never sent to
-    them (``itemsize`` defaults to the float32 worst case)."""
+    them (``itemsize`` defaults to the float32 worst case).  Heads of up
+    to 256 are admitted; on the chip the kernels have run, in a cell of
+    the benchmark, at heads of 64 (BERT), 128 (SDAR, Laguna) and 256
+    (GLM-4.7-Flash's latent attention: two 128-lane tiles a head, about
+    30 MiB of scoped VMEM at T = 8,192)."""
     if has_mask:
         return False
     return seq_q * seq_k >= 256 * 256 and head_dim <= 256 and \

@@ -160,7 +160,8 @@ def test_the_cells_call_visits_80_tiles_a_head_56_whole_and_24_cut(
                        q, kv, kv)
     assert noted()[before:] == [{
         "kind": "block_diffusion", "visited": 80, "whole": 56, "cut": 24,
-        "operand_dtype": "bfloat16", "heads_per_step": 1, "layout": "heads"}]
+        "operand_dtype": "bfloat16", "heads_per_step": 1, "layout": "heads",
+        "head_dim": 128}]
 
 
 @pytest.mark.parametrize("seq,block,bq,bk,heads,kv_heads", [

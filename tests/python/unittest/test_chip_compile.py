@@ -215,6 +215,44 @@ def test_laguna_calls_compile_for_v5e_with_grouped_kv(topo, mosaic_modules,
         assert module.count("arith.extf") == (2 if n == 3 else 0)  # Δ's
 
 
+def test_glm_call_compiles_for_v5e_at_heads_of_256(topo, mosaic_modules):
+    """glm47_flash_t8k's call (latent attention in the expanded form):
+    8,192 positions, 20 heads of 256 over as many KV heads, causal, bf16.
+    Two 128-lane tiles a head and a head's resident K and V twice
+    Laguna's: the kernels ask for more than Mosaic's default VMEM and stay
+    inside the budget.  Head-major view, the body traced for whole and cut
+    tiles, bf16 into every product, no score matrix in the program."""
+    need = pa.flash_vmem_bytes(8192, 8192, 256, 2)
+    assert pa.VMEM_DEFAULT_BYTES < need <= pa.VMEM_BUDGET_BYTES
+    assert pa.use_flash(8192, 8192, 256, False, 2)
+    assert pa.heads_per_step(256, 20, 20) == 0
+    assert pa.tile_counts(8192, 8192, 512, 512, True) == (136, 120, 16)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def f(q, k, v):
+        def loss(q, k, v):
+            return pa.flash_attention(q, k, v, causal=True,
+                                      interpret=False) \
+                .astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    x = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16, sharding=one)
+    text = jax.jit(f).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "20,8192,8192" not in text               # no score matrix
+    assert "bf16[20,8192,256]" in text
+    assert len(mosaic_modules) == 3             # forward, dq, dkv
+    for module, n in zip(mosaic_modules, (2, 3, 4)):
+        matmuls = re.findall(
+            r"tpu\.matmul.*?: (vector<[^>]*>), (vector<[^>]*>), "
+            r"(vector<[^>]*>)", module)
+        assert len(matmuls) == n * 2, (len(matmuls), n)
+        for lhs, rhs, acc in matmuls:
+            assert lhs.endswith("xbf16>") and rhs.endswith("xbf16>") \
+                and acc.endswith("xf32>"), (lhs, rhs, acc)
+
+
 @pytest.mark.parametrize("dropout_p", [0.0, 0.1])
 def test_flash_lowers_on_a_dp_mesh_without_gathering_the_batch(topo,
                                                                dropout_p):

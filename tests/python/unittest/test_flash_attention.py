@@ -718,5 +718,5 @@ def test_the_tiles_instant_says_how_the_kernels_read_the_call(monkeypatch):
     tiles = {"kind": "none", "visited": 1, "whole": 1, "cut": 0,
              "operand_dtype": "bfloat16"}
     assert noted()[before:] == [
-        dict(tiles, heads_per_step=2, layout="rows"),
-        dict(tiles, heads_per_step=1, layout="heads")]
+        dict(tiles, heads_per_step=2, layout="rows", head_dim=64),
+        dict(tiles, heads_per_step=1, layout="heads", head_dim=80)]

@@ -353,7 +353,8 @@ def test_layout_instant_is_written_when_the_layer_meets_a_shape(monkeypatch):
     assert got[0]["args"] == {"experts": 8, "held": 4, "first": 2,
                               "top_k": 2, "buffer_rows": 12,
                               "chunk_rows": 12, "chunks": 1,
-                              "score": "softmax", "scale": 1.0, "shared": 0}
+                              "score": "softmax", "scale": 1.0, "shared": 0,
+                              "bias": False}
     monkeypatch.setattr(moe_mod, "chunk_rows", lambda r: min(r, 8))
     moe(nd.array(np.ones((10, 8), "float32")))
     assert [e for e in trace.events() if e.get("name") == "mx.moe.layout"][

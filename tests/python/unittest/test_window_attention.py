@@ -116,9 +116,11 @@ def test_the_cells_calls_visit_31_and_136_tiles_a_head(monkeypatch):
                            q, kv, kv)
     assert noted()[before:] == [
         {"kind": "window", "visited": 31, "whole": 0, "cut": 31,
-         "operand_dtype": "bfloat16", "heads_per_step": 1, "layout": "heads"},
+         "operand_dtype": "bfloat16", "heads_per_step": 1, "layout": "heads",
+         "head_dim": 128},
         {"kind": "causal", "visited": 136, "whole": 120, "cut": 16,
-         "operand_dtype": "bfloat16", "heads_per_step": 1, "layout": "heads"}]
+         "operand_dtype": "bfloat16", "heads_per_step": 1, "layout": "heads",
+         "head_dim": 128}]
 
 
 @pytest.mark.parametrize("t,window,bq,bk,heads,kv_heads", [
