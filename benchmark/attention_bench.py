@@ -29,6 +29,21 @@
         it, with the head-major transposes around it.  ``--dtype float32``
         feeds the kernels float32 (their products then run at float32).
 
+    python benchmark/attention_bench.py placed
+        what a decoder layer does to q and to k between their projection
+        and the kernels (per-head RMSNorm where the model has QK-norm,
+        rotary positions, the kernels' head-major view), one pass at a time
+        at the shapes of laguna_xs2_t8k's window layers (64 and 8 heads of
+        128, whole rotary), its full layers (48 and 8, YaRN tables over 64
+        of 128) and sdar_30b_a3b_bd4k's (32 and 4 with the norm, two
+        sequences): the Pallas pass (``ops/pallas_rotary.py``, form
+        ``kernel``) against XLA's operations (``rms_norm`` /
+        ``rotary_embedding`` and a transpose, form ``xla``), forward and
+        backward each a program of its own.  One JSON line a (shape, form,
+        pass): every device operation's ms a call from the profiler's
+        trace, and the GB/s of the bytes the pass has to move (the rows in
+        and out once; with a norm the backward reads the rows again).
+
     python benchmark/attention_bench.py [T ...]     # default 2048 8192
         causal forward + backward, Pallas kernels against the blockwise-JAX
         path, host clock: one JSON line per (T, impl).
@@ -157,6 +172,18 @@ def device_ms(trace_dir):
     return found, every
 
 
+def traced_ms(step, args, iters):
+    """``device_ms`` of ``iters`` calls of ``step(*args)`` under the
+    profiler."""
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        for _ in range(iters):
+            out = step(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        return device_ms(d)
+
+
 def bench_cell(name, pa, dtype, iters, impl_label):
     B, T, H, Hkv, D, kw, pairs = cell_shapes(pa)[name]
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -173,13 +200,7 @@ def bench_cell(name, pa, dtype, iters, impl_label):
     jax.block_until_ready(step(q, k, v))
     compile_s = time.perf_counter() - t0
     jax.block_until_ready(step(q, k, v))
-    with tempfile.TemporaryDirectory() as d:
-        jax.profiler.start_trace(d)
-        for _ in range(iters):
-            out = step(q, k, v)
-        jax.block_until_ready(out)
-        jax.profiler.stop_trace()
-        found, every = device_ms(d)
+    found, every = traced_ms(step, (q, k, v), iters)
     peak = _peak_bf16_tflops()
     label = {"shape": name, "impl": impl_label,
              "dtype": jnp.dtype(dtype).name}
@@ -200,6 +221,70 @@ def bench_cell(name, pa, dtype, iters, impl_label):
     rows.append(dict(label, kernel="through multi_head_attention",
                      ms=round(every / iters, 4),
                      around_ms=round(every / iters - total, 4)))
+    return rows
+
+
+def placed_shapes():
+    """name -> (B, T, heads, D, QK-norm, rotary keywords)."""
+    whole, yarn = {"theta": 1e4}, {
+        "rotary_dim": 64, "factor": 1.2,
+        "inv_freq": tuple(1e4 ** (-i / 32.0) for i in range(32))}
+    return {"laguna_win_q": (1, 8192, 64, 128, False, whole),
+            "laguna_win_k": (1, 8192, 8, 128, False, whole),
+            "laguna_full_q": (1, 8192, 48, 128, False, yarn),
+            "laguna_full_k": (1, 8192, 8, 128, False, yarn),
+            "sdar_q": (2, 8192, 32, 128, True, {"theta": 1e6}),
+            "sdar_k": (2, 8192, 4, 128, True, {"theta": 1e6})}
+
+
+def placed_forms(heads, D, norm, rotary):
+    """form -> ``f(x, gain, positions)``: rows to the kernels' view."""
+    from mxnet_tpu.ops import nn, pallas_rotary as pr
+
+    def kernel(x, gain, positions):
+        return pr.placed(x, pr.rotary_tables(positions, D, **rotary), heads,
+                         rotary.get("rotary_dim"), gain if norm else None)
+
+    def xla(x, gain, positions):
+        B, T, _ = x.shape
+        h = x.reshape(B, T, heads, D)
+        if norm:
+            h = nn.rms_norm.fn(h, gain)
+        h = nn.rotary_embedding.fn(h, positions, **rotary)
+        return h.transpose(0, 2, 1, 3).reshape(B * heads, T, 1, D)
+
+    return {"kernel": kernel, "xla": xla}
+
+
+def bench_placed(name, iters):
+    B, T, heads, D, norm, rotary = placed_shapes()[name]
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(keys[0], (B, T, heads * D)).astype(jnp.bfloat16)
+    dy = jax.random.normal(keys[1], (B * heads, T, 1, D)) \
+        .astype(jnp.bfloat16)
+    gain = (1 + 0.1 * jax.random.normal(keys[2], (D,))).astype(jnp.bfloat16)
+    positions = jnp.arange(T, dtype=jnp.int32)
+    rows, outs = [], {}
+    for form, f in placed_forms(heads, D, norm, rotary).items():
+        passes = {
+            "forward": jax.jit(lambda x, gain, dy, f=f: f(x, gain,
+                                                          positions)),
+            "backward": jax.jit(lambda x, gain, dy, f=f: jax.vjp(
+                lambda x, gain: f(x, gain, positions), x, gain)[1](dy)),
+        }
+        for which, step in passes.items():
+            outs[form, which] = jax.block_until_ready(step(x, gain, dy))
+            ms = traced_ms(step, (x, gain, dy), iters)[1] / iters
+            moved = x.nbytes * (3 if norm and which == "backward" else 2)
+            rows.append({"shape": name, "form": form, "pass": which,
+                         "ms": round(ms, 4),
+                         "gbytes_per_s": round(moved / ms / 1e6, 1)})
+    for row in rows:    # the largest difference between the two forms
+        leaves = zip(*(jax.tree_util.tree_leaves(outs[form, row["pass"]])
+                       for form in ("kernel", "xla")))
+        row["forms_differ_by"] = max(float(jnp.abs(
+            a.astype(jnp.float32) - b.astype(jnp.float32)).max())
+            for a, b in leaves)
     return rows
 
 
@@ -247,6 +332,15 @@ def main():
     from mxnet_tpu.compile import jax_cache_dir
 
     jax_cache_dir()
+    if sys.argv[1:2] == ["placed"]:
+        if jax.default_backend() != "tpu":
+            raise SystemExit("attention_bench placed: times come from a "
+                             "TPU's trace; the backend here is %r"
+                             % jax.default_backend())
+        for name in placed_shapes():
+            for row in bench_placed(name, 10):
+                print(json.dumps(row), flush=True)
+        return
     if sys.argv[1:2] == ["cells"]:
         ap = argparse.ArgumentParser(prog="attention_bench.py cells")
         ap.add_argument("--impl", default=None,

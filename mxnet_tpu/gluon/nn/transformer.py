@@ -26,7 +26,9 @@ from ..block import HybridBlock
 from .basic_layers import Dense, Dropout, Embedding, HybridSequential, \
     LayerNorm, RMSNorm
 from .moe import MoE
-from ...ops.pallas_attention import rule_kind, window_mask
+from ...ops import pallas_rotary
+from ...ops.pallas_attention import AttnMask, rule_kind, window_mask
+from ...ops.registry import apply_op
 
 __all__ = ["MultiHeadAttention", "PositionwiseFFN",
            "TransformerEncoderCell", "TransformerEncoder",
@@ -200,8 +202,6 @@ class SinusoidalPositionalEmbedding(HybridBlock):
     def forward(self, x):
         import jax.numpy as jnp
 
-        from ...ops.registry import apply_op
-
         T, C = x.shape[1], self._units
 
         def add_pe(data):
@@ -244,12 +244,29 @@ def _attn_scope(kind):
         else contextlib.nullcontext()
 
 
+_PLACED_NOTED = set()
+
+
+def _note_placed(heads, head_dim, rotary, norm, form):
+    """One ``mx.attn.placed`` instant a distinct call, written where the
+    call is traced: which form gives ``heads`` heads of ``head_dim`` their
+    norm and rotary positions, the Pallas pass (``kernel``) or XLA
+    operations (``xla``)."""
+    args = {"heads": heads, "head_dim": head_dim,
+            "rotary_dim": rotary.get("rotary_dim") or head_dim,
+            "norm": norm is not None, "form": form}
+    if tuple(args.values()) not in _PLACED_NOTED:
+        _PLACED_NOTED.add(tuple(args.values()))
+        _trace.instant("mx.attn.placed", args=args)
+
+
 def _placed(h, heads, positions, rotary, norm=None):
     """A projection's ``(B, T, heads * D)`` rows as ``(B, T, heads, D)``
     with their per-head norm and rotary positions (``rotary``: keywords of
-    ``nd.rotary_embedding``)."""
+    ``nd.rotary_embedding``), as XLA operations."""
     b, t = h.shape[0], h.shape[1]
     h = h.reshape((b, t, heads, -1))
+    _note_placed(heads, h.shape[-1], rotary, norm, "xla")
     if norm is not None:
         h = norm(h)
     return nd.rotary_embedding(h, positions, **rotary)
@@ -291,6 +308,7 @@ class GroupedQueryAttention(HybridBlock):
         self._heads, self._kv_heads, self._dim = num_heads, num_kv_heads, \
             head_dim
         self._rotary = dict(rotary or {}, theta=rope_theta)
+        self._eps = epsilon
         self._causal = bool(causal) or window is not None
         self._window = None if window is None else window_mask(window)
         self.query_proj = Dense(num_heads * head_dim, use_bias=False,
@@ -314,14 +332,30 @@ class GroupedQueryAttention(HybridBlock):
             mask = self._window
         causal = self._causal and mask is None
         q, k, v = self.query_proj(x), self.key_proj(x), self.value_proj(x)
+        norms = (self.query_norm, self.key_norm)
         with _attn_scope(rule_kind(causal, mask)):
-            out = nd.multi_head_attention(
-                _placed(q, self._heads, positions, self._rotary,
-                        self.query_norm).reshape(q.shape),
-                _placed(k, self._kv_heads, positions, self._rotary,
-                        self.key_norm).reshape(k.shape),
-                v, num_heads=self._heads, num_kv_heads=self._kv_heads,
-                mask=mask, causal=causal)
+            if pallas_rotary.serves(
+                    x.shape[1], self._dim,
+                    not (mask is None or isinstance(mask, AttnMask)),
+                    q._data.dtype.itemsize):
+                # one Pallas pass each from the projections' rows into the
+                # flash kernels' view
+                for heads, norm in zip((self._heads, self._kv_heads), norms):
+                    _note_placed(heads, self._dim, self._rotary, norm,
+                                 "kernel")
+                out = apply_op(
+                    pallas_rotary.placed_attention, q, k, v, positions,
+                    *(n.gamma.data() for n in norms if n is not None),
+                    num_heads=self._heads, num_kv_heads=self._kv_heads,
+                    causal=causal, mask=mask, eps=self._eps, **self._rotary)
+            else:
+                out = nd.multi_head_attention(
+                    _placed(q, self._heads, positions, self._rotary,
+                            self.query_norm).reshape(q.shape),
+                    _placed(k, self._kv_heads, positions, self._rotary,
+                            self.key_norm).reshape(k.shape),
+                    v, num_heads=self._heads, num_kv_heads=self._kv_heads,
+                    mask=mask, causal=causal)
             if self.gate_proj is not None:
                 b, t = x.shape[0], x.shape[1]
                 g = nd.sigmoid(self.gate_proj(x)) \

@@ -642,6 +642,14 @@ def _multi_head_attention(q, k, v, num_heads, mask, scale, causal, impl,
     return out.transpose(0, 2, 1, 3).reshape(B, Tq, HD)
 
 
+def _rotary_angles(positions, r, theta, inv_freq):
+    """The angles ``(..., T, r/2)`` of a rotary of ``r`` dimensions:
+    ``positions * inv_freq`` (default ``theta**(-2i/r)``), float32."""
+    inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r)) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
+    return positions.astype(jnp.float32)[..., None] * inv
+
+
 @register("rotary_embedding")
 def rotary_embedding(x, positions, theta=10000.0, rotary_dim=None,
                      inv_freq=None, factor=1.0):
@@ -656,9 +664,7 @@ def rotary_embedding(x, positions, theta=10000.0, rotary_dim=None,
     whoever knows the model's config.  Angles in float32."""
     d = x.shape[-1]
     r = d if rotary_dim is None else int(rotary_dim)
-    inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r)) \
-        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
-    ang = positions.astype(jnp.float32)[..., None] * inv      # (..., T, R/2)
+    ang = _rotary_angles(positions, r, theta, inv_freq)
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[..., None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[..., None, :]
     if factor != 1.0:

@@ -806,15 +806,16 @@ def mesh_rows(mesh, batch_axes):
         _MESH_ROWS.layout = prev
 
 
-def _over_rows(local_fn, out_ndims, cfg, *arrays):
-    """``local_fn(cfg, *arrays)`` — Pallas calls gridded over the leading
-    batch rows of every array — directly, or per device under the
-    ``mesh_rows`` layout in force."""
+def _over_rows(local_fn, out_ndims, cfg, *arrays, shared=()):
+    """``local_fn(cfg, *arrays, *shared)`` — Pallas calls gridded over the
+    leading batch rows of every array in ``arrays`` — directly, or per
+    device under the ``mesh_rows`` layout in force; ``shared`` arrays have
+    no batch rows (tables, gains) and every device takes them whole."""
     layout = getattr(_MESH_ROWS, "layout", None)
     # inside a caller's shard_map (ring attention, a pipeline stage, an
     # MoE expert) the arrays are one device's already
     if layout is None or jax.sharding.get_abstract_mesh().manual_axes:
-        return local_fn(cfg, *arrays)
+        return local_fn(cfg, *arrays, *shared)
     mesh, batch_axes = layout
     keep, size = [], 1
     for a in batch_axes:
@@ -826,9 +827,9 @@ def _over_rows(local_fn, out_ndims, cfg, *arrays):
     # pallas_call outputs carry no varying-axes annotation
     return jax.shard_map(
         functools.partial(local_fn, cfg), mesh=mesh,
-        in_specs=tuple(spec(a.ndim) for a in arrays),
+        in_specs=tuple(spec(a.ndim) for a in arrays) + (P(),) * len(shared),
         out_specs=tuple(spec(n) for n in out_ndims),
-        check_vma=False)(*arrays)
+        check_vma=False)(*arrays, *shared)
 
 
 def _forward_local(cfg, seeds, q, k, v):
@@ -1067,9 +1068,11 @@ def _kernel_views(*arrays):
     q, k = arrays[:2]
     if heads_per_step(q.shape[3], q.shape[2], k.shape[2]):
         return arrays
-    return tuple(x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], 1,
-                                                 x.shape[3])
-                 for x in arrays)
+    return tuple(map(_head_major, arrays))
+
+
+def _head_major(x):
+    return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], 1, x.shape[3])
 
 
 def _caller_view(out, B):
@@ -1208,6 +1211,24 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
         seeds = jnp.zeros((B, H), jnp.int32)
     q, k, v = _kernel_views(q, k, v)
     out = _flash_core(cfg, seeds.reshape(q.shape[0], q.shape[2]), q, k, v)
+    return _caller_view(out, B)
+
+
+def flash_attention_placed(q, k, v, causal=False, sm_scale=None, mask=None):
+    """``flash_attention`` for a caller whose q and k stand in the kernels'
+    head-major view already, ``(B * H, Tq, 1, D)`` and ``(B * Hkv, Tk, 1,
+    D)`` as ``pallas_rotary.placed`` writes them (every head a batch row of
+    one head: what ``_kernel_views`` would make of them); ``v`` is ``(B,
+    Tk, Hkv, D)`` and the result ``(B, Tq, H, D)`` as ``flash_attention``'s.
+    The same kernels under the same specs; no dropout."""
+    B, Tk, kv_heads, D = v.shape
+    H = q.shape[0] // B
+    like_q = jax.ShapeDtypeStruct((B, q.shape[1], H, D), q.dtype)
+    like_k = jax.ShapeDtypeStruct((B, Tk, kv_heads, D), k.dtype)
+    cfg = _cfg_for(like_q, like_k, causal, sm_scale, DEFAULT_BLOCK_Q,
+                   DEFAULT_BLOCK_K, None, mask=mask)
+    _note_tiles(cfg, like_q, like_k)
+    out = _flash_core(cfg, _no_seeds(q), q, k, _head_major(v))
     return _caller_view(out, B)
 
 

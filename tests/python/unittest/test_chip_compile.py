@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from mxnet_tpu.base import MXNetError
-from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops import pallas_attention as pa, pallas_rotary as pr
 
 
 @pytest.fixture(scope="module")
@@ -352,3 +352,88 @@ def test_no_copy_between_a_projection_and_the_kernels(topo, cell):
     assert len(moved) <= (0 if D == 64 else 2), moved
     if D == 128:
         assert "[1,8192,64,128]{3,1,2,0" in text    # a product, head-major
+
+
+@pytest.mark.parametrize("shape,heads,norm,rotary", [
+    ((1, 8192), 64, False, {"theta": 1e4}),     # Laguna's window layers
+    ((1, 8192), 48, False, {                    # its full layers: YaRN
+        "rotary_dim": 64, "factor": 1.2,        # tables over 64 of 128
+        "inv_freq": tuple(1e4 ** (-i / 32.0) for i in range(32))}),
+    ((2, 8192), 32, True, {"theta": 1e6}),      # SDAR's q, with QK-norm
+    ((2, 8192), 4, True, {"theta": 1e6}),       # SDAR's k
+], ids=["laguna_window", "laguna_full", "sdar_q", "sdar_k"])
+def test_placed_pass_compiles_for_v5e(topo, shape, heads, norm, rotary):
+    """``ops/pallas_rotary.py`` at the cells' shapes, forward and backward:
+    Mosaic takes the lane rotates, the 128-lane slices of a block of rows
+    and the head-major out block; the blocks fit the VMEM the call asks
+    for; nothing as large as the rows is copied or transposed by XLA on
+    either side of the two calls."""
+    one = SingleDeviceSharding(topo.devices[0])
+    B, T = shape
+
+    def f(x, gain, positions):
+        def loss(x, gain):
+            return pr.placed(x, pr.rotary_tables(positions, 128, **rotary),
+                             heads, rotary.get("rotary_dim"),
+                             gain if norm else None, interpret=False) \
+                .astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, (0, 1) if norm else 0)(x, gain)
+
+    x = jax.ShapeDtypeStruct((B, T, heads * 128), jnp.bfloat16, sharding=one)
+    gain = jax.ShapeDtypeStruct((128,), jnp.bfloat16, sharding=one)
+    positions = jax.ShapeDtypeStruct((T,), jnp.int32, sharding=one)
+    text = jax.jit(f).lower(x, gain, positions).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "placed_fwd" in text and "placed_bwd" in text
+    assert "bf16[%d,8192,128]" % (B * heads) in text    # the kernels' view
+    assert not re.findall(
+        r"= bf16\[(?:%d,%d,%d|%d,%d,128)\]\S* (?:copy|transpose)\("
+        % (B, T, heads * 128, B * heads, T), text)
+
+
+@pytest.mark.parametrize("cell", ["laguna_xs2_t8k", "sdar_30b_a3b_bd4k"])
+def test_placed_attention_layer_compiles_for_v5e(topo, cell):
+    """Projections, ``placed_attention``, output projection, forward and
+    gradient, as one program at a window layer of Laguna and at SDAR's
+    layer: seven Mosaic calls (the pass on q and on k, the three flash
+    kernels, the pass's backward twice), and of everything as large as q
+    XLA copies the attention output into the output projection (and at
+    SDAR's shape its cotangent back), nothing on the way INTO the
+    kernels."""
+    B, T, H, Hkv, norm, kw = (1, 8192, 64, 8, False, {
+        "mask": pa.window_mask(512), "theta": 1e4}) \
+        if cell == "laguna_xs2_t8k" else (2, 8192, 32, 4, True, {
+            "mask": pa.block_diffusion_mask(4096, 4), "theta": 1e6})
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def f(x, wq, wk, wv, wo, gq, gk):
+        def loss(x, wq, wk, wv, wo, gq, gk):
+            positions = jnp.arange(T, dtype=jnp.int32) % 4096
+            out = pr.placed_attention(
+                x @ wq, x @ wk, x @ wv, positions,
+                *((gq, gk) if norm else ()), num_heads=H, num_kv_heads=Hkv,
+                **kw)
+            return ((out @ wo).astype(jnp.float32) ** 2).sum()
+
+        return jax.value_and_grad(loss, tuple(range(7)))(
+            x, wq, wk, wv, wo, gq, gk)
+
+    arg = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.bfloat16, sharding=one)
+    interpret = pa._default_interpret
+    pa._default_interpret = lambda: False       # compile, on this CPU host
+    try:
+        text = jax.jit(f).lower(
+            arg(B, T, 2048), arg(2048, H * 128), arg(2048, Hkv * 128),
+            arg(2048, Hkv * 128), arg(H * 128, 2048), arg(128),
+            arg(128)).compile().as_text()
+    finally:
+        pa._default_interpret = interpret
+    assert text.count("tpu_custom_call") == 7
+    moved = re.findall(
+        r"= bf16\[(?:%d,%d,%d|%d,%d,%d,128|%d,%d,128)\]\S* "
+        r"(?:copy|transpose)\(.*?op_name=\"([^\"]*)\""
+        % (B, T, H * 128, B, T, H, B * H, T), text)
+    assert len(moved) <= 2, moved
+    assert not any("placed" in name for name in moved), moved
